@@ -1,6 +1,6 @@
-"""Kernel micro-benchmarks: jnp-oracle wall time on CPU (the interpreter
-validates correctness; these numbers size the CPU fallbacks) + analytic
-MXU-time projections for the TPU target from the kernels' FLOP counts.
+"""Kernel micro-benchmarks: jnp-oracle wall time on the CPU (host numbers
+that size the CPU fallbacks, not device speed) plus each op's FLOP count
+from its shapes.  Device times come only from a run on the chip.
 """
 from __future__ import annotations
 
@@ -11,8 +11,6 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.types import QueryBatch, StoreView
-
-PEAK = 197e12
 
 
 def _time(f, *args, iters=5):
@@ -35,7 +33,7 @@ def main():
     f = jax.jit(lambda x, a, b: ref.lsh_hash_ref(x, a, b, w=0.5))
     t = _time(f, x, a, b)
     flops = 2 * 8192 * 100 * 128
-    rows.append(("lsh_hash_8192x100x128", t * 1e6, f"tpu_us={flops/PEAK*1e6:.2f}"))
+    rows.append(("lsh_hash_8192x100x128", t * 1e6, f"flops={flops}"))
 
     # bucket_search: R=512, N=4096, d=64, L=8
     q = jax.random.normal(key, (512, 64))
@@ -51,21 +49,21 @@ def main():
         query=qb_, store=sv, cr2=2.0, L=8))
     t = _time(f, query, store)
     flops = 2 * 512 * 4096 * 64
-    rows.append(("bucket_search_512x4096", t * 1e6, f"tpu_us={flops/PEAK*1e6:.2f}"))
+    rows.append(("bucket_search_512x4096", t * 1e6, f"flops={flops}"))
 
     # top-K variant: same scan, K=16 accumulator (the serving path)
     f = jax.jit(lambda qb_, sv: ref.bucket_search_ref(
         query=qb_, store=sv, cr2=2.0, L=8, K=16))
     t = _time(f, query, store)
     rows.append(("bucket_search_topk16_512x4096", t * 1e6,
-                 f"tpu_us={flops/PEAK*1e6:.2f}"))
+                 f"flops={flops}"))
 
     # attention: B1 H8 S1024 dh64
     qq = jax.random.normal(key, (1, 8, 1024, 64), jnp.bfloat16)
     f = jax.jit(lambda q, k, v: ref.attention_ref(q, k, v, causal=True))
     t = _time(f, qq, qq, qq)
     flops = 4 * 8 * 1024 * 1024 * 64
-    rows.append(("attention_1x8x1024x64", t * 1e6, f"tpu_us={flops/PEAK*1e6:.2f}"))
+    rows.append(("attention_1x8x1024x64", t * 1e6, f"flops={flops}"))
 
     # ssd_scan: B1 S1024 H4 P32 N32
     xs = jax.random.normal(key, (1, 1024, 4, 32)) * 0.3

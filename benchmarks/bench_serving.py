@@ -102,6 +102,9 @@ print("SERVING_JSON " + json.dumps(metrics))
 
 def _run_script(script: str, timeout: int = 1800) -> str:
     env = dict(os.environ)
+    # a CPU-lane tool: the child never contends for an accelerator the
+    # parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(repo, "src")

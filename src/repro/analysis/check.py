@@ -63,9 +63,10 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
     import numpy as np
 
     from repro.analysis import hlo_pass, jaxpr_pass
-    from repro.compat import make_mesh, shard_map
+    from repro.compat import make_mesh
     from repro.core import DistributedLSHIndex, LSHConfig, Scheme
     from repro.data import planted_random
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     cc = contracts["check_config"]

@@ -1,41 +1,17 @@
-"""Version-compat wrappers for jax APIs that moved between releases.
+"""The one mesh constructor every call site uses.
 
-The repo targets the current jax API (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types``); older releases still in the wild expose the same
-functionality under ``jax.experimental.shard_map`` / without the
-``axis_types`` kwarg.  Route every call site through here so the rest of
-the code is written against one surface.
+``make_mesh`` builds meshes with Auto axis types (``jax.make_mesh``
+defaults to Explicit), so shardings propagate through the routed steps
+without per-op annotations.
 """
 from __future__ import annotations
 
 import jax
-
-# Oldest jax release the shims below are tested against; CI's version
-# matrix installs exactly this pin for its "oldest" leg (the lower bound
-# in requirements.txt must match).
-MIN_SUPPORTED_JAX = "0.4.37"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with graceful fallback to the experimental API.
-
-    ``check_vma`` maps onto the old ``check_rep`` flag (both disable the
-    replication/varying-manual-axes check that pallas out_shapes lack).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices,
-            axis_types=(AxisType.Auto,) * len(axis_names))
-    except (ImportError, TypeError):
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)

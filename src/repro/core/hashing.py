@@ -19,6 +19,11 @@ import jax.numpy as jnp
 
 from repro.core.config import LSHConfig, Scheme
 
+# Hashes are a function of the data, not of the device: every projection
+# runs at full f32 precision (the TPU default would round the operands
+# to bf16 and move points across bucket boundaries).
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
@@ -169,7 +174,8 @@ def sample_stacked_params(key: jax.Array, cfg: LSHConfig) -> StackedHashParams:
 
 def gamma(params: HashParams, x: jax.Array, W: float) -> jax.Array:
     """Gamma(x) = (A^T x + b) / W  with shape (..., k)."""
-    return (x.astype(jnp.float32) @ params.A + params.b) / jnp.float32(W)
+    return (jnp.matmul(x.astype(jnp.float32), params.A, precision=HIGHEST)
+            + params.b) / jnp.float32(W)
 
 
 def hash_h(params: HashParams, x: jax.Array, W: float) -> jax.Array:
@@ -190,12 +196,14 @@ def pack_buckets(params: HashParams, hk: jax.Array) -> jax.Array:
 
 def g_of(params: HashParams, hk: jax.Array, D: float) -> jax.Array:
     """G(u) = floor((alpha.u + beta)/D) applied to bucket vectors (..., k)."""
-    proj = hk.astype(jnp.float32) @ params.alpha + params.beta
+    proj = jnp.matmul(hk.astype(jnp.float32), params.alpha,
+                      precision=HIGHEST) + params.beta
     return jnp.floor(proj / jnp.float32(D)).astype(jnp.int32)
 
 
 def g_cauchy_of(params: HashParams, hk: jax.Array, D: float) -> jax.Array:
-    proj = hk.astype(jnp.float32) @ params.alpha_cauchy + params.beta
+    proj = jnp.matmul(hk.astype(jnp.float32), params.alpha_cauchy,
+                      precision=HIGHEST) + params.beta
     return jnp.floor(proj / jnp.float32(D)).astype(jnp.int32)
 
 

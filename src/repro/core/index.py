@@ -57,10 +57,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from jax.sharding import Mesh
 
-from repro.compat import shard_map
 from repro.core.config import LSHConfig, Scheme
 from repro.core.hashing import (HashParams, StackedHashParams, hash_h,
                                 pack_buckets, sample_stacked_params,
@@ -350,13 +350,16 @@ class DistributedLSHIndex:
     """
 
     def __init__(self, cfg: LSHConfig, mesh: Mesh, axis: str = "shard",
-                 slack: float = 4.0, use_kernel: bool = False,
+                 slack: float = 4.0, use_kernel: Optional[bool] = None,
                  k_neighbors: int = 1, use_csr: bool = True,
                  merge_min_rows: int = 1024, merge_frac: float = 0.25):
         """use_kernel=True routes the per-shard bucket search through the
         Pallas streaming kernels (kernels/bucket_search.py) instead of the
         jnp mask formulation -- identical results (tested), O(R*N) score
-        matrix never materialised.
+        matrix never materialised.  The default (None) takes the kernels
+        on every accelerator and the jnp oracle on the CPU, where the
+        kernels would only run in the Pallas interpreter; the oracle's
+        dense (R, N) tiles do not fit at deployment store sizes.
 
         k_neighbors is the default K for ``query``: each query returns its
         K best (dist, gid) pairs within cr, union-merged across shards
@@ -375,8 +378,10 @@ class DistributedLSHIndex:
         self.cfg = cfg
         self.mesh = mesh
         self.axis = axis
+        self._sharding = jax.sharding.NamedSharding(mesh, P(axis))
         self.slack = slack
-        self.use_kernel = use_kernel
+        self.use_kernel = (jax.default_backend() != "cpu"
+                           if use_kernel is None else use_kernel)
         self.use_csr = use_csr
         self.merge_min_rows = merge_min_rows
         self.merge_frac = merge_frac
@@ -494,15 +499,16 @@ class DistributedLSHIndex:
         Locality-preserving placement is skewed by design (Table 1).  Bulk
         builds concentrate around the balanced share, so the slack-sized
         block suffices; small streaming batches do not, so their share is
-        doubled and clamped at n_rows (all-to-one always fits: a small
-        batch can never overflow the dispatch, only the append region).
+        doubled.  Either is clamped at n_rows: one source never sends more
+        than all of its rows to one destination, so the clamp drops
+        nothing (and on one shard it keeps the block at the batch size).
         """
         if self.cfg.data_capacity is not None:
             return self.cfg.data_capacity
         S = self.cfg.n_shards
         base = max(8, int(math.ceil(n_rows / S * self.slack)))
         if n_rows > 64 * S:           # bulk regime: slack-share sizing
-            return base
+            return min(n_rows, base)
         return min(n_rows, 2 * base)
 
     def _store_capacity(self, n_rows: int) -> int:
@@ -548,20 +554,18 @@ class DistributedLSHIndex:
         """
         cfg = self.cfg
         S = cfg.n_shards
-        sharding = jax.sharding.NamedSharding(self.mesh, P(self.axis))
-        def alloc(shape, dtype, fill):
-            return jax.device_put(jnp.full(shape, fill, dtype), sharding)
-        self.store = StoreState(
-            x=alloc((S, capacity, cfg.d), jnp.float32, 0.0),
-            packed=alloc((S, capacity, 2), jnp.uint32, 0),
-            gid=alloc((S, capacity), jnp.int32, IMAX),
-            table=alloc((S, capacity), jnp.int32, 0),
-            key=alloc((S, capacity), jnp.int32, 0),
-            valid=alloc((S, capacity), jnp.bool_, False),
-            bucket_start=alloc((S, capacity), jnp.int32, 0),
-            bucket_end=alloc((S, capacity), jnp.int32, 0),
-            n_sorted=0,
-        )
+        cols = {"x": ((S, capacity, cfg.d), 0.0, jnp.float32),
+                "packed": ((S, capacity, 2), 0, jnp.uint32),
+                "gid": ((S, capacity), IMAX, jnp.int32),
+                "table": ((S, capacity), 0, jnp.int32),
+                "key": ((S, capacity), 0, jnp.int32),
+                "valid": ((S, capacity), False, jnp.bool_),
+                "bucket_start": ((S, capacity), 0, jnp.int32),
+                "bucket_end": ((S, capacity), 0, jnp.int32)}
+        # one program fills every column in place on each shard's device
+        alloc = jax.jit(lambda: {c: jnp.full(*v) for c, v in cols.items()},
+                        out_shardings=self._sharding)
+        self.store = StoreState(**alloc(), n_sorted=0)
         self._shard_load = np.zeros((S,), np.int64)
         self._drops = 0
         self._n_live = 0
@@ -711,13 +715,13 @@ class DistributedLSHIndex:
                     f"gids up to {self._next_gid + n - 1} >= the int32 "
                     f"sentinel {int(IMAX)}; pass explicit in-range gids")
             gid_start = self._next_gid if n else None
-            gids = jnp.arange(self._next_gid, self._next_gid + n,
-                              dtype=jnp.int32)
+            gids = np.arange(self._next_gid, self._next_gid + n,
+                             dtype=np.int32)
             self._next_gid += n
         else:
             g64 = np.asarray(gids, np.int64)
             check_gid_range(g64)
-            gids = jnp.asarray(g64, jnp.int32)
+            gids = g64.astype(np.int32)
             # the batch's actual minimum gid (NOT the unrelated _next_gid)
             gid_start = int(g64.min()) if n else None
             self._next_gid = max(self._next_gid, int(g64.max())
@@ -739,15 +743,15 @@ class DistributedLSHIndex:
         st = self.store
         cap = st.capacity
 
+        # pad on the host and place each shard's slice on its own device
+        # (no whole-batch staging on the default device)
         n_pad = int(math.ceil(n / S)) * S if n else S
-        pad = n_pad - n
-        x = jnp.concatenate(
-            [jnp.asarray(points, jnp.float32),
-             jnp.zeros((pad, cfg.d), jnp.float32)]) if pad else jnp.asarray(
-                 points, jnp.float32)
-        g = jnp.concatenate([gids, jnp.full((pad,), IMAX, jnp.int32)]) \
-            if pad else gids
-        valid = jnp.arange(n_pad) < n
+        x = np.zeros((n_pad, cfg.d), np.float32)
+        x[:n] = np.asarray(points, np.float32)
+        g = np.full((n_pad,), int(IMAX), np.int32)
+        g[:n] = gids
+        x, g, valid = jax.device_put((x, g, np.arange(n_pad) < n),
+                                     self._sharding)
         n_loc = n_pad // S
         Ci = self._dispatch_capacity(n_loc * T)
 
@@ -988,8 +992,8 @@ class DistributedLSHIndex:
         self._max_bucket = max_b
         self._mean_bucket = sum_b / n if n else 0.0
 
-        sharding = jax.sharding.NamedSharding(self.mesh, P(self.axis))
-        put = lambda a: jax.device_put(jnp.asarray(a), sharding)
+        # host numpy -> per-shard slices straight to their devices
+        put = lambda a: jax.device_put(a, self._sharding)
         self.store = StoreState(x=put(hx), packed=put(hp), gid=put(hg),
                                 table=put(ht), key=put(hk), valid=put(hv),
                                 bucket_start=put(hbs), bucket_end=put(hbe),

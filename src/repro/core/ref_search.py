@@ -11,12 +11,18 @@ import numpy as np
 IMAX = np.iinfo(np.int32).max
 
 
+def sq_dists(queries: jax.Array, points: jax.Array) -> jax.Array:
+    """(m, n) squared distances at full f32 precision on every device."""
+    d2 = (jnp.sum(queries ** 2, -1)[:, None]
+          + jnp.sum(points ** 2, -1)[None, :]
+          - 2.0 * jnp.matmul(queries, points.T,
+                             precision=jax.lax.Precision.HIGHEST))
+    return jnp.maximum(d2, 0.0)
+
+
 @jax.jit
 def _chunk_min(queries: jax.Array, chunk: jax.Array):
-    d2 = (jnp.sum(queries ** 2, -1)[:, None]
-          + jnp.sum(chunk ** 2, -1)[None, :]
-          - 2.0 * queries @ chunk.T)
-    d2 = jnp.maximum(d2, 0.0)
+    d2 = sq_dists(queries, chunk)
     return jnp.min(d2, axis=1), jnp.argmin(d2, axis=1)
 
 
@@ -65,10 +71,7 @@ def topk_merge_host(best: np.ndarray, arg: np.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _chunk_topk(queries: jax.Array, chunk: jax.Array, idx0: int, *, k: int):
-    d2 = (jnp.sum(queries ** 2, -1)[:, None]
-          + jnp.sum(chunk ** 2, -1)[None, :]
-          - 2.0 * queries @ chunk.T)
-    d2 = jnp.maximum(d2, 0.0)
+    d2 = sq_dists(queries, chunk)
     idx = jnp.broadcast_to(
         idx0 + jnp.arange(chunk.shape[0], dtype=jnp.int32)[None, :],
         d2.shape)

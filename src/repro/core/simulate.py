@@ -362,14 +362,14 @@ def _exact_search_recall(cfg: LSHConfig, table_params: List[HashParams],
     per-query exact top-K among candidates within cr, as (m, k)
     sqrt-distances / gids in (dist, gid) lex order (else None, None).
     """
-    from repro.core.ref_search import topk_merge_host, topk_sort_jnp
+    from repro.core.ref_search import (sq_dists, topk_merge_host,
+                                       topk_sort_jnp)
     T = len(probes_t)
     m = probes_t[0][0].shape[0]
     packed_off_t = [pack_buckets(table_params[t], probes_t[t][0])
                     for t in range(T)]                 # (m, L, 2) each
     r2 = jnp.float32(cfg.r ** 2)
     cr2 = jnp.float32((cfg.c * cfg.r) ** 2)
-    q_sq = jnp.sum(queries ** 2, axis=-1)              # (m,)
     imax = np.iinfo(np.int32).max
 
     def chunk_stats(chunk: jax.Array, packed_chunk_t: tuple, idx0):
@@ -383,9 +383,7 @@ def _exact_search_recall(cfg: LSHConfig, table_params: List[HashParams],
             cand_t = jnp.any(eq & probes_t[t][1][:, :, None], axis=1)
             cand_any = cand_any | cand_t
             n_hit_tables = n_hit_tables + cand_t.astype(jnp.int32)
-        d2 = (q_sq[:, None] + jnp.sum(chunk ** 2, axis=-1)[None, :]
-              - 2.0 * queries @ chunk.T)
-        d2 = jnp.maximum(d2, 0.0)
+        d2 = sq_dists(queries, chunk)
         within = d2 <= cr2
         hit = cand_any & within
         hit_r = jnp.any(cand_any & (d2 <= r2), axis=1)  # (m,)

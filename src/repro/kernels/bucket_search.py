@@ -24,12 +24,21 @@ threshold filter and the running top-K reduction all happen in the same
 VMEM residency -- the O(R*N) distance matrix never reaches HBM.  The
 accumulator is a per-row (dist^2, gid) list of length K kept sorted by
 (dist^2, gid) lex order in the revisited output blocks; each point tile
-is merged in with K extract-min passes over the tile's masked distances
-concatenated with the running K (an insertion merge -- O(K*(TILE_N+K))
-VPU work per tile, no sort network needed).
+is merged in with K successor passes over the pool (this tile's masked
+pairs plus the running K): pass k picks the lex-smallest pair strictly
+after the pair pass k-1 picked -- O(K*(TILE_N+K)) VPU work per tile, no
+sort network and no writes into the pool.
+
+Mosaic layout (what the TPU compiler accepts): every block is 2-D.
+Per-row scalars travel as (R, 1) columns, per-point scalars as
+lane-dense (1, N) rows, probe buckets as separate hi/lo (R, L) words,
+and the accumulators as (R, KP) blocks with KP = K rounded up to the
+128-lane width (lanes >= K hold sentinels and are sliced off by the
+wrapper).  The distance matmul runs at HIGHEST precision, so the f32
+contract is the same on the chip as in the CPU interpreter.
 
 Because both kernels feed the SAME (TILE_R, d) x (TILE_N, d) dot_general
-with identical aligned point tiles, and the extract-min merge is exact
+with identical aligned point tiles, and the successor merge is exact
 selection over lex (dist^2, gid) order (visit-order independent), the
 gather kernel's results are bitwise identical to the full scan's.
 
@@ -50,8 +59,25 @@ from repro.kernels.types import QueryBatch, StoreView
 
 TILE_R = 128
 TILE_N = 128
+LANES = 128
 F32_MAX = float(jnp.finfo(jnp.float32).max)
 IMAX = int(jnp.iinfo(jnp.int32).max)
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def k_lanes(K: int) -> int:
+    """Accumulator width: K rounded up to whole 128-lane vregs."""
+    return -(-K // LANES) * LANES
+
+
+def _sq_dist(q_ref, qsq_ref, p_ref, psq_ref):
+    """(TR, TN) squared distances from the MXU, clamped at 0."""
+    q = q_ref[...].astype(jnp.float32)            # (TR, d)
+    p = p_ref[...].astype(jnp.float32)            # (TN, d)
+    qp = jax.lax.dot_general(q, p, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
+    return jnp.maximum(qsq_ref[...] + psq_ref[...] - 2.0 * qp, 0.0)
 
 
 def _merge_topk_tile(topd_ref, topg_ref, d2m, gidm, *, K: int, init):
@@ -61,92 +87,89 @@ def _merge_topk_tile(topd_ref, topg_ref, d2m, gidm, *, K: int, init):
     Candidate pool = this tile's masked pairs + the running K.  gids are
     unique across the pool (stored rows are unique and the running K came
     from earlier, disjoint tiles); empty slots are the (F32_MAX, IMAX)
-    sentinel, which extract-min leaves in place, so fewer-than-K hits pad
-    the tail with sentinels.
+    sentinel, the lex-largest pair, so fewer-than-K hits pad the tail
+    with sentinels.
     """
     @pl.when(init)
     def _init():
         topd_ref[...] = jnp.full(topd_ref.shape, F32_MAX, jnp.float32)
         topg_ref[...] = jnp.full(topg_ref.shape, IMAX, jnp.int32)
 
-    cand_d = jnp.concatenate([d2m, topd_ref[...]], axis=1)  # (TR, TN+K)
-    cand_g = jnp.concatenate([gidm, topg_ref[...]], axis=1)
-    out_d, out_g = [], []
-    for _ in range(K):
-        bd = jnp.min(cand_d, axis=1)                          # (TR,)
-        bg = jnp.min(jnp.where(cand_d <= bd[:, None], cand_g, IMAX),
-                     axis=1)                                  # lex tie-break
-        out_d.append(bd)
-        out_g.append(bg)
-        taken = (cand_d == bd[:, None]) & (cand_g == bg[:, None])
-        cand_d = jnp.where(taken, F32_MAX, cand_d)
-        cand_g = jnp.where(taken, IMAX, cand_g)
-    topd_ref[...] = jnp.stack(out_d, axis=1)                  # (TR, K)
-    topg_ref[...] = jnp.stack(out_g, axis=1)
+    pool = ((d2m, gidm), (topd_ref[...], topg_ref[...]))
+    TR, KP = topd_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (TR, KP), 1)
+
+    def extract(k, carry):
+        last_d, last_g, out_d, out_g = carry
+        after = [(d > last_d) | ((d == last_d) & (g > last_g))
+                 for d, g in pool]
+        bd = functools.reduce(jnp.minimum, [
+            jnp.min(jnp.where(a, d, F32_MAX), axis=1, keepdims=True)
+            for a, (d, _) in zip(after, pool)])               # (TR, 1)
+        bg = functools.reduce(jnp.minimum, [
+            jnp.min(jnp.where(a & (d == bd), g, IMAX), axis=1,
+                    keepdims=True)
+            for a, (d, g) in zip(after, pool)])               # lex tie-break
+        return (bd, bg, jnp.where(lane == k, bd, out_d),
+                jnp.where(lane == k, bg, out_g))
+
+    carry = (jnp.full((TR, 1), -1.0, jnp.float32),            # below any d2
+             jnp.zeros((TR, 1), jnp.int32),
+             jnp.full((TR, KP), F32_MAX, jnp.float32),
+             jnp.full((TR, KP), IMAX, jnp.int32))
+    _, _, out_d, out_g = jax.lax.fori_loop(0, K, extract, carry)
+    topd_ref[...] = out_d
+    topg_ref[...] = out_g
 
 
-def _bucket_search_kernel(q_ref, qsq_ref, qb_ref, probe_ref, qtab_ref,
-                          p_ref, psq_ref, pb_ref, gid_ref, pvalid_ref,
-                          ptab_ref, cr2_ref,
-                          topd_ref, topg_ref, cnt_ref, *, L: int, K: int):
-    j = pl.program_id(1)
-
-    q = q_ref[...].astype(jnp.float32)            # (TR, d)
-    p = p_ref[...].astype(jnp.float32)            # (TN, d)
-    d2 = (qsq_ref[...].reshape(-1, 1) + psq_ref[...].reshape(1, -1)
-          - 2.0 * jax.lax.dot_general(
-              q, p, (((1,), (1,)), ((), ())),
-              preferred_element_type=jnp.float32))  # (TR, TN)
-    d2 = jnp.maximum(d2, 0.0)
-
-    # bucket match: OR over the L probed buckets of each query row
-    qb = qb_ref[...]                              # (TR, 2*L) int32 pairs
-    pb = pb_ref[...]                              # (TN, 2)
-    probe = probe_ref[...]                        # (TR, L) int32 0/1
-    match = jnp.zeros(d2.shape, jnp.bool_)
-    for l in range(L):
-        eq = ((qb[:, 2 * l, None] == pb[None, :, 0])
-              & (qb[:, 2 * l + 1, None] == pb[None, :, 1]))
-        match = match | (eq & (probe[:, l, None] > 0))
-    match = match & (pvalid_ref[...].reshape(1, -1) > 0)
-    # multi-table fusion: a stored row only answers probes of its own
-    # table (rows of different tables live interleaved in one store)
-    match = match & (qtab_ref[...].reshape(-1, 1)
-                     == ptab_ref[...].reshape(1, -1))
-
-    hit = match & (d2 <= cr2_ref[0, 0])
-    d2m = jnp.where(hit, d2, F32_MAX)             # (TR, TN)
-    gid = gid_ref[...]                            # (TN,)
-    gidm = jnp.where(hit, gid[None, :], IMAX)     # non-hits carry no gid
-
-    @pl.when(j == 0)
+def _count_and_merge(topd_ref, topg_ref, cnt_ref, d2, hit, gid, *, K, init):
+    """Shared epilogue: hit count + top-K merge of one tile."""
+    @pl.when(init)
     def _():
         cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
-    cnt_ref[...] = cnt_ref[...] + jnp.sum(hit, axis=1).astype(jnp.int32)
+    cnt_ref[...] += jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+    _merge_topk_tile(topd_ref, topg_ref, jnp.where(hit, d2, F32_MAX),
+                     jnp.where(hit, gid, IMAX), K=K, init=init)
 
-    _merge_topk_tile(topd_ref, topg_ref, d2m, gidm, K=K, init=j == 0)
+
+def _bucket_search_kernel(q_ref, qsq_ref, qh_ref, ql_ref, probe_ref,
+                          qtab_ref, p_ref, psq_ref, ph_ref, pl_ref, gid_ref,
+                          pvalid_ref, ptab_ref, cr2_ref,
+                          topd_ref, topg_ref, cnt_ref, *, L: int, K: int):
+    d2 = _sq_dist(q_ref, qsq_ref, p_ref, psq_ref)    # (TR, TN)
+
+    # bucket match: OR over the L probed buckets of each query row
+    qh, ql, probe = qh_ref[...], ql_ref[...], probe_ref[...]   # (TR, L)
+    ph, plo = ph_ref[...], pl_ref[...]                         # (1, TN)
+    match = jnp.zeros(d2.shape, jnp.bool_)
+    for l in range(L):
+        match = match | ((qh[:, l:l + 1] == ph) & (ql[:, l:l + 1] == plo)
+                         & (probe[:, l:l + 1] > 0))
+    # multi-table fusion: a stored row only answers probes of its own
+    # table (rows of different tables live interleaved in one store)
+    hit = (match & (pvalid_ref[...] > 0) & (qtab_ref[...] == ptab_ref[...])
+           & (d2 <= cr2_ref[0, 0]))
+    _count_and_merge(topd_ref, topg_ref, cnt_ref, d2, hit, gid_ref[...],
+                     K=K, init=pl.program_id(1) == 0)
 
 
 def vmem_bytes_per_step(d: int, L: int, K: int) -> int:
-    """VMEM footprint of one grid step's blocks (inputs + accumulators).
+    """VMEM footprint of one grid step's blocks (inputs + accumulators),
+    with every 2-D block padded to whole (8, 128) tiles.
 
     By construction this is independent of R and N -- the proof that the
     kernel never materialises the O(R*N) distance matrix: per step it
     holds one (TILE_R, TILE_N) distance tile plus O(TILE_R * K) outputs.
     """
+    col = TILE_R * LANES * 4            # one (TILE_R, 1) column block
+    row = 8 * TILE_N * 4                # one (1, TILE_N) row block
+    probes = TILE_R * k_lanes(L) * 4    # one (TILE_R, L) word block
     in_bytes = (TILE_R * d * 4          # q tile
-                + TILE_R * 4            # qsq
-                + TILE_R * 2 * L * 4    # qbuckets
-                + TILE_R * L * 4        # probe
-                + TILE_R * 4            # qtable
+                + 2 * col               # qsq, qtable
+                + 3 * probes            # bucket hi, bucket lo, probe
                 + TILE_N * d * 4        # p tile
-                + TILE_N * 4            # psq
-                + TILE_N * 2 * 4        # pbuckets
-                + TILE_N * 4            # gid
-                + TILE_N * 4            # pvalid
-                + TILE_N * 4            # ptable
-                + 4)                    # cr2 scalar
-    out_bytes = TILE_R * K * 4 * 2 + TILE_R * 4   # topd, topg, cnt
+                + 6 * row)              # psq, hi, lo, gid, pvalid, ptable
+    out_bytes = TILE_R * k_lanes(K) * 4 * 2 + col   # topd, topg, cnt
     dist_tile = TILE_R * TILE_N * 4               # d2 scratch residency
     return in_bytes + out_bytes + dist_tile
 
@@ -154,14 +177,32 @@ def vmem_bytes_per_step(d: int, L: int, K: int) -> int:
 def gather_vmem_bytes_per_step(d: int, K: int) -> int:
     """VMEM per bucket-gather grid step: independent of N_shard AND of L
     (the probe expansion happens on the row axis, not in the block)."""
+    col = TILE_R * LANES * 4
+    row = 8 * TILE_N * 4
     in_bytes = (TILE_R * d * 4          # expanded q tile
-                + TILE_R * 4 * 3        # eqsq, span start, span end
+                + 3 * col               # eqsq, span start, span end
                 + TILE_N * d * 4        # gathered p tile
-                + TILE_N * 4 * 3        # psq, gid, pvalid
-                + 4)                    # cr2 scalar
-    out_bytes = TILE_R * K * 4 * 2 + TILE_R * 4
+                + 3 * row)              # psq, gid, pvalid
+    out_bytes = TILE_R * k_lanes(K) * 4 * 2 + col
     dist_tile = TILE_R * TILE_N * 4
     return in_bytes + out_bytes + dist_tile
+
+
+def _col(x):
+    """(R,) -> (R, 1) per-row column."""
+    return x.reshape(-1, 1)
+
+
+def _row(x):
+    """(N,) -> (1, N) lane-dense per-point row."""
+    return x.reshape(1, -1)
+
+
+def _outputs(R: int, K: int):
+    KP = k_lanes(K)
+    return [jax.ShapeDtypeStruct((R, KP), jnp.float32),
+            jax.ShapeDtypeStruct((R, KP), jnp.int32),
+            jax.ShapeDtypeStruct((R, 1), jnp.int32)]
 
 
 @functools.partial(jax.jit, static_argnames=("L", "K", "interpret"))
@@ -190,39 +231,28 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
     N = store.points.shape[0]
     assert R % TILE_R == 0 and N % TILE_N == 0, (R, N)
     assert 1 <= K <= TILE_N, K
-    grid = (R // TILE_R, N // TILE_N)
-    kernel = functools.partial(_bucket_search_kernel, L=L, K=K)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    KP = k_lanes(K)
+    rows = lambda w: pl.BlockSpec((TILE_R, w), lambda i, j: (i, 0))
+    pts = pl.BlockSpec((1, TILE_N), lambda i, j: (0, j))
+    topd, topg, cnt = pl.pallas_call(
+        functools.partial(_bucket_search_kernel, L=L, K=K),
+        grid=(R // TILE_R, N // TILE_N),
         in_specs=[
-            pl.BlockSpec((TILE_R, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((TILE_R,), lambda i, j: (i,)),
-            pl.BlockSpec((TILE_R, 2 * L), lambda i, j: (i, 0)),
-            pl.BlockSpec((TILE_R, L), lambda i, j: (i, 0)),
-            pl.BlockSpec((TILE_R,), lambda i, j: (i,)),
+            rows(d), rows(1), rows(L), rows(L), rows(L), rows(1),
             pl.BlockSpec((TILE_N, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((TILE_N,), lambda i, j: (j,)),
-            pl.BlockSpec((TILE_N, 2), lambda i, j: (j, 0)),
-            pl.BlockSpec((TILE_N,), lambda i, j: (j,)),
-            pl.BlockSpec((TILE_N,), lambda i, j: (j,)),
-            pl.BlockSpec((TILE_N,), lambda i, j: (j,)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pts, pts, pts, pts, pts, pts,
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=[
-            pl.BlockSpec((TILE_R, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((TILE_R, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((TILE_R,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, K), jnp.float32),
-            jax.ShapeDtypeStruct((R, K), jnp.int32),
-            jax.ShapeDtypeStruct((R,), jnp.int32),
-        ],
+        out_specs=[rows(KP), rows(KP), rows(1)],
+        out_shape=_outputs(R, K),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(query.q, query.qsq, query.buckets, query.probe, query.table,
-      store.points, store.psq, store.buckets, store.gid, store.valid,
-      store.table, jnp.full((1, 1), cr2, jnp.float32))
+    )(query.q, _col(query.qsq), query.buckets[:, 0::2],
+      query.buckets[:, 1::2], query.probe, _col(query.table),
+      store.points, _row(store.psq), _row(store.buckets[:, 0]),
+      _row(store.buckets[:, 1]), _row(store.gid), _row(store.valid),
+      _row(store.table), jnp.full((1, 1), cr2, jnp.float32))
+    return topd[:, :K], topg[:, :K], cnt[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -234,14 +264,7 @@ def _bucket_gather_kernel(base_ref, q_ref, qsq_ref, s_ref, e_ref,
                           p_ref, psq_ref, gid_ref, pvalid_ref, cr2_ref,
                           topd_ref, topg_ref, cnt_ref, *, K: int):
     i, g = pl.program_id(0), pl.program_id(1)
-
-    q = q_ref[...].astype(jnp.float32)            # (TR, d)
-    p = p_ref[...].astype(jnp.float32)            # (TN, d)
-    d2 = (qsq_ref[...].reshape(-1, 1) + psq_ref[...].reshape(1, -1)
-          - 2.0 * jax.lax.dot_general(
-              q, p, (((1,), (1,)), ((), ())),
-              preferred_element_type=jnp.float32))  # (TR, TN)
-    d2 = jnp.maximum(d2, 0.0)
+    d2 = _sq_dist(q_ref, qsq_ref, p_ref, psq_ref)    # (TR, TN)
 
     # span mask: absolute store-row index of each column in this gathered
     # tile, against the expanded row's CSR span [start, end).  Rows in the
@@ -250,19 +273,10 @@ def _bucket_gather_kernel(base_ref, q_ref, qsq_ref, s_ref, e_ref,
     # only liveness (tombstones stay in place until the next merge).
     col0 = (base_ref[i] + g) * TILE_N
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, TILE_N), 1)
-    span = ((cols >= s_ref[...].reshape(-1, 1))
-            & (cols < e_ref[...].reshape(-1, 1)))        # (TR, TN)
-    hit = span & (pvalid_ref[...].reshape(1, -1) > 0) \
-        & (d2 <= cr2_ref[0, 0])
-    d2m = jnp.where(hit, d2, F32_MAX)
-    gidm = jnp.where(hit, gid_ref[...][None, :], IMAX)
-
-    @pl.when(g == 0)
-    def _():
-        cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
-    cnt_ref[...] = cnt_ref[...] + jnp.sum(hit, axis=1).astype(jnp.int32)
-
-    _merge_topk_tile(topd_ref, topg_ref, d2m, gidm, K=K, init=g == 0)
+    hit = ((cols >= s_ref[...]) & (cols < e_ref[...])
+           & (pvalid_ref[...] > 0) & (d2 <= cr2_ref[0, 0]))
+    _count_and_merge(topd_ref, topg_ref, cnt_ref, d2, hit, gid_ref[...],
+                     K=K, init=g == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("K", "G", "interpret"))
@@ -297,35 +311,26 @@ def bucket_gather_pallas(base, q, qsq, start, end, p, psq, gid, pvalid,
     assert E % TILE_R == 0 and N % TILE_N == 0, (E, N)
     assert 1 <= K <= TILE_N, K
     assert 1 <= G <= N // TILE_N, (G, N)
-    kernel = functools.partial(_bucket_gather_kernel, K=K)
+    KP = k_lanes(K)
+    rows = lambda w: pl.BlockSpec((TILE_R, w), lambda i, g, b: (i, 0))
+    pts = pl.BlockSpec((1, TILE_N), lambda i, g, b: (0, b[i] + g))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(E // TILE_R, G),
         in_specs=[
-            pl.BlockSpec((TILE_R, d), lambda i, g, b: (i, 0)),
-            pl.BlockSpec((TILE_R,), lambda i, g, b: (i,)),
-            pl.BlockSpec((TILE_R,), lambda i, g, b: (i,)),
-            pl.BlockSpec((TILE_R,), lambda i, g, b: (i,)),
+            rows(d), rows(1), rows(1), rows(1),
             pl.BlockSpec((TILE_N, d), lambda i, g, b: (b[i] + g, 0)),
-            pl.BlockSpec((TILE_N,), lambda i, g, b: (b[i] + g,)),
-            pl.BlockSpec((TILE_N,), lambda i, g, b: (b[i] + g,)),
-            pl.BlockSpec((TILE_N,), lambda i, g, b: (b[i] + g,)),
-            pl.BlockSpec((1, 1), lambda i, g, b: (0, 0)),
+            pts, pts, pts,
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=[
-            pl.BlockSpec((TILE_R, K), lambda i, g, b: (i, 0)),
-            pl.BlockSpec((TILE_R, K), lambda i, g, b: (i, 0)),
-            pl.BlockSpec((TILE_R,), lambda i, g, b: (i,)),
-        ],
+        out_specs=[rows(KP), rows(KP), rows(1)],
     )
-    return pl.pallas_call(
-        kernel,
+    topd, topg, cnt = pl.pallas_call(
+        functools.partial(_bucket_gather_kernel, K=K),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((E, K), jnp.float32),
-            jax.ShapeDtypeStruct((E, K), jnp.int32),
-            jax.ShapeDtypeStruct((E,), jnp.int32),
-        ],
+        out_shape=_outputs(E, K),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(base, q, qsq, start, end, p, psq, gid, pvalid,
-      jnp.full((1, 1), cr2, jnp.float32))
+    )(base, q, _col(qsq), _col(start), _col(end), p, _row(psq), _row(gid),
+      _row(pvalid), jnp.full((1, 1), cr2, jnp.float32))
+    return topd[:, :K], topg[:, :K], cnt[:, 0]

@@ -1,8 +1,8 @@
 """Public wrappers for the Pallas kernels.
 
 Each op pads inputs to kernel tile multiples, dispatches to the Pallas
-kernel (interpret=True on CPU -- TPU v5e is the compile target, this
-container validates in the interpreter), and unpads. ``use_kernel=False``
+kernel (compiled by Mosaic on a TPU; interpret=True on the CPU backend,
+where the tests validate it), and unpads. ``use_kernel=False``
 falls back to the jnp oracle, which the dry-run / XLA path also uses for
 sharded lowering.
 

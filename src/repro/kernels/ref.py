@@ -35,7 +35,8 @@ def bucket_search_ref(*, query, store, cr2, L: int, K: int = 1):
     matches probes of its own table (multi-table fusion).
     """
     q, p = query.q, store.points
-    d2 = query.qsq[:, None] + store.psq[None, :] - 2.0 * q @ p.T
+    d2 = (query.qsq[:, None] + store.psq[None, :]
+          - 2.0 * jnp.matmul(q, p.T, precision=jax.lax.Precision.HIGHEST))
     d2 = jnp.maximum(d2, 0.0)
     qb = query.buckets.reshape(q.shape[0], L, 2)
     pbuckets, probe, gid = store.buckets, query.probe, store.gid

@@ -5,6 +5,10 @@ the paper's metrics (rows/query, load balance) alongside latency.
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-7b --reduced \
       --docs 2048 --batches 4
 (multi-device: XLA_FLAGS=--xla_force_host_platform_device_count=8)
+
+On an accelerator the index searches with the Pallas kernels (the
+``DistributedLSHIndex`` default); compiled programs persist in the cache
+that ``repro.compile_cache`` sets up.
 """
 from __future__ import annotations
 
@@ -16,6 +20,7 @@ import numpy as np
 
 from repro import persist
 from repro.compat import make_mesh
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import Scheme
 from repro.models import init_params
@@ -49,6 +54,7 @@ def main(argv=None):
                          "query pipeline + background snapshots "
                          "(bitwise-identical results)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     params = init_params(jax.random.PRNGKey(args.seed), cfg)

@@ -257,7 +257,8 @@ class SnapshotWriter:
 
 def restore(snap_dir: str, mesh, *, n_shards: Optional[int] = None,
             step: Optional[int] = None, axis: str = "shard",
-            use_kernel: bool = False, k_neighbors: Optional[int] = None,
+            use_kernel: Optional[bool] = None,
+            k_neighbors: Optional[int] = None,
             slack: float = 4.0, capacity: Optional[int] = None,
             ) -> DistributedLSHIndex:
     """Rebuild a live index from the latest (or given) snapshot.
@@ -267,7 +268,8 @@ def restore(snap_dir: str, mesh, *, n_shards: Optional[int] = None,
     as ``Key mod n_shards`` -- no re-hashing, and exact agreement with a
     fresh index of that shard count (hash params are shard-count-
     independent).  ``capacity`` pre-reserves per-shard append-region rows
-    for a stream that keeps growing after the restore.
+    for a stream that keeps growing after the restore.  ``use_kernel``
+    is the rebuilt index's (None: the Pallas kernels on an accelerator).
     """
     by_path, step, extra = checkpoint.load(snap_dir, step=step)
     if extra.get("kind") != "lsh-index-snapshot":
@@ -326,7 +328,7 @@ class RecoverResult:
 
 
 def recover(snap_dir: str, mesh, *, n_shards: Optional[int] = None,
-            axis: str = "shard", use_kernel: bool = False,
+            axis: str = "shard", use_kernel: Optional[bool] = None,
             k_neighbors: Optional[int] = None, slack: float = 4.0,
             capacity: Optional[int] = None,
             service: Optional[dict] = None) -> RecoverResult:

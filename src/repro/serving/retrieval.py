@@ -52,7 +52,7 @@ class RetrievalService:
     def build(cls, cfg: ModelConfig, params, doc_tokens, mesh,
               r: float = 0.25, c: float = 2.0, k: int = 10, L: int = 16,
               W: float = 1.0, scheme: Scheme = Scheme.LAYERED,
-              seed: int = 0, use_kernel: bool = False,
+              seed: int = 0, use_kernel: "bool | None" = None,
               bucket_size: int = 64, max_latency_ms: float = 25.0,
               k_neighbors: int = 1, n_tables: int = 1,
               pipelined: bool = False):
@@ -62,7 +62,11 @@ class RetrievalService:
 
         pipelined=True serves through ``AsyncLSHService`` (double-
         buffered query pipeline + worker threads, bitwise-identical
-        results); the default stays the synchronous micro-batcher."""
+        results); the default stays the synchronous micro-batcher.
+
+        use_kernel=None searches with the Pallas kernels on an
+        accelerator and the jnp oracle on the CPU (see
+        ``DistributedLSHIndex``)."""
         docs = embed_texts(params, cfg, doc_tokens)
         lsh = LSHConfig(d=int(docs.shape[1]), k=k, W=W, r=r, c=c, L=L,
                         n_shards=mesh.shape["shard"], scheme=scheme,

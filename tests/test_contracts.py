@@ -43,10 +43,9 @@ def _one_dev_mesh():
 def _shmap(fn, out_specs):
     import jax
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
-    return jax.jit(shard_map(fn, mesh=_one_dev_mesh(),
-                             in_specs=(P("shard"),), out_specs=out_specs,
-                             check_vma=False))
+    return jax.jit(jax.shard_map(fn, mesh=_one_dev_mesh(),
+                                 in_specs=(P("shard"),), out_specs=out_specs,
+                                 check_vma=False))
 
 
 def test_collective_counts_sees_psum_despite_rename():
@@ -129,7 +128,7 @@ def test_wide_dtype_drift_flagged_but_prng_keys_exempt():
     def wide_fn():
         return jnp.arange(8, dtype=jnp.int64) * 2
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         stats = jaxpr_pass.intermediate_stats(jax.make_jaxpr(wide_fn)())
     assert stats["wide_dtypes"], "int64 intermediate must be flagged"
 

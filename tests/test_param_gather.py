@@ -266,7 +266,10 @@ def test_flush_failure_requeues_with_original_deadline():
     requeued queries keep their SLO without waiting for a fresh submit."""
     from repro.serving import ShardedLSHService
     fake = _FakeIndex()
-    svc = ShardedLSHService(fake, bucket_size=4, max_latency_ms=50.0)
+    # a frozen clock: the deadline cannot lapse between two calls, however
+    # slowly a loaded test worker runs them
+    svc = ShardedLSHService(fake, bucket_size=4, max_latency_ms=50.0,
+                            clock=lambda: 100.0)
     h = svc.submit(np.zeros(8, np.float32))
     d0 = svc._deadline
     assert d0 is not None
@@ -296,7 +299,9 @@ def test_full_bucket_flush_failure_mid_submit_keeps_deadline():
     and a later recovered flush drains in submission order."""
     from repro.serving import ShardedLSHService
     fake = _FakeIndex()
-    svc = ShardedLSHService(fake, bucket_size=4, max_latency_ms=1e4)
+    now = [100.0]                             # test-driven clock
+    svc = ShardedLSHService(fake, bucket_size=4, max_latency_ms=1e4,
+                            clock=lambda: now[0])
     h1 = svc.submit_batch(np.zeros((3, 8), np.float32))
     d0 = svc._deadline
     fake.fail = True
@@ -306,6 +311,7 @@ def test_full_bucket_flush_failure_mid_submit_keeps_deadline():
     assert svc._deadline == d0                # oldest query keeps its SLO
 
     fake.fail = False
+    now[0] += 1.0                             # well inside the 10 s SLO
     svc.submit_batch(np.zeros((2, 8), np.float32))  # 6th -> flush fires
     assert all(h.done for h in h1)            # oldest bucket went first
     assert svc.n_pending == 2
