@@ -87,8 +87,8 @@ def run_size(n: int, cr2: float = 8.0, k: int = 4) -> dict:
     f_full = jax.jit(lambda q, s: ops.bucket_search(
         query=q, store=s, cr2=cr2, L=1, k=k, force_full_scan=True))
 
-    d_c, g_c, c_c = f_csr(query, store)
-    d_f, g_f, c_f = f_full(query, store)
+    d_c, g_c, c_c, _ = f_csr(query, store)
+    d_f, g_f, c_f, _ = f_full(query, store)
     np.testing.assert_array_equal(
         np.asarray(d_c).view(np.uint32), np.asarray(d_f).view(np.uint32))
     np.testing.assert_array_equal(np.asarray(g_c), np.asarray(g_f))

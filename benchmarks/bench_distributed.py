@@ -101,7 +101,7 @@ for scheme in (Scheme.SIMPLE, Scheme.LAYERED):
     st = svc.stats
     load = svc.shard_load()
     skew = load.max() / max(load.mean(), 1)
-    print(f"{{scheme.value}},{{st.queries_per_s:.0f}},"
+    print(f"{{scheme.value}},{{st.queries / sum(lat):.0f}},"
           f"{{st.inserts_per_s:.0f}},{{np.median(lat)*1e3:.1f}},"
           f"{{st.routed_rows/max(st.queries,1):.2f}},{{skew:.2f}},"
           f"{{st.occupancy:.2f}},{{st.drops}}")

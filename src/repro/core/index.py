@@ -68,6 +68,7 @@ from repro.core.hashing import (HashParams, StackedHashParams, hash_h,
 from repro.core.offsets import (query_offsets, query_offsets_by_table,
                                 stacked_base_keys)
 from repro.core import store_layout
+from repro.core.spans import span
 from repro.kernels import ops as kops
 from repro.kernels.types import QueryBatch, StoreView
 
@@ -270,6 +271,8 @@ class QueryResult:
     #                           exactly what crossed the wire)
     query_load: np.ndarray    # (S,) live rows received per shard
     drops: int
+    scan_counters: np.ndarray  # (S, len(kops.SCAN_COUNTERS)) what each
+    #                            shard's scan saw (kernels.ops.bucket_search)
 
     @property
     def k_neighbors(self) -> int:
@@ -309,6 +312,7 @@ class DispatchedBatch:
     drops: jax.Array          # (S,) capacity drops per source shard
     m: int
     Cq: int
+    batch: Optional[int] = None   # the caller's batch number (spans' arg)
 
 
 @dataclasses.dataclass
@@ -317,15 +321,20 @@ class ScannedBatch:
 
     ``ret`` holds each shard's local per-qid top-K (bitcast distances,
     gids, emit count): the routed return payload.  It is consumed
-    (donated) by ``query_return``.
+    (donated) by ``query_return``.  ``counters`` is what each shard's
+    scan saw (``kernels.ops.SCAN_COUNTERS``), left on the device until
+    the caller fetches the batch.
     """
     ret: jax.Array            # (S*m, 2K+1) int32 return payload
     recv_load: jax.Array      # (S,) live rows received per shard
+    counters: jax.Array       # (S, len(kops.SCAN_COUNTERS)) int32
     m: int
     K: int
+    batch: Optional[int] = None
 
 
-def _host_query_result(gtopd, gtopg, gemit, fq, load, drops) -> QueryResult:
+def _host_query_result(gtopd, gtopg, gemit, fq, load, drops,
+                       counters) -> QueryResult:
     """Fetch device query outputs into a host QueryResult (blocks)."""
     gtopd = np.asarray(gtopd)
     return QueryResult(
@@ -335,7 +344,8 @@ def _host_query_result(gtopd, gtopg, gemit, fq, load, drops) -> QueryResult:
         n_within_cr=np.asarray(gemit),
         fq=np.asarray(fq).reshape(-1),
         query_load=np.asarray(load),
-        drops=int(np.asarray(drops).sum()))
+        drops=int(np.asarray(drops).sum()),
+        scan_counters=np.asarray(counters))
 
 
 class DistributedLSHIndex:
@@ -1016,7 +1026,8 @@ class DistributedLSHIndex:
         if self.store is None:
             raise RuntimeError("insert() or build() first")
         before = self.store.capacity
-        load = self.load_rows(self.host_live_rows())
+        with span("lsh.merge", n_live=self._n_live):
+            load = self.load_rows(self.host_live_rows())
         self._merges += 1
         return CompactResult(capacity_before=before,
                              capacity_after=self.store.capacity,
@@ -1086,6 +1097,7 @@ class DistributedLSHIndex:
             earlier = jnp.arange(L)[:, None] > jnp.arange(L)[None, :]
             return ~jnp.any(eq & earlier, axis=-1)      # (L,)
 
+        @jax.named_scope("lsh.dispatch")
         def dispatch_body(q_loc, qid_loc):
             """Stage 1: route.  Hash T x L offsets, pack the payload and
             issue the ONE fused dispatch all_to_all."""
@@ -1121,10 +1133,12 @@ class DistributedLSHIndex:
             r = _a2a(sbuf, axis)                         # (S*Cq, d+2)
             return r, fq_local, drops
 
+        @jax.named_scope("lsh.scan")
         def scan_body(r, store_x, store_packed, store_gid, store_table,
                       store_valid, store_bs, store_be):
             """Stage 2: receive-side hash-once + bucket search + local
-            per-qid union across tables.  No collectives."""
+            per-qid union across tables.  No collectives.  Also returns
+            what the bucket search saw (``kernels.ops.SCAN_COUNTERS``)."""
             me = jax.lax.axis_index(axis)
             rq = _i2f(r[:, :d])
             rid = r[:, d]
@@ -1175,7 +1189,7 @@ class DistributedLSHIndex:
                 gid=store_gid, valid=store_valid.astype(jnp.int32),
                 table=store_table, bucket_start=store_bs,
                 bucket_end=store_be, n_sorted=ns)
-            row_d, row_g, row_emit = kops.bucket_search(
+            row_d, row_g, row_emit, counters = kops.bucket_search(
                 query=qbatch, store=sview,
                 cr2=float(np.float32((cfg.c * cfg.r) ** 2)), L=L, k=K,
                 use_kernel=use_kernel, force_full_scan=not use_csr,
@@ -1186,24 +1200,26 @@ class DistributedLSHIndex:
             # into (qid, table) slots and K-way merge the T tables
             # (dedup by gid: a point stored in several tables counts
             # once) ----
-            idx = jnp.where(rvalid, rid * T + rtab, m * T)  # sink m*T
-            loc_d = jnp.full((m * T + 1, K), INF).at[idx].set(
-                jnp.where(rvalid[:, None], row_d, INF))
-            loc_g = jnp.full((m * T + 1, K), IMAX, jnp.int32).at[idx].set(
-                jnp.where(rvalid[:, None], row_g, IMAX))
-            loc_d, loc_g = merge_topk(
-                loc_d[:m * T].reshape(m, T * K),
-                loc_g[:m * T].reshape(m, T * K), K)         # (m, K)
-            qid_sink = jnp.where(rvalid, rid, m)
-            emit = jnp.zeros((m + 1,), jnp.int32).at[qid_sink].add(
-                jnp.where(rvalid, row_emit, 0))[:m]
+            with jax.named_scope("merge"):
+                idx = jnp.where(rvalid, rid * T + rtab, m * T)  # sink m*T
+                loc_d = jnp.full((m * T + 1, K), INF).at[idx].set(
+                    jnp.where(rvalid[:, None], row_d, INF))
+                loc_g = jnp.full((m * T + 1, K), IMAX, jnp.int32).at[
+                    idx].set(jnp.where(rvalid[:, None], row_g, IMAX))
+                loc_d, loc_g = merge_topk(
+                    loc_d[:m * T].reshape(m, T * K),
+                    loc_g[:m * T].reshape(m, T * K), K)         # (m, K)
+                qid_sink = jnp.where(rvalid, rid, m)
+                emit = jnp.zeros((m + 1,), jnp.int32).at[qid_sink].add(
+                    jnp.where(rvalid, row_emit, 0))[:m]
 
             # ---- return payload: each qid's local top-K (+ emit count)
             # as one int32 row, ready for the routed return a2a ----
             ret = jnp.concatenate([
                 _f2i(loc_d), loc_g, emit[:, None]], axis=1)  # (m, 2K+1)
-            return ret, recv_load
+            return ret, recv_load, counters
 
+        @jax.named_scope("lsh.return")
         def return_body(ret):
             """Stage 3: ONE routed all_to_all ships each qid's local
             top-K (+ emit count) only to the qid's OWNER shard
@@ -1230,17 +1246,17 @@ class DistributedLSHIndex:
                         store_table, store_valid, store_bs, store_be):
             r, fq_local, drops = dispatch_body(q_loc, qid_loc)
             # stores arrive with a leading per-shard block dim of 1
-            ret, recv_load = scan_body(
+            ret, recv_load, counters = scan_body(
                 r, store_x[0], store_packed[0], store_gid[0],
                 store_table[0], store_valid[0], store_bs[0], store_be[0])
             gtopd, gtopg, gemit = return_body(ret)
             return (gtopd, gtopg, gemit, fq_local, recv_load[None],
-                    drops[None])
+                    drops[None], counters[None])
 
         spec = P(self.axis)
         return jax.jit(shard_map(
             query_shard, mesh=self.mesh,
-            in_specs=(spec,) * 9, out_specs=(spec,) * 6,
+            in_specs=(spec,) * 9, out_specs=(spec,) * 7,
             check_vma=False,   # pallas out_shape has no vma annotation
         ), donate_argnums=(0,) if donate else ())
 
@@ -1266,15 +1282,15 @@ class DistributedLSHIndex:
         def scan_shard(r, store_x, store_packed, store_gid, store_table,
                        store_valid, store_bs, store_be):
             # stores arrive with a leading per-shard block dim of 1
-            ret, recv_load = scan_body(
+            ret, recv_load, counters = scan_body(
                 r, store_x[0], store_packed[0], store_gid[0],
                 store_table[0], store_valid[0], store_bs[0], store_be[0])
-            return ret, recv_load[None]
+            return ret, recv_load[None], counters[None]
 
         spec = P(self.axis)
         return jax.jit(shard_map(
             scan_shard, mesh=self.mesh,
-            in_specs=(spec,) * 8, out_specs=(spec,) * 2,
+            in_specs=(spec,) * 8, out_specs=(spec,) * 3,
             check_vma=False,
         ), donate_argnums=(0,))   # the routed recv buffer dies here
 
@@ -1293,7 +1309,8 @@ class DistributedLSHIndex:
         ), donate_argnums=(0,))   # the return payload dies here
 
     def query(self, queries: jax.Array, donate: bool = False,
-              k_neighbors: Optional[int] = None) -> QueryResult:
+              k_neighbors: Optional[int] = None,
+              batch: Optional[int] = None) -> QueryResult:
         """Answer a batch of queries (m, d), m divisible by n_shards.
 
         donate=True donates the query buffer to the compiled executable
@@ -1302,35 +1319,38 @@ class DistributedLSHIndex:
 
         k_neighbors overrides the index-level default K for this call
         (each distinct K compiles its own executable, cached).
-        """
-        if self.store is None:
-            raise RuntimeError("call build() or insert() first")
-        cfg = self.cfg
-        S = cfg.n_shards
-        m = queries.shape[0]
-        if m % S:
-            raise ValueError(f"m={m} must divide by n_shards={S}")
-        K = self.k_neighbors if k_neighbors is None else k_neighbors
-        if not 1 <= K <= 128:
-            raise ValueError(f"k_neighbors={K} not in [1, 128]")
-        m_loc = m // S
-        Cq = self._query_capacity(m_loc)
-        st = self.store
-        G = self._gather_window(S * Cq * cfg.L)
 
-        key = (m, st.capacity, Cq, donate, K, st.n_sorted, G,
-               self.use_csr)
-        fn = self._query_fns.get(key)
-        if fn is None:
-            fn = self._query_fns[key] = self._make_query_fn(
-                m, st.capacity, Cq, donate, K, st.n_sorted, G)
-        qids = jnp.arange(m, dtype=jnp.int32)
-        gtopd, gtopg, gemit, fq, load, drops = fn(
-            queries, qids, st.x, st.packed, st.gid, st.table, st.valid,
-            st.bucket_start, st.bucket_end)
-        # each shard returned exactly its own qids' results (the routed
-        # return path); the sharded outputs concatenate to (m, K)
-        return _host_query_result(gtopd, gtopg, gemit, fq, load, drops)
+        batch: the caller's number for this batch, the ``lsh.query``
+        span's arg (``core/spans.py``).
+        """
+        with span("lsh.query", batch=batch):
+            if self.store is None:
+                raise RuntimeError("call build() or insert() first")
+            cfg = self.cfg
+            S = cfg.n_shards
+            m = queries.shape[0]
+            if m % S:
+                raise ValueError(f"m={m} must divide by n_shards={S}")
+            K = self.k_neighbors if k_neighbors is None else k_neighbors
+            if not 1 <= K <= 128:
+                raise ValueError(f"k_neighbors={K} not in [1, 128]")
+            m_loc = m // S
+            Cq = self._query_capacity(m_loc)
+            st = self.store
+            G = self._gather_window(S * Cq * cfg.L)
+
+            key = (m, st.capacity, Cq, donate, K, st.n_sorted, G,
+                   self.use_csr)
+            fn = self._query_fns.get(key)
+            if fn is None:
+                fn = self._query_fns[key] = self._make_query_fn(
+                    m, st.capacity, Cq, donate, K, st.n_sorted, G)
+            qids = jnp.arange(m, dtype=jnp.int32)
+            # each shard returned exactly its own qids' results (the routed
+            # return path); the sharded outputs concatenate to (m, K)
+            return _host_query_result(*fn(
+                queries, qids, st.x, st.packed, st.gid, st.table, st.valid,
+                st.bucket_start, st.bucket_end))
 
     # ------------------------------------------------------------------
     # Staged query: the same step as separately-invocable stages.  Each
@@ -1352,24 +1372,28 @@ class DistributedLSHIndex:
             raise ValueError(f"k_neighbors={K} not in [1, 128]")
         return m, K
 
-    def query_dispatch(self, queries: jax.Array,
-                       donate: bool = False) -> DispatchedBatch:
+    def query_dispatch(self, queries: jax.Array, donate: bool = False,
+                       batch: Optional[int] = None) -> DispatchedBatch:
         """Stage 1/3: hash + route the batch through the dispatch a2a.
 
         Returns device-resident handles immediately (async dispatch).
         donate=True donates the query staging buffer -- the pipeline
-        must not refill that buffer until this batch retires.
+        must not refill that buffer until this batch retires.  ``batch``
+        (the caller's number for it) travels with the batch as the arg
+        of its ``lsh.dispatch`` / ``lsh.scan`` / ``lsh.return`` spans.
         """
-        m, _ = self._check_query_batch(queries, None)
-        Cq = self._query_capacity(m // self.cfg.n_shards)
-        key = ("dispatch", m, Cq, donate)
-        fn = self._query_fns.get(key)
-        if fn is None:
-            fn = self._query_fns[key] = self._make_query_dispatch_fn(
-                m, Cq, donate)
-        qids = jnp.arange(m, dtype=jnp.int32)
-        recv, fq, drops = fn(queries, qids)
-        return DispatchedBatch(recv=recv, fq=fq, drops=drops, m=m, Cq=Cq)
+        with span("lsh.dispatch", batch=batch):
+            m, _ = self._check_query_batch(queries, None)
+            Cq = self._query_capacity(m // self.cfg.n_shards)
+            key = ("dispatch", m, Cq, donate)
+            fn = self._query_fns.get(key)
+            if fn is None:
+                fn = self._query_fns[key] = self._make_query_dispatch_fn(
+                    m, Cq, donate)
+            qids = jnp.arange(m, dtype=jnp.int32)
+            recv, fq, drops = fn(queries, qids)
+        return DispatchedBatch(recv=recv, fq=fq, drops=drops, m=m, Cq=Cq,
+                               batch=batch)
 
     def query_scan(self, disp: DispatchedBatch,
                    k_neighbors: Optional[int] = None) -> ScannedBatch:
@@ -1382,17 +1406,21 @@ class DistributedLSHIndex:
         K = self.k_neighbors if k_neighbors is None else k_neighbors
         if not 1 <= K <= 128:
             raise ValueError(f"k_neighbors={K} not in [1, 128]")
-        st = self.store
-        G = self._gather_window(self.cfg.n_shards * disp.Cq * self.cfg.L)
-        key = ("scan", disp.m, st.capacity, disp.Cq, K, st.n_sorted, G,
-               self.use_csr)
-        fn = self._query_fns.get(key)
-        if fn is None:
-            fn = self._query_fns[key] = self._make_query_scan_fn(
-                disp.m, st.capacity, disp.Cq, K, st.n_sorted, G)
-        ret, recv_load = fn(disp.recv, st.x, st.packed, st.gid, st.table,
-                            st.valid, st.bucket_start, st.bucket_end)
-        return ScannedBatch(ret=ret, recv_load=recv_load, m=disp.m, K=K)
+        with span("lsh.scan", batch=disp.batch):
+            st = self.store
+            G = self._gather_window(
+                self.cfg.n_shards * disp.Cq * self.cfg.L)
+            key = ("scan", disp.m, st.capacity, disp.Cq, K, st.n_sorted, G,
+                   self.use_csr)
+            fn = self._query_fns.get(key)
+            if fn is None:
+                fn = self._query_fns[key] = self._make_query_scan_fn(
+                    disp.m, st.capacity, disp.Cq, K, st.n_sorted, G)
+            ret, recv_load, counters = fn(
+                disp.recv, st.x, st.packed, st.gid, st.table, st.valid,
+                st.bucket_start, st.bucket_end)
+        return ScannedBatch(ret=ret, recv_load=recv_load, counters=counters,
+                            m=disp.m, K=K, batch=disp.batch)
 
     def query_return(self, scanned: ScannedBatch
                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -1402,12 +1430,13 @@ class DistributedLSHIndex:
         (topk_dist^2, topk_gid, n_within_cr) -- fetch with np.asarray
         to block on the batch.
         """
-        key = ("return", scanned.m, scanned.K)
-        fn = self._query_fns.get(key)
-        if fn is None:
-            fn = self._query_fns[key] = self._make_query_return_fn(
-                scanned.m, scanned.K)
-        return fn(scanned.ret)
+        with span("lsh.return", batch=scanned.batch):
+            key = ("return", scanned.m, scanned.K)
+            fn = self._query_fns.get(key)
+            if fn is None:
+                fn = self._query_fns[key] = self._make_query_return_fn(
+                    scanned.m, scanned.K)
+            return fn(scanned.ret)
 
     def query_staged(self, queries: jax.Array, donate: bool = False,
                      k_neighbors: Optional[int] = None) -> QueryResult:
@@ -1421,4 +1450,5 @@ class DistributedLSHIndex:
         scanned = self.query_scan(disp, k_neighbors=k_neighbors)
         gtopd, gtopg, gemit = self.query_return(scanned)
         return _host_query_result(gtopd, gtopg, gemit, disp.fq,
-                                  scanned.recv_load, disp.drops)
+                                  scanned.recv_load, disp.drops,
+                                  scanned.counters)

@@ -205,9 +205,10 @@ def _outputs(R: int, K: int):
             jax.ShapeDtypeStruct((R, 1), jnp.int32)]
 
 
-@functools.partial(jax.jit, static_argnames=("L", "K", "interpret"))
+@functools.partial(jax.jit, static_argnames=("L", "K", "interpret", "name"))
 def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
-                         L: int, K: int = 1, interpret: bool = False):
+                         L: int, K: int = 1, interpret: bool = False,
+                         name: str = "bucket_search_pallas"):
     """Streaming masked top-K NN scan over EVERY stored row (full scan).
 
     Args (all keyword-only):
@@ -219,6 +220,8 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
         CSR fields are ignored here (this is the layout-agnostic path).
       cr2: scalar threshold (c*r)^2.
       K: neighbours to keep per row (static).
+      name: the kernel's name in the compiled program and the device
+        trace (static); each call site gives its own.
     Returns:
       topd (R, K) f32 masked distance^2, ascending (F32_MAX sentinel pad),
       topg (R, K) int32 gids (IMAX sentinel pad),
@@ -247,6 +250,7 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
         out_shape=_outputs(R, K),
         compiler_params=_PARAMS,
         interpret=interpret,
+        name=name,
     )(query.q, _col(query.qsq), query.buckets[:, 0::2],
       query.buckets[:, 1::2], query.probe, _col(query.table),
       store.points, _row(store.psq), _row(store.buckets[:, 0]),
@@ -331,6 +335,7 @@ def bucket_gather_pallas(base, q, qsq, start, end, p, psq, gid, pvalid,
         out_shape=_outputs(E, K),
         compiler_params=_PARAMS,
         interpret=interpret,
+        name="bucket_gather_pallas",
     )(base, q, _col(qsq), _col(start), _col(end), p, _row(psq), _row(gid),
       _row(pvalid), jnp.full((1, 1), cr2, jnp.float32))
     return topd[:, :K], topg[:, :K], cnt[:, 0]

@@ -42,6 +42,10 @@ IMAX = int(jnp.iinfo(jnp.int32).max)
 # caller has no bucket statistics to size it from
 DEFAULT_WINDOW_TILES = 4
 
+# the counters ``bucket_search`` returns, in order (see its docstring)
+SCAN_COUNTERS = ("fallback", "need_tiles", "window_tiles", "probes_live",
+                 "probes_expanded")
+
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
@@ -161,10 +165,15 @@ def csr_probe_spans(query: QueryBatch, store: StoreView
 
 
 def _full_scan(query_p: QueryBatch, store_view: StoreView, cr2, *,
-               L: int, k: int, interpret: bool):
+               L: int, k: int, interpret: bool,
+               name: str = "bucket_search_pallas"):
     """Full-scan kernel over an (already padded) store view."""
     return bucket_search_pallas(query=query_p, store=store_view, cr2=cr2,
-                                L=L, K=k, interpret=interpret)
+                                L=L, K=k, interpret=interpret, name=name)
+
+
+def _no_counters() -> jax.Array:
+    return jnp.zeros((len(SCAN_COUNTERS),), jnp.int32)
 
 
 def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
@@ -179,74 +188,88 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
 
     # ---- per-probe spans, expanded rows sorted by span start so each
     # 128-row tile's spans cluster into a small tile window ----
-    start, end = csr_probe_spans(query, store)
+    with jax.named_scope("spans"):
+        start, end = csr_probe_spans(query, store)
     # Duplicate probes of one row (two perturbations packing to the same
     # bucket) must count each store row once, as the full scan's OR-mask
     # does.  Identical non-empty spans identify identical buckets, so
     # blank every repeat after the first.
-    if L > 1:
-        dup_cols = [jnp.zeros((R,), bool)]
-        for l in range(1, L):
-            d_l = jnp.zeros((R,), bool)
-            for m in range(l):
-                d_l = d_l | ((start[:, l] == start[:, m])
-                             & (end[:, l] == end[:, m]))
-            dup_cols.append(d_l)
-        dup = jnp.stack(dup_cols, axis=1) & (end > start)
-        zero = jnp.zeros((), jnp.int32)
-        start = jnp.where(dup, zero, start)
-        end = jnp.where(dup, zero, end)
-    sflat, eflat = start.reshape(-1), end.reshape(-1)
-    E0 = R * L
-    live = eflat > sflat
-    order = jnp.argsort(jnp.where(live, sflat, ns))   # dead probes last
-    E = -(-E0 // TILE_R) * TILE_R
-    pad = E - E0
-    rowid = order // L
-    eq = _pad_to(query.q[rowid], 0, TILE_R)
-    eqsq = _pad_to(query.qsq[rowid], 0, TILE_R)
-    es = _pad_to(sflat[order], 0, TILE_R)
-    ee = _pad_to(eflat[order], 0, TILE_R)             # pad: empty spans
-    elive = _pad_to(live[order], 0, TILE_R)
+    with jax.named_scope("expand"):
+        if L > 1:
+            dup_cols = [jnp.zeros((R,), bool)]
+            for l in range(1, L):
+                d_l = jnp.zeros((R,), bool)
+                for m in range(l):
+                    d_l = d_l | ((start[:, l] == start[:, m])
+                                 & (end[:, l] == end[:, m]))
+                dup_cols.append(d_l)
+            dup = jnp.stack(dup_cols, axis=1) & (end > start)
+            zero = jnp.zeros((), jnp.int32)
+            start = jnp.where(dup, zero, start)
+            end = jnp.where(dup, zero, end)
+        sflat, eflat = start.reshape(-1), end.reshape(-1)
+        E0 = R * L
+        live = eflat > sflat
+        order = jnp.argsort(jnp.where(live, sflat, ns))   # dead probes last
+        E = -(-E0 // TILE_R) * TILE_R
+        rowid = order // L
+        eq = _pad_to(query.q[rowid], 0, TILE_R)
+        eqsq = _pad_to(query.qsq[rowid], 0, TILE_R)
+        es = _pad_to(sflat[order], 0, TILE_R)
+        ee = _pad_to(eflat[order], 0, TILE_R)             # pad: empty spans
+        elive = _pad_to(live[order], 0, TILE_R)
 
-    # ---- static-window bases + overflow guard ----
-    lo_t = jnp.where(elive, es // TILE_N, n_tiles - 1).astype(jnp.int32)
-    hi_t = jnp.where(elive, (ee - 1) // TILE_N, 0).astype(jnp.int32)
-    base = jnp.min(lo_t.reshape(-1, TILE_R), axis=1)
-    need = jnp.max(hi_t.reshape(-1, TILE_R) - base[:, None] + 1, axis=1)
-    overflow = jnp.any(need > G)
-    base = jnp.clip(base, 0, n_tiles - G)
+        # ---- static-window bases + overflow guard ----
+        lo_t = jnp.where(elive, es // TILE_N, n_tiles - 1).astype(jnp.int32)
+        hi_t = jnp.where(elive, (ee - 1) // TILE_N, 0).astype(jnp.int32)
+        base = jnp.min(lo_t.reshape(-1, TILE_R), axis=1)
+        need = jnp.max(hi_t.reshape(-1, TILE_R) - base[:, None] + 1, axis=1)
+        overflow = jnp.any(need > G)
+        base = jnp.clip(base, 0, n_tiles - G)
+        # what the guard saw, reported whichever branch runs
+        counters = jnp.stack([
+            overflow.astype(jnp.int32), jnp.maximum(jnp.max(need), 0),
+            jnp.int32(G), live.sum(dtype=jnp.int32), jnp.int32(E)])
 
     sorted_view = _pad_slice(store, 0, ns)
 
     def run_csr(_):
-        gd, gg, gc = bucket_gather_pallas(
-            base, eq, eqsq, es, ee, sorted_view.points, sorted_view.psq,
-            sorted_view.gid, sorted_view.valid, cr2, K=k, G=G,
-            interpret=interpret)
-        # unsort back to (row, probe) order; spans of one row's probes
-        # are disjoint buckets, so a plain lex sort merges them exactly
-        rd = jnp.full((E0, k), F32_MAX, jnp.float32).at[order].set(gd[:E0])
-        rg = jnp.full((E0, k), IMAX, jnp.int32).at[order].set(gg[:E0])
-        rc = jnp.zeros((E0,), jnp.int32).at[order].set(gc[:E0])
-        cand_d = rd.reshape(R, L * k)
-        cand_g = rg.reshape(R, L * k)
-        cnt = rc.reshape(R, L).sum(axis=1)
+        with jax.named_scope("gather"):
+            gd, gg, gc = bucket_gather_pallas(
+                base, eq, eqsq, es, ee, sorted_view.points,
+                sorted_view.psq, sorted_view.gid, sorted_view.valid, cr2,
+                K=k, G=G, interpret=interpret)
+        with jax.named_scope("merge"):
+            # unsort back to (row, probe) order; spans of one row's probes
+            # are disjoint buckets, so a plain lex sort merges them exactly
+            rd = jnp.full((E0, k), F32_MAX, jnp.float32).at[order].set(
+                gd[:E0])
+            rg = jnp.full((E0, k), IMAX, jnp.int32).at[order].set(gg[:E0])
+            rc = jnp.zeros((E0,), jnp.int32).at[order].set(gc[:E0])
+            cand_d = rd.reshape(R, L * k)
+            cand_g = rg.reshape(R, L * k)
+            cnt = rc.reshape(R, L).sum(axis=1)
         if cap > ns:                       # unsorted insert tail
-            td, tg, tc = _full_scan(query_p, _pad_slice(store, ns, cap),
-                                    cr2, L=L, k=k, interpret=interpret)
+            with jax.named_scope("tail"):
+                td, tg, tc = _full_scan(
+                    query_p, _pad_slice(store, ns, cap), cr2, L=L, k=k,
+                    interpret=interpret, name="bucket_search_pallas_tail")
             cand_d = jnp.concatenate([cand_d, td[:R]], axis=1)
             cand_g = jnp.concatenate([cand_g, tg[:R]], axis=1)
             cnt = cnt + tc[:R]
-        sd, sg = jax.lax.sort((cand_d, cand_g), dimension=1, num_keys=2)
+        with jax.named_scope("merge"):
+            sd, sg = jax.lax.sort((cand_d, cand_g), dimension=1,
+                                  num_keys=2)
         return sd[:, :k], sg[:, :k], cnt
 
     def run_full(_):
-        td, tg, tc = _full_scan(query_p, _pad_slice(store, 0, cap), cr2,
-                                L=L, k=k, interpret=interpret)
+        with jax.named_scope("fallback"):
+            td, tg, tc = _full_scan(
+                query_p, _pad_slice(store, 0, cap), cr2, L=L, k=k,
+                interpret=interpret, name="bucket_search_pallas_fallback")
         return td[:R], tg[:R], tc[:R]
 
-    return jax.lax.cond(overflow, run_full, run_csr, None)
+    return jax.lax.cond(overflow, run_full, run_csr, None) + (counters,)
 
 
 def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
@@ -258,8 +281,14 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     Keyword-only typed surface: ``query`` bundles the R received rows
     (q, qsq, packed probe buckets, probe mask, table), ``store`` bundles
     the N stored rows plus the optional CSR layout.  Returns
-    (topd (R, k), topg (R, k), cnt (R,)) in (dist^2, gid) lex order,
-    sentinel-padded with (F32_MAX, IMAX) past the available hits.
+    (topd (R, k), topg (R, k), cnt (R,), counters) with the first three
+    in (dist^2, gid) lex order, sentinel-padded with (F32_MAX, IMAX) past
+    the available hits, and ``counters`` an int32 vector holding, in the
+    order of ``SCAN_COUNTERS``, what the CSR path's overflow guard saw:
+    whether it fell back to the full scan, the most aligned store tiles a
+    row tile's spans needed, the window G it had, the expanded probe rows
+    with a non-empty span and all expanded rows (padding included).
+    Every other path reports zeros.
 
     Dispatch: a bucket-sorted store (``store.n_sorted > 0``) uses the CSR
     bucket-gather kernel over the sorted region plus a full scan of the
@@ -274,7 +303,7 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     """
     if not use_kernel:
         return ref.bucket_search_ref(query=query, store=store, cr2=cr2,
-                                     L=L, K=k)
+                                     L=L, K=k) + (_no_counters(),)
     R = query.q.shape[0]
     interpret = _on_cpu()
     query_p = _pad_query(query)
@@ -284,7 +313,7 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     topd, topg, cnt = _full_scan(
         query_p, _pad_slice(store, 0, store.points.shape[0]), cr2,
         L=L, k=k, interpret=interpret)
-    return topd[:R], topg[:R], cnt[:R]
+    return topd[:R], topg[:R], cnt[:R], _no_counters()
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,

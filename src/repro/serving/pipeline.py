@@ -26,6 +26,12 @@ stage bodies are the fused trace cut at its collective boundaries, the
 stage payloads are exact int32 buffers, and retirement applies the same
 numpy post-processing in the same submission order (tested in
 tests/test_serving_pipeline.py).
+
+Spans (``core/spans.py``; args ``batch`` = the submit sequence number):
+``lsh.enqueue`` covers staging plus the three stage calls, ``lsh.retire``
+the fetch (``lsh.fetch``, where the host waits on the device) and the
+handle loop (``lsh.resolve``).  The scan's counters come back with the
+fetch the retire makes anyway, so counting adds no host sync.
 """
 from __future__ import annotations
 
@@ -40,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.index import DistributedLSHIndex
-from repro.serving.service import ServiceStats
+from repro.core.spans import span
+from repro.serving.service import BatchRecord, ServiceStats
 
 
 @dataclasses.dataclass
@@ -52,9 +59,12 @@ class _InFlight:
     emit: jax.Array               # (bucket,) emit counts (device)
     fq: jax.Array                 # (bucket,) routed rows (device)
     drops: jax.Array              # (S,) capacity drops (device)
+    counters: jax.Array           # (S, C) what the scan saw (device)
+    seq: int                      # submit sequence number
     take: int                     # live queries (rest is padding)
     reason: str                   # what triggered the submit (stats key)
-    t_submit: float               # pipeline clock at submit
+    t_enqueue: float              # pipeline clock when staging began
+    t_enqueued: float             # ... when the three stages were enqueued
 
 
 class QueryPipeline:
@@ -64,8 +74,8 @@ class QueryPipeline:
     blocks on device work; it retires the oldest batch first if the
     pipeline is full).  ``retire_one``/``drain`` fetch results and
     resolve handles.  Handle objects need the ``PendingQuery`` attribute
-    surface (gids/dists/gid/dist/n_within_cr/fq/done/t_submit) plus an
-    optional ``_resolved()`` hook (used by the async front-end to wake
+    surface (gids/dists/gid/dist/n_within_cr/fq/batch/done/t_submit) plus
+    an optional ``_resolved()`` hook (used by the async front-end to wake
     waiters).
     """
 
@@ -91,11 +101,8 @@ class QueryPipeline:
         self._slots = [np.zeros((bucket_size, index.cfg.d), np.float32)
                        for _ in range(depth)]
         self._slot = 0
+        self._seq = 0
         self._inflight: deque[_InFlight] = deque()
-        # device-time accounting: union of [submit, fetch-done] intervals
-        # (in-flight batches overlap; summing per-batch spans would
-        # double-count the overlapped time the pipeline exists to create)
-        self._busy_until = 0.0
 
     @property
     def n_inflight(self) -> int:
@@ -117,18 +124,23 @@ class QueryPipeline:
                              f"{self.bucket_size}")
         while len(self._inflight) >= self.depth:
             self.retire_one()
-        buf = self._slots[self._slot]
-        buf[:take] = rows
-        buf[take:] = 0.0   # re-zero the pad region (slot is reused)
+        seq = self._seq
         t0 = self._clock()
-        disp = self.index.query_dispatch(jnp.asarray(buf), donate=True)
-        scanned = self.index.query_scan(disp,
-                                        k_neighbors=self.k_neighbors)
-        topd, topg, emit = self.index.query_return(scanned)
+        with span("lsh.enqueue", batch=seq, reason=reason, take=take):
+            buf = self._slots[self._slot]
+            buf[:take] = rows
+            buf[take:] = 0.0   # re-zero the pad region (slot is reused)
+            disp = self.index.query_dispatch(jnp.asarray(buf), donate=True,
+                                             batch=seq)
+            scanned = self.index.query_scan(disp,
+                                            k_neighbors=self.k_neighbors)
+            topd, topg, emit = self.index.query_return(scanned)
         self._inflight.append(_InFlight(
             handles=handles, topd=topd, topg=topg, emit=emit,
-            fq=disp.fq, drops=disp.drops, take=take, reason=reason,
-            t_submit=t0))
+            fq=disp.fq, drops=disp.drops, counters=scanned.counters,
+            seq=seq, take=take, reason=reason, t_enqueue=t0,
+            t_enqueued=self._clock()))
+        self._seq += 1
         self._slot = (self._slot + 1) % self.depth
         if len(self._inflight) > self.stats.inflight_peak:
             self.stats.inflight_peak = len(self._inflight)
@@ -143,38 +155,40 @@ class QueryPipeline:
         if not self._inflight:
             return 0
         fl = self._inflight.popleft()
-        topd = np.asarray(fl.topd)          # blocks until the batch ran
-        topg = np.asarray(fl.topg)
-        emit = np.asarray(fl.emit)
-        fq = np.asarray(fl.fq).reshape(-1)
-        drops = int(np.asarray(fl.drops).sum())
-        now = self._clock()
-        dists = np.sqrt(np.where(topd < np.float32(3e38), topd, np.inf))
-
-        st = self.stats
-        for i, h in enumerate(fl.handles):
-            h.gids = topg[i].copy()
-            h.dists = dists[i].copy()
-            h.gid = int(h.gids[0])
-            h.dist = float(h.dists[0])
-            h.n_within_cr = int(emit[i])
-            h.fq = int(fq[i])
-            h.done = True
-            st.record_latency((now - h.t_submit) * 1e3)
-            resolved = getattr(h, "_resolved", None)
-            if resolved is not None:
-                resolved()
-
-        st.queries += fl.take
-        st.batches += 1
-        st.pad_rows += self.bucket_size - fl.take
-        st.drops += drops
-        st.routed_rows += int(fq[:fl.take].sum())
-        # busy-interval union: overlapped device time is counted once
-        st.query_time_s += now - max(fl.t_submit, self._busy_until)
-        self._busy_until = now
-        key = f"flush_{fl.reason}"
-        setattr(st, key, getattr(st, key) + 1)
+        with span("lsh.retire", batch=fl.seq):
+            with span("lsh.fetch", batch=fl.seq):
+                topd = np.asarray(fl.topd)  # blocks until the batch ran
+                topg = np.asarray(fl.topg)
+                emit = np.asarray(fl.emit)
+                fq = np.asarray(fl.fq).reshape(-1)
+                drops = int(np.asarray(fl.drops).sum())
+                counters = np.asarray(fl.counters)
+            now = self._clock()
+            dists = np.sqrt(np.where(topd < np.float32(3e38), topd, np.inf))
+            rec = BatchRecord(
+                seq=fl.seq, reason=fl.reason, take=fl.take,
+                pad=self.bucket_size - fl.take, drops=drops,
+                routed_rows=int(fq[:fl.take].sum()),
+                admission_wait_s=sum(fl.t_enqueue - h.t_submit
+                                     for h in fl.handles),
+                t_enqueue=fl.t_enqueue, t_enqueued=fl.t_enqueued,
+                counters=counters)
+            st = self.stats
+            st.record_batch(rec)
+            with span("lsh.resolve", batch=fl.seq):
+                for i, h in enumerate(fl.handles):
+                    h.gids = topg[i].copy()
+                    h.dists = dists[i].copy()
+                    h.gid = int(h.gids[0])
+                    h.dist = float(h.dists[0])
+                    h.n_within_cr = int(emit[i])
+                    h.fq = int(fq[i])
+                    h.batch = rec
+                    h.done = True
+                    st.record_latency((now - h.t_submit) * 1e3)
+                    resolved = getattr(h, "_resolved", None)
+                    if resolved is not None:
+                        resolved()
         return fl.take
 
     def drain(self) -> int:

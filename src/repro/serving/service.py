@@ -18,7 +18,10 @@ into that service:
     ``persist.recover`` (snapshot + idempotent WAL-tail replay);
   * accounting -- per-flush latency, occupancy, routed rows and overflow
     drops accumulate into ``ServiceStats`` (the serving-regime view of the
-    paper's network-cost metric).  WAL-replayed writes go through the
+    paper's network-cost metric), with how many scans took the gather
+    window's overflow fallback and how long queries waited for admission;
+    each answered handle also holds its batch's ``BatchRecord`` (the
+    scan's tiles and probe rows).  WAL-replayed writes go through the
     same ``insert``/``delete`` entry points, so they are counted too.
 
 The front-end is synchronous and deterministic (no threads): deadlines
@@ -36,6 +39,57 @@ import numpy as np
 
 from repro.core.index import (DeleteResult, DistributedLSHIndex,
                               InsertResult, check_gid_range)
+from repro.kernels.ops import SCAN_COUNTERS
+
+_COL = {name: i for i, name in enumerate(SCAN_COUNTERS)}
+
+
+@dataclasses.dataclass(frozen=True)
+class BatchRecord:
+    """What one answered query batch did; every handle of the batch holds
+    it as ``batch`` once resolved.  ``ServiceStats`` sums the part its
+    ``summary()`` prints; the rest is read off the records."""
+    seq: int                  # the front end's batch number (spans' arg)
+    reason: str               # what triggered it: full/deadline/manual
+    take: int                 # live queries
+    pad: int                  # padding rows (bucket - live)
+    drops: int                # capacity drops
+    routed_rows: int          # live query rows shipped
+    admission_wait_s: float   # summed over its queries: t_enqueue - t_submit
+    t_enqueue: float          # clock when the batch left admission
+    t_enqueued: float         # clock when its device work was all enqueued
+    #                           (the synchronous flush: when it returned)
+    counters: np.ndarray      # (S, len(SCAN_COUNTERS)) per-shard scan view
+
+    def _col(self, name: str) -> np.ndarray:
+        return np.asarray(self.counters).reshape(
+            -1, len(SCAN_COUNTERS))[:, _COL[name]]
+
+    @property
+    def fallback(self) -> bool:
+        """Some shard's overflow guard took the full scan."""
+        return bool(self._col("fallback").max() > 0)
+
+    @property
+    def need_tiles(self) -> int:
+        """Most aligned store tiles one row tile's spans needed (max over
+        row tiles and shards); 0 off the CSR path."""
+        return int(self._col("need_tiles").max())
+
+    @property
+    def window_tiles(self) -> int:
+        """The gather window G it ran with; 0 off the CSR path."""
+        return int(self._col("window_tiles").max())
+
+    @property
+    def probes_live(self) -> int:
+        """Expanded probe rows with a non-empty span, over shards."""
+        return int(self._col("probes_live").sum())
+
+    @property
+    def probes_expanded(self) -> int:
+        """Expanded probe rows scanned (padding included), over shards."""
+        return int(self._col("probes_expanded").sum())
 
 
 @dataclasses.dataclass
@@ -50,6 +104,7 @@ class PendingQuery:
     n_within_cr: int = 0          # candidates within cr across all shards
     fq: int = 0                   # routed rows (Definition 7)
     t_submit: float = 0.0         # service clock at admission (for latency)
+    batch: Optional[BatchRecord] = None   # the batch that answered it
 
     def result(self) -> "PendingQuery":
         """Block until resolved (forces a flush of the owning bucket)."""
@@ -77,7 +132,14 @@ class ServiceStats:
     drops: int = 0                # capacity overflow anywhere (must stay 0)
     routed_rows: int = 0          # live query rows shipped (network cost,
     #                               summed over the fused tables)
-    query_time_s: float = 0.0     # wall time inside flushed query steps
+    # what the scan saw (BatchRecord's counters, summed per batch)
+    scan_steps: int = 0           # query batches scanned
+    scan_fallbacks: int = 0       # ... whose overflow guard took the full
+    #                               scan on some shard (the rest of what a
+    #                               scan saw stays on its BatchRecord)
+    # admission: queries' wait from submit until their batch was enqueued
+    admission_wait_s: float = 0.0
+    admitted: int = 0
     insert_time_s: float = 0.0
     # store-layout health (mirrored from the index after every write):
     # a growing tail erodes the CSR win -- each query full-scans it --
@@ -97,6 +159,21 @@ class ServiceStats:
     _lat_ms: list = dataclasses.field(default_factory=list, repr=False)
 
     _LAT_CAP = 8192
+
+    def record_batch(self, rec: BatchRecord) -> None:
+        """Count one answered batch (before its handles resolve, so a
+        client that has its answers also sees the batch counted)."""
+        self.queries += rec.take
+        self.batches += 1
+        self.pad_rows += rec.pad
+        self.drops += rec.drops
+        self.routed_rows += rec.routed_rows
+        key = f"flush_{rec.reason}"
+        setattr(self, key, getattr(self, key) + 1)
+        self.scan_steps += 1
+        self.scan_fallbacks += rec.fallback
+        self.admission_wait_s += rec.admission_wait_s
+        self.admitted += rec.take
 
     def record_latency(self, ms: float) -> None:
         self._lat_ms.append(ms)
@@ -128,8 +205,10 @@ class ServiceStats:
         return self.queries / total if total else 0.0
 
     @property
-    def queries_per_s(self) -> float:
-        return self.queries / self.query_time_s if self.query_time_s else 0.0
+    def admission_wait_ms(self) -> float:
+        """Mean wait of a query from submit until its batch was enqueued."""
+        return (1e3 * self.admission_wait_s / self.admitted
+                if self.admitted else 0.0)
 
     @property
     def inserts_per_s(self) -> float:
@@ -140,7 +219,8 @@ class ServiceStats:
         return (f"queries={self.queries} batches={self.batches} "
                 f"(full={self.flush_full} deadline={self.flush_deadline} "
                 f"manual={self.flush_manual}) occupancy={self.occupancy:.2f} "
-                f"qps={self.queries_per_s:.0f} "
+                f"fallbacks={self.scan_fallbacks}/{self.scan_steps} "
+                f"admission_wait={self.admission_wait_ms:.1f}ms "
                 f"inserts={self.inserts} ips={self.inserts_per_s:.0f} "
                 f"deletes={self.deletes} "
                 f"(rows={self.delete_rows}) "
@@ -204,6 +284,7 @@ class ShardedLSHService:
         self._pending: List[PendingQuery] = []
         self._pending_q: List[np.ndarray] = []
         self._deadline: Optional[float] = None
+        self._seq = 0             # batch number of the next flush
 
     # ------------------------------------------------------------------
     # Queries
@@ -263,7 +344,8 @@ class ShardedLSHService:
         t0 = self._clock()
         try:
             res = self.index.query(jnp.asarray(buf), donate=True,
-                                   k_neighbors=self.k_neighbors)
+                                   k_neighbors=self.k_neighbors,
+                                   batch=self._seq)
         except BaseException:
             # a failed query step must not orphan the handles (result()
             # would spin forever on an empty queue): requeue with their
@@ -274,9 +356,16 @@ class ShardedLSHService:
             self._deadline = prev_deadline
             raise
         now = self._clock()
-        dt = now - t0
-
+        # padded rows still route (their offsets are hashed), so count
+        # only the live rows as the paper's shuffle size
+        rec = BatchRecord(
+            seq=self._seq, reason=reason, take=take, pad=pad,
+            drops=res.drops, routed_rows=int(res.fq[:take].sum()),
+            admission_wait_s=sum(t0 - h.t_submit for h in handles),
+            t_enqueue=t0, t_enqueued=now, counters=res.scan_counters)
+        self._seq += 1
         st = self.stats
+        st.record_batch(rec)
         for i, h in enumerate(handles):
             h.gids = res.topk_gid[i].copy()
             h.dists = res.topk_dist[i].copy()
@@ -284,18 +373,9 @@ class ShardedLSHService:
             h.dist = float(h.dists[0])
             h.n_within_cr = int(res.n_within_cr[i])
             h.fq = int(res.fq[i])
+            h.batch = rec
             h.done = True
             st.record_latency((now - h.t_submit) * 1e3)
-
-        st.queries += take
-        st.batches += 1
-        st.pad_rows += pad
-        st.drops += res.drops
-        # padded rows still route (their offsets are hashed), so count
-        # only the live rows as the paper's shuffle size
-        st.routed_rows += int(res.fq[:take].sum())
-        st.query_time_s += dt
-        setattr(st, f"flush_{reason}", getattr(st, f"flush_{reason}") + 1)
         return take
 
     def drain(self) -> int:

@@ -24,6 +24,10 @@ Backpressure: the admission queue is bounded by ``queue_depth``.
 ``admission="block"`` applies backpressure to producers (put blocks);
 ``admission="reject"`` raises ``AdmissionFull`` and counts the reject
 in ``ServiceStats``.
+
+Spans (``core/spans.py``): each write is an ``lsh.write`` (args ``kind``,
+``rows``) holding the ``lsh.drain_barrier`` that retires the in-flight
+query batches before it; the query path's spans are the pipeline's.
 """
 from __future__ import annotations
 
@@ -35,6 +39,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.core.index import DistributedLSHIndex
+from repro.core.spans import span
 from repro.serving.pipeline import QueryPipeline
 from repro.serving.service import ServiceStats, ShardedLSHService
 
@@ -51,11 +56,11 @@ class AsyncQuery:
     """Future-like handle for one query admitted to the async service.
 
     Exposes the same result surface as ``PendingQuery`` (gids / dists /
-    gid / dist / n_within_cr / fq / done) once resolved.
+    gid / dist / n_within_cr / fq / batch / done) once resolved.
     """
 
     __slots__ = ("_service", "_event", "_error", "done", "gid", "dist",
-                 "gids", "dists", "n_within_cr", "fq", "t_submit")
+                 "gids", "dists", "n_within_cr", "fq", "batch", "t_submit")
 
     def __init__(self, service: "AsyncLSHService", t_submit: float):
         self._service = service
@@ -68,6 +73,7 @@ class AsyncQuery:
         self.dists: Optional[np.ndarray] = None
         self.n_within_cr = 0
         self.fq = 0
+        self.batch = None   # the BatchRecord that answered it
         self.t_submit = t_submit
 
     def _resolved(self) -> None:   # QueryPipeline retire hook
@@ -329,12 +335,16 @@ class AsyncLSHService:
                 self._submit_bucket("full")
         elif kind == "insert":
             _, points, gids, fut = item
-            self.pipeline.drain()   # donation barrier: see _barrier note
-            fut._set(self._writes.insert(points, gids=gids))
+            with span("lsh.write", kind=kind, rows=len(points)):
+                with span("lsh.drain_barrier"):   # see _submit_bucket
+                    self.pipeline.drain()
+                fut._set(self._writes.insert(points, gids=gids))
         elif kind == "delete":
             _, gids, fut = item
-            self.pipeline.drain()
-            fut._set(self._writes.delete(gids))
+            with span("lsh.write", kind=kind, rows=np.size(gids)):
+                with span("lsh.drain_barrier"):
+                    self.pipeline.drain()
+                fut._set(self._writes.delete(gids))
         elif kind == "snapshot":
             _, snap_dir, kw, fut = item
             fut._set(self._snapshot(snap_dir, **kw))

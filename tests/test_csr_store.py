@@ -194,8 +194,8 @@ def test_probe_spans_degenerate_buckets():
 def test_gather_degenerate_buckets_match_full_scan():
     query, store = _degenerate_store()
     kw = dict(query=query, store=store, cr2=100.0, L=1, k=4)
-    td, tg, cnt = ops.bucket_search(**kw)
-    td_f, tg_f, cnt_f = ops.bucket_search(**kw, force_full_scan=True)
+    td, tg, cnt, _ = ops.bucket_search(**kw)
+    td_f, tg_f, cnt_f, _ = ops.bucket_search(**kw, force_full_scan=True)
     np.testing.assert_array_equal(_bits(td), _bits(td_f))
     np.testing.assert_array_equal(np.asarray(tg), np.asarray(tg_f))
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_f))
@@ -218,8 +218,8 @@ def test_gather_tight_radius_filters_inside_bucket():
     within cr count -- identical to the full scan's filter."""
     query, store = _degenerate_store()
     kw = dict(query=query, store=store, cr2=5.0, L=1, k=4)  # rows 1,2 only
-    td, tg, cnt = ops.bucket_search(**kw)
-    td_f, tg_f, cnt_f = ops.bucket_search(**kw, force_full_scan=True)
+    td, tg, cnt, _ = ops.bucket_search(**kw)
+    td_f, tg_f, cnt_f, _ = ops.bucket_search(**kw, force_full_scan=True)
     np.testing.assert_array_equal(_bits(td), _bits(td_f))
     np.testing.assert_array_equal(np.asarray(tg), np.asarray(tg_f))
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_f))

@@ -84,8 +84,8 @@ def _qs(args, qtable=None, ptable=None):
 def test_bucket_search_matches_ref(R, N, d, L):
     query, store = _qs(_bucket_case(jax.random.PRNGKey(R + N), R, N, d, L))
     cr2 = 2.5
-    best_k, gid_k, cnt_k = ops.bucket_search(query=query, store=store,
-                                             cr2=cr2, L=L)
+    best_k, gid_k, cnt_k, _ = ops.bucket_search(query=query, store=store,
+                                                cr2=cr2, L=L)
     best_r, gid_r, cnt_r = ref.bucket_search_ref(query=query, store=store,
                                                  cr2=cr2, L=L)
     np.testing.assert_allclose(np.asarray(best_k), np.asarray(best_r),
@@ -103,8 +103,8 @@ def test_bucket_search_topk_matches_ref(K, R, N, d, L):
     query, store = _qs(_bucket_case(jax.random.PRNGKey(K * 7 + R), R, N,
                                     d, L, frac_match=0.5))
     cr2 = 40.0  # wide threshold so most rows have many hits
-    td_k, tg_k, c_k = ops.bucket_search(query=query, store=store, cr2=cr2,
-                                        L=L, k=K)
+    td_k, tg_k, c_k, _ = ops.bucket_search(query=query, store=store,
+                                           cr2=cr2, L=L, k=K)
     td_r, tg_r, c_r = ref.bucket_search_ref(query=query, store=store,
                                             cr2=cr2, L=L, K=K)
     assert td_k.shape == (R, K) and tg_k.shape == (R, K)
@@ -135,8 +135,8 @@ def test_bucket_search_topk_ties():
     gid = jnp.arange(N, dtype=jnp.int32)[::-1].copy()   # descending
     query, store = _qs((q, jnp.sum(q * q, -1), qb, probe, p,
                         jnp.sum(p * p, -1), pb, gid, pv))
-    td_k, tg_k, cnt = ops.bucket_search(query=query, store=store, cr2=100.0,
-                                        L=L, k=K)
+    td_k, tg_k, cnt, _ = ops.bucket_search(query=query, store=store,
+                                           cr2=100.0, L=L, k=K)
     td_r, tg_r, _ = ref.bucket_search_ref(query=query, store=store,
                                           cr2=100.0, L=L, K=K)
     np.testing.assert_array_equal(np.asarray(tg_k), np.asarray(tg_r))
@@ -157,8 +157,8 @@ def test_bucket_search_table_mask(T):
     ptable = jax.random.randint(ks[1], (N,), 0, T, dtype=jnp.int32)
     cr2 = 40.0
     query, store = _qs(args, qtable=qtable, ptable=ptable)
-    td_k, tg_k, c_k = ops.bucket_search(query=query, store=store, cr2=cr2,
-                                        L=L, k=4)
+    td_k, tg_k, c_k, _ = ops.bucket_search(query=query, store=store,
+                                           cr2=cr2, L=L, k=4)
     td_r, tg_r, c_r = ref.bucket_search_ref(query=query, store=store,
                                             cr2=cr2, L=L, K=4)
     np.testing.assert_allclose(np.asarray(td_k), np.asarray(td_r),
@@ -184,8 +184,8 @@ def test_bucket_search_no_matches():
     args = list(_bucket_case(jax.random.PRNGKey(0), R, N, d, L))
     args[3] = jnp.zeros_like(args[3])  # probe nothing
     query, store = _qs(tuple(args))
-    best, gid, cnt = ops.bucket_search(query=query, store=store, cr2=1.0,
-                                       L=L, k=4)
+    best, gid, cnt, _ = ops.bucket_search(query=query, store=store,
+                                          cr2=1.0, L=L, k=4)
     assert np.all(np.asarray(best) == np.float32(np.finfo(np.float32).max))
     assert np.all(np.asarray(gid) == np.iinfo(np.int32).max)
     assert np.all(np.asarray(cnt) == 0)

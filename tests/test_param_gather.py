@@ -34,6 +34,7 @@ from repro.core import (LSHConfig, Scheme, StackedHashParams, hash_h,
                         pack_buckets, query_offsets, query_offsets_by_table,
                         sample_stacked_params, sample_table_params,
                         shard_key, stacked_base_keys, table_base_key)
+from repro.kernels.ops import SCAN_COUNTERS
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 IMAX = int(np.iinfo(np.int32).max)
@@ -244,7 +245,7 @@ class _FakeIndex:
         self.fail = False
         self.calls = 0
 
-    def query(self, qs, donate=False, k_neighbors=None):
+    def query(self, qs, donate=False, k_neighbors=None, batch=None):
         self.calls += 1
         if self.fail:
             raise RuntimeError("injected query-step failure")
@@ -252,12 +253,13 @@ class _FakeIndex:
         K = k_neighbors or 1
         return dataclasses.make_dataclass("R", [
             "topk_dist", "topk_gid", "n_within_cr", "fq", "query_load",
-            "drops"])(
+            "drops", "scan_counters"])(
                 topk_dist=np.full((b, K), np.inf, np.float32),
                 topk_gid=np.full((b, K), IMAX, np.int32),
                 n_within_cr=np.zeros((b,), np.int64),
                 fq=np.zeros((b,), np.int64),
-                query_load=np.zeros((1,), np.int64), drops=0)
+                query_load=np.zeros((1,), np.int64), drops=0,
+                scan_counters=np.zeros((1, len(SCAN_COUNTERS)), np.int32))
 
 
 def test_flush_failure_requeues_with_original_deadline():

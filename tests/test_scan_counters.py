@@ -1,0 +1,201 @@
+"""What the query scan saw, and the served path's spans.
+
+  * ops level: ``bucket_search`` reports, in ``SCAN_COUNTERS`` order,
+    the overflow fallback, the tiles a row tile needed, the window G,
+    the expanded probe rows with a non-empty span (equal to a numpy count
+    of the distinct non-empty spans ``csr_probe_spans`` gives) and all
+    expanded rows; zeros off the CSR path;
+  * served: a gather window forced too small falls back on every scan
+    step, a roomy one on none, with the same answers; the synchronous
+    flush and the async pipeline count the same on the same calls;
+  * spans: under a CPU profiler trace the spans of one batch carry its
+    number as the arg ``batch``.
+"""
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.compat import make_mesh
+from repro.core import DistributedLSHIndex, LSHConfig, Scheme, store_layout
+from repro.kernels import ops
+from repro.kernels.types import QueryBatch, StoreView
+from repro.serving import AsyncLSHService, ShardedLSHService
+
+COL = {name: i for i, name in enumerate(ops.SCAN_COUNTERS)}
+
+
+def _sorted_store(n=300, d=8, n_buckets=40, seed=0):
+    rng = np.random.default_rng(seed)
+    packed = np.zeros((n, 2), np.int32)
+    packed[:, 1] = np.sort(rng.integers(0, n_buckets, n))
+    table = np.zeros(n, np.int32)
+    bs, be = store_layout.bucket_spans(table, packed)
+    return StoreView.build(
+        jnp.asarray(rng.normal(size=(n, d)).astype(np.float32)),
+        jnp.asarray(packed), jnp.arange(n, dtype=jnp.int32),
+        jnp.ones((n,), jnp.int32), bucket_start=jnp.asarray(bs),
+        bucket_end=jnp.asarray(be), n_sorted=n)
+
+
+def _probes(R=40, L=4, d=8, n_buckets=50, seed=1):
+    """Probes of buckets 0..n_buckets (some absent from the store), some
+    repeated within a row, some switched off."""
+    rng = np.random.default_rng(seed)
+    qb = np.zeros((R, L, 2), np.int32)
+    qb[..., 1] = rng.integers(0, n_buckets, (R, L))
+    qb[:, 1, 1] = qb[:, 0, 1]                    # a repeat in every row
+    probe = (rng.random((R, L)) < 0.8).astype(np.int32)
+    return QueryBatch.build(
+        jnp.asarray(rng.normal(size=(R, d)).astype(np.float32)),
+        jnp.asarray(qb.reshape(R, 2 * L)), jnp.asarray(probe))
+
+
+def test_counters_report_what_the_guard_saw():
+    store, query = _sorted_store(), _probes()
+    R, L = query.probe.shape
+    start, end = (np.asarray(a) for a in ops.csr_probe_spans(query, store))
+    spans = sorted({(r, s, e) for r in range(R)
+                    for s, e in zip(start[r], end[r]) if e > s},
+                   key=lambda t: t[1])
+    # each run of 128 live spans, in start order, is one row tile
+    tn, tr = ops.TILE_N, ops.TILE_R
+    need = max((max((e - 1) // tn for _, _, e in spans[i:i + tr])
+                - min(s // tn for _, s, _ in spans[i:i + tr]) + 1)
+               for i in range(0, len(spans), tr))
+    assert need > 1 and len(spans) > 0
+    for window in (1, need):
+        *_, c = ops.bucket_search(query=query, store=store, cr2=4.0, L=L,
+                                  k=4, window_tiles=window)
+        c = np.asarray(c)
+        assert c.shape == (len(ops.SCAN_COUNTERS),)
+        assert c[COL["probes_live"]] == len(spans)
+        assert c[COL["probes_expanded"]] == -(-R * L // tr) * tr
+        assert c[COL["window_tiles"]] == window
+        assert c[COL["need_tiles"]] == need
+        assert c[COL["fallback"]] == int(need > window)
+
+
+@pytest.mark.parametrize("kw", [dict(force_full_scan=True),
+                                dict(use_kernel=False)])
+def test_counters_are_zero_off_the_csr_path(kw):
+    store, query = _sorted_store(), _probes()
+    *_, c = ops.bucket_search(query=query, store=store, cr2=4.0, L=4, k=4,
+                              **kw)
+    assert not np.asarray(c).any()
+
+
+def _index():
+    cfg = LSHConfig(d=8, k=4, W=1.2, r=0.3, c=2.0, L=4, n_shards=1,
+                    scheme=Scheme.LAYERED, seed=0)
+    idx = DistributedLSHIndex(cfg, make_mesh((1,), ("shard",)),
+                              use_kernel=True, k_neighbors=4)
+    rng = np.random.default_rng(0)
+    data = rng.normal(size=(640, 8)).astype(np.float32)
+    idx.build(jnp.asarray(data))
+    idx.compact()
+    queries = data[:24] + rng.normal(scale=0.05, size=(24, 8)).astype(
+        np.float32)
+    return idx, queries
+
+
+@pytest.fixture(scope="module")
+def served():
+    idx, queries = _index()
+    assert idx.layout["n_sorted"] > 128      # more than one store tile
+    return idx, queries
+
+
+def _serve(svc, queries):
+    handles = svc.submit_batch(queries)
+    svc.drain()
+    return handles
+
+
+@pytest.mark.parametrize("window", ["tight", "roomy"])
+def test_window_decides_the_fallback(served, window, monkeypatch):
+    idx, queries = served
+    n_tiles = -(-idx.store.n_sorted // ops.TILE_N)
+    monkeypatch.setattr(idx, "_gather_window",
+                        lambda n: 1 if window == "tight" else n_tiles)
+    svc = ShardedLSHService(idx, bucket_size=8, max_latency_ms=float("inf"))
+    handles = _serve(svc, queries)
+    st = svc.stats
+    recs = {h.batch.seq: h.batch for h in handles}
+    assert sorted(recs) == [0, 1, 2]
+    assert st.scan_steps == 3 == st.batches
+    assert [r.window_tiles for r in recs.values()] == [
+        1 if window == "tight" else n_tiles] * 3
+    if window == "tight":
+        assert max(r.need_tiles for r in recs.values()) > 1
+        assert st.scan_fallbacks == st.scan_steps
+    else:
+        assert st.scan_fallbacks == 0
+    assert [r.fallback for r in recs.values()] == [window == "tight"] * 3
+    assert all(0 < r.probes_live <= r.probes_expanded
+               for r in recs.values())
+    # the window moves work, never answers
+    ref = idx.query(jnp.asarray(queries[:8]))
+    np.testing.assert_array_equal(
+        np.stack([h.gids for h in handles[:8]]), ref.topk_gid)
+
+
+def test_sync_and_async_count_the_same(served):
+    idx, queries = served
+    sync = ShardedLSHService(idx, bucket_size=8,
+                             max_latency_ms=float("inf"))
+    sync_handles = _serve(sync, queries)
+    with AsyncLSHService(idx, bucket_size=8,
+                         max_latency_ms=float("inf")) as asvc:
+        handles = _serve(asvc, queries)
+        st = asvc.stats
+        # counted before the handles resolved
+        assert all(h.done for h in handles) and st.scan_steps == 3
+    for name in ("scan_steps", "scan_fallbacks", "admitted", "queries",
+                 "batches"):
+        assert getattr(st, name) == getattr(sync.stats, name), name
+    for a, s in zip(handles[::8], sync_handles[::8]):
+        assert a.batch.seq == s.batch.seq
+        np.testing.assert_array_equal(a.batch.counters, s.batch.counters)
+    assert st.admission_wait_s >= 0.0 and st.admitted == 24
+    assert "fallbacks=" in st.summary() and "qps=" not in st.summary()
+    assert not hasattr(st, "query_time_s")
+
+
+def test_spans_of_one_batch_share_its_number(tmp_path):
+    idx, queries = _index()              # its own: the insert writes
+    with AsyncLSHService(idx, bucket_size=8,
+                         max_latency_ms=float("inf")) as svc:
+        _serve(svc, queries[:8])          # compiled outside the trace
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _serve(svc, queries[8:24])
+            svc.insert(queries[:4]).result(timeout=60)
+            svc.drain()
+            n_live = idx.n_live
+            idx.compact()       # the engine is idle: nothing is admitted
+        finally:
+            jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("lsh."):
+                    spans.setdefault(ev.name, []).append(
+                        {k: v for k, v in ev.stats})
+    batches = lambda name: sorted(a["batch"] for a in spans[name])
+    assert batches("lsh.enqueue") == [1, 2]
+    for name in ("lsh.dispatch", "lsh.scan", "lsh.return", "lsh.retire",
+                 "lsh.fetch", "lsh.resolve"):
+        assert batches(name) == [1, 2], name
+    assert {a["reason"] for a in spans["lsh.enqueue"]} == {"full"}
+    assert {a["take"] for a in spans["lsh.enqueue"]} == {8}
+    write, = spans["lsh.write"]
+    assert write["kind"] == "insert" and write["rows"] == 4
+    assert len(spans["lsh.drain_barrier"]) == 1
+    assert spans["lsh.merge"] == [{"n_live": n_live}]
