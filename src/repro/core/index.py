@@ -534,8 +534,22 @@ class DistributedLSHIndex:
         rows = m_local * self.cfg.pairs_per_query()   # summed over tables
         return max(8, int(math.ceil(rows / S * self.slack)))
 
+    def _scan_rows(self, m: int, Cq: int) -> int:
+        """Received rows the scan step searches: the S*Cq receive buffer
+        compacted to its live rows, of which there are at most m*T (one
+        per (qid, table) after the receive-side dedup)."""
+        return min(self.cfg.n_shards * Cq, m * self.cfg.n_tables)
+
     def _gather_window(self, n_expanded: int) -> int:
         """Static CSR gather window (aligned store tiles per row tile).
+
+        ``n_expanded`` is the receive buffer's S*Cq*L expanded probe
+        rows, not the _scan_rows(m, Cq)*L the search gets after
+        compaction.  The smaller count widens G into the range a row tile
+        needs (``random1m`` in bench/: G = 2,687 against 2,661-3,729
+        tiles), so the branch would flip with the queries, and there the
+        CSR branch (gather + tail scan) took 233 ms a step against the
+        fallback's 179 ms on one TPU v5e.
 
         A row tile holds TILE_R expanded probes sorted by span start; its
         window must cover their start spread (~ TILE_R * n_sorted /
@@ -1079,6 +1093,7 @@ class DistributedLSHIndex:
         S, L, T, d = cfg.n_shards, cfg.L, cfg.n_tables, cfg.d
         axis = self.axis
         m_loc = m // S
+        Rc = self._scan_rows(m, Cq)         # received rows the scan searches
         use_kernel = self.use_kernel
         use_csr = self.use_csr
 
@@ -1138,7 +1153,14 @@ class DistributedLSHIndex:
                       store_valid, store_bs, store_be):
             """Stage 2: receive-side hash-once + bucket search + local
             per-qid union across tables.  No collectives.  Also returns
-            what the bucket search saw (``kernels.ops.SCAN_COUNTERS``)."""
+            what the bucket search saw (``kernels.ops.SCAN_COUNTERS``).
+
+            Only the first row per (qid, table) probes anything, so the
+            S*Cq received rows are compacted, in order, to the Rc =
+            min(S*Cq, m*T) rows that can be live before the search.  The
+            bound is exact: the dedup leaves one row per key rid*T + rtab
+            in [0, m*T), and a row's answers do not depend on the other
+            rows of its call, so no answer changes."""
             me = jax.lax.axis_index(axis)
             rq = _i2f(r[:, :d])
             rid = r[:, d]
@@ -1153,6 +1175,9 @@ class DistributedLSHIndex:
             # (qid, table) -- sort-based, no R x R matrix.
             rvalid = first_occurrence_mask(
                 jnp.where(rvalid, rid * T + rtab, IMAX), rvalid)
+            live = jnp.nonzero(rvalid, size=Rc, fill_value=0)[0]
+            rq, rid, rtab = rq[live], rid[live], rtab[live]
+            rvalid = jnp.arange(Rc) < rvalid.sum()
             rid_safe = jnp.where(rvalid, rid, 0)
             rtab_safe = jnp.where(rvalid, rtab, 0)
 

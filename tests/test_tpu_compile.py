@@ -7,6 +7,8 @@ the worker that runs these tests loads the TPU compiler library.  The
 persistent compilation cache is off around the compiles -- entries for a
 described chip cannot be read back without one.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -103,3 +105,12 @@ def test_index_query_scan_compiles_for_v5e_mesh(topo, monkeypatch):
     # both kernels: the CSR gather over the sorted region, the full scan
     # over the tail (and as the overflow fallback)
     assert hlo.count("tpu_custom_call") >= 2, hlo.count("tpu_custom_call")
+    # ... over the received rows compacted to m*T (64 of S*Cq = 580):
+    # one row tile for the full scans, m*T*L expanded rows for the gather
+    Rc = idx._scan_rows(m, Cq)
+    assert Rc == m < S * Cq, (Rc, S * Cq)
+    rows = {name: int(n) for name, n in re.findall(
+        r"%(bucket_\w+?)(?:\.\d+)? = \(f32\[(\d+),", hlo)}
+    assert rows == {"bucket_gather_pallas": Rc * L,
+                    "bucket_search_pallas_tail": 128,
+                    "bucket_search_pallas_fallback": 128}, rows
