@@ -42,9 +42,11 @@ IMAX = int(jnp.iinfo(jnp.int32).max)
 # caller has no bucket statistics to size it from
 DEFAULT_WINDOW_TILES = 4
 
-# the counters ``bucket_search`` returns, in order (see its docstring)
-SCAN_COUNTERS = ("fallback", "need_tiles", "window_tiles", "probes_live",
-                 "probes_expanded")
+# the counters ``bucket_search`` returns, in order (see its docstring):
+# the CSR overflow guard's five, then what the taken path returned
+GUARD_COUNTERS = ("fallback", "need_tiles", "window_tiles", "probes_live",
+                  "probes_expanded")
+SCAN_COUNTERS = GUARD_COUNTERS + ("hits", "filled", "grid_steps")
 
 
 def _on_cpu() -> bool:
@@ -172,8 +174,23 @@ def _full_scan(query_p: QueryBatch, store_view: StoreView, cr2, *,
                                 L=L, K=k, interpret=interpret, name=name)
 
 
-def _no_counters() -> jax.Array:
-    return jnp.zeros((len(SCAN_COUNTERS),), jnp.int32)
+def _grid_steps(rows: int, points: int) -> int:
+    """(row tile, store tile) grid steps of one kernel call over ``rows``
+    query rows and ``points`` store rows, both padded to whole tiles."""
+    return -(-rows // TILE_R) * -(-points // TILE_N)
+
+
+def _counters(guard, cnt, topg, grid_steps) -> jax.Array:
+    """The ``SCAN_COUNTERS`` vector: the guard's five, then the hits
+    within cr, the top-K ranks that hold a gid and the kernels' grid
+    steps, read off what the taken path returned."""
+    return jnp.concatenate([guard, jnp.stack([
+        cnt.sum(dtype=jnp.int32), (topg != IMAX).sum(dtype=jnp.int32),
+        jnp.asarray(grid_steps, jnp.int32)])])
+
+
+def _no_guard() -> jax.Array:
+    return jnp.zeros((len(GUARD_COUNTERS),), jnp.int32)
 
 
 def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
@@ -227,7 +244,7 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
         overflow = jnp.any(need > G)
         base = jnp.clip(base, 0, n_tiles - G)
         # what the guard saw, reported whichever branch runs
-        counters = jnp.stack([
+        guard = jnp.stack([
             overflow.astype(jnp.int32), jnp.maximum(jnp.max(need), 0),
             jnp.int32(G), live.sum(dtype=jnp.int32), jnp.int32(E)])
 
@@ -269,7 +286,12 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
                 interpret=interpret, name="bucket_search_pallas_fallback")
         return td[:R], tg[:R], tc[:R]
 
-    return jax.lax.cond(overflow, run_full, run_csr, None) + (counters,)
+    topd, topg, cnt = jax.lax.cond(overflow, run_full, run_csr, None)
+    R_p = query_p.q.shape[0]
+    csr_steps = _grid_steps(E, G * TILE_N) + (
+        _grid_steps(R_p, cap - ns) if cap > ns else 0)
+    steps = jnp.where(overflow, _grid_steps(R_p, cap), csr_steps)
+    return topd, topg, cnt, _counters(guard, cnt, topg, steps)
 
 
 def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
@@ -287,8 +309,11 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     order of ``SCAN_COUNTERS``, what the CSR path's overflow guard saw:
     whether it fell back to the full scan, the most aligned store tiles a
     row tile's spans needed, the window G it had, the expanded probe rows
-    with a non-empty span and all expanded rows (padding included).
-    Every other path reports zeros.
+    with a non-empty span and all expanded rows (padding included), each
+    zero on every other path; then, on every path, the hits within cr
+    (the sum of ``cnt``), the top-K ranks that hold a gid (summed over
+    rows) and the (row tile, store tile) grid steps the Pallas calls of
+    the path taken ran (0 for the oracle).
 
     Dispatch: a bucket-sorted store (``store.n_sorted > 0``) uses the CSR
     bucket-gather kernel over the sorted region plus a full scan of the
@@ -302,18 +327,22 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     only affects performance, never results.
     """
     if not use_kernel:
-        return ref.bucket_search_ref(query=query, store=store, cr2=cr2,
-                                     L=L, K=k) + (_no_counters(),)
+        topd, topg, cnt = ref.bucket_search_ref(query=query, store=store,
+                                                cr2=cr2, L=L, K=k)
+        return topd, topg, cnt, _counters(_no_guard(), cnt, topg, 0)
     R = query.q.shape[0]
     interpret = _on_cpu()
     query_p = _pad_query(query)
     if store.n_sorted > 0 and not force_full_scan:
         return _csr_search(query, query_p, store, cr2, L=L, k=k,
                            window_tiles=window_tiles, interpret=interpret)
+    cap = store.points.shape[0]
     topd, topg, cnt = _full_scan(
-        query_p, _pad_slice(store, 0, store.points.shape[0]), cr2,
-        L=L, k=k, interpret=interpret)
-    return topd[:R], topg[:R], cnt[:R], _no_counters()
+        query_p, _pad_slice(store, 0, cap), cr2, L=L, k=k,
+        interpret=interpret)
+    topd, topg, cnt = topd[:R], topg[:R], cnt[:R]
+    return topd, topg, cnt, _counters(
+        _no_guard(), cnt, topg, _grid_steps(query_p.q.shape[0], cap))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,

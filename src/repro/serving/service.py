@@ -21,8 +21,9 @@ into that service:
     paper's network-cost metric), with how many scans took the gather
     window's overflow fallback and how long queries waited for admission;
     each answered handle also holds its batch's ``BatchRecord`` (the
-    scan's tiles and probe rows).  WAL-replayed writes go through the
-    same ``insert``/``delete`` entry points, so they are counted too.
+    scan's tiles, probe rows, hits, filled ranks and kernel grid steps).
+    WAL-replayed writes go through the same ``insert``/``delete`` entry
+    points, so they are counted too.
 
 The front-end is synchronous and deterministic (no threads): deadlines
 are checked on entry to ``submit``/``submit_batch``, which is the natural
@@ -62,8 +63,13 @@ class BatchRecord:
     counters: np.ndarray      # (S, len(SCAN_COUNTERS)) per-shard scan view
 
     def _col(self, name: str) -> np.ndarray:
-        return np.asarray(self.counters).reshape(
-            -1, len(SCAN_COUNTERS))[:, _COL[name]]
+        """One counter per shard; a record with fewer columns (one kept
+        from an earlier program, which counted only the first ones)
+        reads 0 for the rest."""
+        c = np.asarray(self.counters)
+        c = c.reshape(-1, c.shape[-1])
+        i = _COL[name]
+        return c[:, i] if i < c.shape[1] else np.zeros(len(c), c.dtype)
 
     @property
     def fallback(self) -> bool:
@@ -90,6 +96,22 @@ class BatchRecord:
     def probes_expanded(self) -> int:
         """Expanded probe rows scanned (padding included), over shards."""
         return int(self._col("probes_expanded").sum())
+
+    @property
+    def hits(self) -> int:
+        """Candidates within cr the scan rows found, over shards."""
+        return int(self._col("hits").sum())
+
+    @property
+    def filled(self) -> int:
+        """Ranks of the scan rows' top-K that hold a gid, over shards."""
+        return int(self._col("filled").sum())
+
+    @property
+    def grid_steps(self) -> int:
+        """(row tile, store tile) grid steps the scan's Pallas calls ran,
+        over shards; 0 on the jnp oracle."""
+        return int(self._col("grid_steps").sum())
 
 
 @dataclasses.dataclass
@@ -137,6 +159,9 @@ class ServiceStats:
     scan_fallbacks: int = 0       # ... whose overflow guard took the full
     #                               scan on some shard (the rest of what a
     #                               scan saw stays on its BatchRecord)
+    scan_hits: int = 0            # candidates within cr the scans found
+    scan_filled: int = 0          # top-K ranks the scans filled
+    scan_grid_steps: int = 0      # kernel grid steps the scans ran
     # admission: queries' wait from submit until their batch was enqueued
     admission_wait_s: float = 0.0
     admitted: int = 0
@@ -172,6 +197,9 @@ class ServiceStats:
         setattr(self, key, getattr(self, key) + 1)
         self.scan_steps += 1
         self.scan_fallbacks += rec.fallback
+        self.scan_hits += rec.hits
+        self.scan_filled += rec.filled
+        self.scan_grid_steps += rec.grid_steps
         self.admission_wait_s += rec.admission_wait_s
         self.admitted += rec.take
 
@@ -220,6 +248,8 @@ class ServiceStats:
                 f"(full={self.flush_full} deadline={self.flush_deadline} "
                 f"manual={self.flush_manual}) occupancy={self.occupancy:.2f} "
                 f"fallbacks={self.scan_fallbacks}/{self.scan_steps} "
+                f"hits={self.scan_hits} filled={self.scan_filled} "
+                f"grid_steps={self.scan_grid_steps} "
                 f"admission_wait={self.admission_wait_ms:.1f}ms "
                 f"inserts={self.inserts} ips={self.inserts_per_s:.0f} "
                 f"deletes={self.deletes} "

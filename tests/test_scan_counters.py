@@ -82,10 +82,17 @@ def test_counters_report_what_the_guard_saw():
 @pytest.mark.parametrize("kw", [dict(force_full_scan=True),
                                 dict(use_kernel=False)])
 def test_counters_are_zero_off_the_csr_path(kw):
+    """The guard's counters are zero there; what the path returned is
+    counted on every path."""
     store, query = _sorted_store(), _probes()
-    *_, c = ops.bucket_search(query=query, store=store, cr2=4.0, L=4, k=4,
-                              **kw)
-    assert not np.asarray(c).any()
+    _, g, cnt, c = ops.bucket_search(query=query, store=store, cr2=4.0,
+                                     L=4, k=4, **kw)
+    c = np.asarray(c)
+    assert not c[:len(ops.GUARD_COUNTERS)].any()
+    assert c[COL["hits"]] == np.asarray(cnt).sum() > 0
+    assert c[COL["filled"]] == (np.asarray(g) != ops.IMAX).sum() > 0
+    # one row tile over the store's 300 rows, padded to 3 store tiles
+    assert c[COL["grid_steps"]] == (3 if kw.get("use_kernel", True) else 0)
 
 
 def _index():
