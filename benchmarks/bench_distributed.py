@@ -146,7 +146,7 @@ for T in TABLES:
     trace[f"compile_s_T{{T}}"] = round(t_cold, 3)
     st = idx.store
     qf = idx._make_query_fn(M, st.capacity, idx._query_capacity(M // 8),
-                            False, K, st.n_sorted, 4)
+                            False, K, st.n_sorted, 4, idx._scan_extent())
     qj = jax.make_jaxpr(qf)(
         queries, jnp.arange(M, dtype=jnp.int32), st.x, st.packed, st.gid,
         st.table, st.valid, st.bucket_start, st.bucket_end)

@@ -107,8 +107,9 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
 
         Cq = idx._query_capacity(n_loc)
         G = idx._gather_window(S * Cq * cfg.L)
+        ext = idx._scan_extent()
         qf = idx._make_query_fn(m, st.capacity, Cq, False, K,
-                                st.n_sorted, G)
+                                st.n_sorted, G, ext)
         qargs = (queries, jnp.arange(m, dtype=jnp.int32), st.x, st.packed,
                  st.gid, st.table, st.valid, st.bucket_start, st.bucket_end)
 
@@ -123,7 +124,7 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
         sdfn = idx._make_query_dispatch_fn(m, Cq, False)
         sdargs = (queries, qids)
         ssfn = idx._make_query_scan_fn(m, st.capacity, Cq, K,
-                                       st.n_sorted, G)
+                                       st.n_sorted, G, ext)
         ssargs = (jnp.zeros((S * S * Cq, cc["d"] + 2), jnp.int32),
                   st.x, st.packed, st.gid, st.table, st.valid,
                   st.bucket_start, st.bucket_end)
@@ -166,6 +167,7 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
             hlo_ctx = {"idx": idx, "ifn": ifn, "iargs": iargs,
                        "qargs": qargs, "m": m, "cap": st.capacity,
                        "Cq": Cq, "K": K, "ns": st.n_sorted, "G": G,
+                       "ext": ext,
                        "ssfn": ssfn, "ssargs": ssargs,
                        "srfn": srfn, "srargs": srargs}
 
@@ -183,7 +185,7 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
     donate_query = seed != "broken-donation"
     qfn = idx._make_query_fn(hlo_ctx["m"], hlo_ctx["cap"], hlo_ctx["Cq"],
                              donate_query, hlo_ctx["K"], hlo_ctx["ns"],
-                             hlo_ctx["G"])
+                             hlo_ctx["G"], hlo_ctx["ext"])
     compiled_query = qfn.lower(*hlo_ctx["qargs"]).compile()
     # the staged stages as the pipeline runs them: dispatch donates the
     # staging buffer; scan/return always donate the routed payloads

@@ -420,6 +420,8 @@ class DistributedLSHIndex:
         # store-layout accounting (host-side mirrors of the LSM state)
         self._sorted_live = 0     # live rows in the sorted region (sum S)
         self._tail_live = 0       # live rows in the unsorted tail (sum S)
+        self._tail_hw = 0         # tail slots ever valid since the last
+        #                           load_rows, one past the highest (max S)
         self._merges = 0          # tail merges performed (incl. compact)
         self._max_bucket = 0      # bucket-occupancy stats of the sorted
         self._mean_bucket = 0.0   # region (sizes the gather window)
@@ -566,6 +568,17 @@ class DistributedLSHIndex:
         need = math.ceil(2.0 * (spread + self._max_bucket) / tn) + 2
         return int(min(n_tiles, max(2, need)))
 
+    def _scan_extent(self) -> int:
+        """The store's live extent, the static bound of every full scan:
+        the sorted region plus the tail up to its high-water mark, no
+        valid row lying past it.  The tail's part is rounded up to a
+        power of two of whole store tiles, so a growing tail recompiles
+        the scan step once per doubling, not once per insert."""
+        st = self.store
+        tiles = -(-self._tail_hw // kops.TILE_N)
+        tail = kops.TILE_N << (tiles - 1).bit_length() if tiles else 0
+        return min(st.capacity, st.n_sorted + tail)
+
     # ------------------------------------------------------------------
     # Store lifecycle
     # ------------------------------------------------------------------
@@ -595,6 +608,7 @@ class DistributedLSHIndex:
         self._n_live = 0
         self._sorted_live = 0
         self._tail_live = 0
+        self._tail_hw = 0
         self._max_bucket = 0
         self._mean_bucket = 0.0
         return self.store
@@ -699,14 +713,17 @@ class DistributedLSHIndex:
             load = nv.sum().astype(jnp.int32)
             stored = fit.sum().astype(jnp.int32)
             stored_t0 = (fit & (rt == 0)).sum().astype(jnp.int32)
+            # one past the highest valid tail slot, from n_sorted
+            tail_hw = jnp.max(jnp.where(nv, jnp.arange(cap) - ns + 1, 0))
             return (nx[None], npk[None], ng[None], nt[None], nk[None],
                     nv[None], load[None], (d_drops + s_drops)[None],
-                    stored[None], stored_t0[None])
+                    stored[None], stored_t0[None],
+                    tail_hw.astype(jnp.int32)[None])
 
         spec = P(axis)
         return jax.jit(shard_map(
             insert_shard, mesh=self.mesh,
-            in_specs=(spec,) * 9, out_specs=(spec,) * 10,
+            in_specs=(spec,) * 9, out_specs=(spec,) * 11,
             check_vma=False,   # pallas out_shape has no vma annotation
         ), donate_argnums=(3, 4, 5, 6, 7, 8))
 
@@ -784,8 +801,9 @@ class DistributedLSHIndex:
         if fn is None:
             fn = self._insert_fns[key] = self._make_insert_fn(
                 n_loc, Ci, cap, st.n_sorted)
-        nx, npk, ng, nt, nk, nv, load, drops, stored, stored_t0 = fn(
-            x, g, valid, st.x, st.packed, st.gid, st.table, st.key, st.valid)
+        (nx, npk, ng, nt, nk, nv, load, drops, stored, stored_t0,
+         tail_hw) = fn(x, g, valid, st.x, st.packed, st.gid, st.table,
+                       st.key, st.valid)
         # inserts only touch tail slots: the CSR columns and the region
         # split carry over unchanged
         self.store = StoreState(x=nx, packed=npk, gid=ng, table=nt, key=nk,
@@ -799,6 +817,8 @@ class DistributedLSHIndex:
         self._drops += n_drops
         self._n_live += rows_stored
         self._tail_live += rows_stored
+        # deletes never lower the mark: a tombstone stays inside it
+        self._tail_hw = max(self._tail_hw, int(np.asarray(tail_hw).max()))
         result = InsertResult(shard_load=np.asarray(load), drops=n_drops,
                               n_inserted=n_stored, rows_stored=rows_stored,
                               capacity=cap, gid_start=gid_start)
@@ -1026,6 +1046,7 @@ class DistributedLSHIndex:
         self._n_live = n
         self._sorted_live = n
         self._tail_live = 0
+        self._tail_hw = 0
         return counts
 
     def compact(self) -> CompactResult:
@@ -1064,6 +1085,7 @@ class DistributedLSHIndex:
             "n_sorted": 0 if st is None else st.n_sorted,
             "sorted_rows": self._sorted_live,
             "tail_rows": self._tail_live,
+            "tail_hw": self._tail_hw,
             "merges": self._merges,
             "max_bucket": self._max_bucket,
             "mean_bucket": self._mean_bucket,
@@ -1075,7 +1097,7 @@ class DistributedLSHIndex:
     # separately-invocable staged path the serving pipeline overlaps.
     # ------------------------------------------------------------------
     def _query_bodies(self, m: int, Cq: int, cap: int, K: int, ns: int,
-                      G: int):
+                      G: int, extent: int):
         """Build the three per-shard stage bodies of the query step.
 
         ``_make_query_fn`` composes all three inside ONE shard_map (the
@@ -1087,6 +1109,7 @@ class DistributedLSHIndex:
         the two all_to_all boundaries; stage payloads are exact int32
         buffers (floats bitcast), so no precision is lost crossing a
         boundary and staged results are bitwise identical (tested).
+        ``extent`` (``_scan_extent``) bounds the kernels' full scans.
         """
         cfg = self.cfg
         sparams, skeys = self.stacked_params, self.stacked_keys
@@ -1213,7 +1236,7 @@ class DistributedLSHIndex:
                     store_packed, jnp.int32),
                 gid=store_gid, valid=store_valid.astype(jnp.int32),
                 table=store_table, bucket_start=store_bs,
-                bucket_end=store_be, n_sorted=ns)
+                bucket_end=store_be, n_sorted=ns, extent=extent)
             row_d, row_g, row_emit, counters = kops.bucket_search(
                 query=qbatch, store=sview,
                 cr2=float(np.float32((cfg.c * cfg.r) ** 2)), L=L, k=K,
@@ -1263,9 +1286,9 @@ class DistributedLSHIndex:
         return dispatch_body, scan_body, return_body
 
     def _make_query_fn(self, m: int, cap: int, Cq: int, donate: bool,
-                       K: int, ns: int, G: int):
+                       K: int, ns: int, G: int, extent: int):
         dispatch_body, scan_body, return_body = self._query_bodies(
-            m, Cq, cap, K, ns, G)
+            m, Cq, cap, K, ns, G, extent)
 
         def query_shard(q_loc, qid_loc, store_x, store_packed, store_gid,
                         store_table, store_valid, store_bs, store_be):
@@ -1286,8 +1309,8 @@ class DistributedLSHIndex:
         ), donate_argnums=(0,) if donate else ())
 
     def _make_query_dispatch_fn(self, m: int, Cq: int, donate: bool):
-        # cap/K/ns/G shape only the scan/return bodies; any values do
-        dispatch_body, _, _ = self._query_bodies(m, Cq, 0, 1, 0, 1)
+        # cap/K/ns/G/extent shape only the scan/return bodies
+        dispatch_body, _, _ = self._query_bodies(m, Cq, 0, 1, 0, 1, 0)
 
         def dispatch_shard(q_loc, qid_loc):
             r, fq_local, drops = dispatch_body(q_loc, qid_loc)
@@ -1301,8 +1324,8 @@ class DistributedLSHIndex:
         ), donate_argnums=(0,) if donate else ())
 
     def _make_query_scan_fn(self, m: int, cap: int, Cq: int, K: int,
-                            ns: int, G: int):
-        _, scan_body, _ = self._query_bodies(m, Cq, cap, K, ns, G)
+                            ns: int, G: int, extent: int):
+        _, scan_body, _ = self._query_bodies(m, Cq, cap, K, ns, G, extent)
 
         def scan_shard(r, store_x, store_packed, store_gid, store_table,
                        store_valid, store_bs, store_be):
@@ -1320,8 +1343,8 @@ class DistributedLSHIndex:
         ), donate_argnums=(0,))   # the routed recv buffer dies here
 
     def _make_query_return_fn(self, m: int, K: int):
-        # Cq/cap/ns/G shape only the dispatch/scan bodies
-        _, _, return_body = self._query_bodies(m, 8, 0, K, 0, 1)
+        # Cq/cap/ns/G/extent shape only the dispatch/scan bodies
+        _, _, return_body = self._query_bodies(m, 8, 0, K, 0, 1, 0)
 
         def return_shard(ret):
             return return_body(ret)
@@ -1363,13 +1386,14 @@ class DistributedLSHIndex:
             Cq = self._query_capacity(m_loc)
             st = self.store
             G = self._gather_window(S * Cq * cfg.L)
+            ext = self._scan_extent()
 
-            key = (m, st.capacity, Cq, donate, K, st.n_sorted, G,
+            key = (m, st.capacity, Cq, donate, K, st.n_sorted, G, ext,
                    self.use_csr)
             fn = self._query_fns.get(key)
             if fn is None:
                 fn = self._query_fns[key] = self._make_query_fn(
-                    m, st.capacity, Cq, donate, K, st.n_sorted, G)
+                    m, st.capacity, Cq, donate, K, st.n_sorted, G, ext)
             qids = jnp.arange(m, dtype=jnp.int32)
             # each shard returned exactly its own qids' results (the routed
             # return path); the sharded outputs concatenate to (m, K)
@@ -1435,12 +1459,13 @@ class DistributedLSHIndex:
             st = self.store
             G = self._gather_window(
                 self.cfg.n_shards * disp.Cq * self.cfg.L)
+            ext = self._scan_extent()
             key = ("scan", disp.m, st.capacity, disp.Cq, K, st.n_sorted, G,
-                   self.use_csr)
+                   ext, self.use_csr)
             fn = self._query_fns.get(key)
             if fn is None:
                 fn = self._query_fns[key] = self._make_query_scan_fn(
-                    disp.m, st.capacity, disp.Cq, K, st.n_sorted, G)
+                    disp.m, st.capacity, disp.Cq, K, st.n_sorted, G, ext)
             ret, recv_load, counters = fn(
                 disp.recv, st.x, st.packed, st.gid, st.table, st.valid,
                 st.bucket_start, st.bucket_end)

@@ -44,7 +44,9 @@ gather kernel's results are bitwise identical to the full scan's.
 
 Grid: (row tiles, point tiles); the point axis is minor-most, so the
 output blocks for a row tile are revisited across point tiles and act as
-the running accumulator (standard TPU streaming-reduction pattern).
+the running accumulator (standard TPU streaming-reduction pattern).  The
+full scan's point axis may stop short of the store (``n_tiles``): the
+grid, not the arrays, is bounded.
 """
 from __future__ import annotations
 
@@ -205,10 +207,12 @@ def _outputs(R: int, K: int):
             jax.ShapeDtypeStruct((R, 1), jnp.int32)]
 
 
-@functools.partial(jax.jit, static_argnames=("L", "K", "interpret", "name"))
+@functools.partial(jax.jit, static_argnames=("L", "K", "interpret", "name",
+                                             "n_tiles"))
 def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
                          L: int, K: int = 1, interpret: bool = False,
-                         name: str = "bucket_search_pallas"):
+                         name: str = "bucket_search_pallas",
+                         n_tiles: int | None = None):
     """Streaming masked top-K NN scan over EVERY stored row (full scan).
 
     Args (all keyword-only):
@@ -222,6 +226,10 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
       K: neighbours to keep per row (static).
       name: the kernel's name in the compiled program and the device
         trace (static); each call site gives its own.
+      n_tiles: store tiles to visit, rows [0, n_tiles * TILE_N) (static;
+        default all N rows, N a tile multiple).  Only the grid stops
+        there: the store columns are read in place, never sliced, and
+        the rows past it must hold nothing valid.
     Returns:
       topd (R, K) f32 masked distance^2, ascending (F32_MAX sentinel pad),
       topg (R, K) int32 gids (IMAX sentinel pad),
@@ -232,14 +240,17 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
     """
     R, d = query.q.shape
     N = store.points.shape[0]
-    assert R % TILE_R == 0 and N % TILE_N == 0, (R, N)
+    if n_tiles is None:
+        assert N % TILE_N == 0, N
+        n_tiles = N // TILE_N
+    assert R % TILE_R == 0 and 1 <= n_tiles * TILE_N <= N, (R, n_tiles, N)
     assert 1 <= K <= TILE_N, K
     KP = k_lanes(K)
     rows = lambda w: pl.BlockSpec((TILE_R, w), lambda i, j: (i, 0))
     pts = pl.BlockSpec((1, TILE_N), lambda i, j: (0, j))
     topd, topg, cnt = pl.pallas_call(
         functools.partial(_bucket_search_kernel, L=L, K=K),
-        grid=(R // TILE_R, N // TILE_N),
+        grid=(R // TILE_R, n_tiles),
         in_specs=[
             rows(d), rows(1), rows(L), rows(L), rows(L), rows(1),
             pl.BlockSpec((TILE_N, d), lambda i, j: (j, 0)),

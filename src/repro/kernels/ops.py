@@ -16,7 +16,9 @@ kernel.  The CSR path's results are bitwise identical to the full scan
 (same dot_general tiles, same exact top-K selection), and a traced
 overflow guard falls back to the full scan whenever a row tile's spans
 do not fit the static window, so correctness never depends on the
-window budget.
+window budget.  Every full scan -- the tail's, the fallback's and the
+unsorted store's -- stops at the store's live extent (``StoreView.extent``),
+past which no row is valid.
 """
 from __future__ import annotations
 
@@ -166,12 +168,26 @@ def csr_probe_spans(query: QueryBatch, store: StoreView
     return jnp.where(on, start, zero), jnp.where(on, end, zero)
 
 
-def _full_scan(query_p: QueryBatch, store_view: StoreView, cr2, *,
-               L: int, k: int, interpret: bool,
+def _extent(store: StoreView) -> int:
+    """Rows [0, extent) the full scans visit, at least one: no valid
+    row lies past them."""
+    n = store.n_rows
+    return n if store.extent is None else min(max(store.extent, 1), n)
+
+
+def _full_scan(query_p: QueryBatch, store: StoreView, lo: int, hi: int,
+               cr2, *, L: int, k: int, interpret: bool,
                name: str = "bucket_search_pallas"):
-    """Full-scan kernel over an (already padded) store view."""
-    return bucket_search_pallas(query=query_p, store=store_view, cr2=cr2,
-                                L=L, K=k, interpret=interpret, name=name)
+    """Full-scan kernel over store rows [lo, hi), hi > lo.  From row 0,
+    whole tiles that stay inside the store are scanned in place, only
+    the kernel's grid bounded; anything else scans a padded copy of the
+    slice (padded rows invalid)."""
+    tiles = -(-(hi - lo) // TILE_N)
+    view = (store if lo == 0 and tiles * TILE_N <= store.n_rows
+            else _pad_slice(store, lo, hi))
+    return bucket_search_pallas(query=query_p, store=view, cr2=cr2, L=L,
+                                K=k, interpret=interpret, name=name,
+                                n_tiles=tiles)
 
 
 def _grid_steps(rows: int, points: int) -> int:
@@ -199,7 +215,7 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
     """CSR path: span lookup -> sorted probe expansion -> windowed gather
     over the sorted region + full scan of the tail, exact-merged."""
     R = query.q.shape[0]
-    ns, cap = store.n_sorted, store.points.shape[0]
+    ns, ext = store.n_sorted, _extent(store)
     n_tiles = -(-ns // TILE_N)
     G = max(1, min(window_tiles, n_tiles))
 
@@ -266,10 +282,10 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
             cand_d = rd.reshape(R, L * k)
             cand_g = rg.reshape(R, L * k)
             cnt = rc.reshape(R, L).sum(axis=1)
-        if cap > ns:                       # unsorted insert tail
+        if ext > ns:                       # unsorted insert tail
             with jax.named_scope("tail"):
                 td, tg, tc = _full_scan(
-                    query_p, _pad_slice(store, ns, cap), cr2, L=L, k=k,
+                    query_p, store, ns, ext, cr2, L=L, k=k,
                     interpret=interpret, name="bucket_search_pallas_tail")
             cand_d = jnp.concatenate([cand_d, td[:R]], axis=1)
             cand_g = jnp.concatenate([cand_g, tg[:R]], axis=1)
@@ -282,15 +298,15 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
     def run_full(_):
         with jax.named_scope("fallback"):
             td, tg, tc = _full_scan(
-                query_p, _pad_slice(store, 0, cap), cr2, L=L, k=k,
-                interpret=interpret, name="bucket_search_pallas_fallback")
+                query_p, store, 0, ext, cr2, L=L, k=k, interpret=interpret,
+                name="bucket_search_pallas_fallback")
         return td[:R], tg[:R], tc[:R]
 
     topd, topg, cnt = jax.lax.cond(overflow, run_full, run_csr, None)
     R_p = query_p.q.shape[0]
     csr_steps = _grid_steps(E, G * TILE_N) + (
-        _grid_steps(R_p, cap - ns) if cap > ns else 0)
-    steps = jnp.where(overflow, _grid_steps(R_p, cap), csr_steps)
+        _grid_steps(R_p, ext - ns) if ext > ns else 0)
+    steps = jnp.where(overflow, _grid_steps(R_p, ext), csr_steps)
     return topd, topg, cnt, _counters(guard, cnt, topg, steps)
 
 
@@ -324,7 +340,9 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     are tested against, and the XLA path for sharded lowering).
     ``window_tiles`` sizes the gather window (see bucket_gather_pallas);
     oversized spans trigger the traced full-scan fallback, so the value
-    only affects performance, never results.
+    only affects performance, never results.  The kernels' full scans
+    visit rows [0, ``store.extent``) only (and the tail's [n_sorted,
+    extent)); the oracle reads every row.
     """
     if not use_kernel:
         topd, topg, cnt = ref.bucket_search_ref(query=query, store=store,
@@ -336,13 +354,12 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     if store.n_sorted > 0 and not force_full_scan:
         return _csr_search(query, query_p, store, cr2, L=L, k=k,
                            window_tiles=window_tiles, interpret=interpret)
-    cap = store.points.shape[0]
-    topd, topg, cnt = _full_scan(
-        query_p, _pad_slice(store, 0, cap), cr2, L=L, k=k,
-        interpret=interpret)
+    ext = _extent(store)
+    topd, topg, cnt = _full_scan(query_p, store, 0, ext, cr2, L=L, k=k,
+                                 interpret=interpret)
     topd, topg, cnt = topd[:R], topg[:R], cnt[:R]
     return topd, topg, cnt, _counters(
-        _no_guard(), cnt, topg, _grid_steps(query_p.q.shape[0], cap))
+        _no_guard(), cnt, topg, _grid_steps(query_p.q.shape[0], ext))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,

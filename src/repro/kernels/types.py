@@ -67,6 +67,10 @@ class StoreView:
     tail, scanned by the full-scan kernel.  ``n_sorted == 0`` marks a
     fully unsorted store (the pre-CSR layout); the CSR arrays may then
     be None and every consumer falls back to the full scan.
+
+    ``extent`` (static) is the store's live extent: no row at or past it
+    is valid, so the kernels' full scans stop there (None = all N rows).
+    The jnp oracle ignores ``extent`` and reads every row.
     """
 
     points: jax.Array   # (N, d) float32 stored points
@@ -79,6 +83,7 @@ class StoreView:
     bucket_start: Optional[jax.Array] = None  # (N,) int32 CSR span start
     bucket_end: Optional[jax.Array] = None    # (N,) int32 CSR span end
     n_sorted: int = 0   # static: rows [0, n_sorted) are bucket-sorted
+    extent: Optional[int] = None   # static: no valid row at or past it
 
     @classmethod
     def build(cls, points, buckets, gid, valid, table=None, key=None,
@@ -100,8 +105,9 @@ class StoreView:
     def tree_flatten(self):
         return ((self.points, self.psq, self.buckets, self.gid, self.valid,
                  self.table, self.key, self.bucket_start, self.bucket_end),
-                self.n_sorted)
+                (self.n_sorted, self.extent))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, n_sorted=aux)
+        n_sorted, extent = aux
+        return cls(*children, n_sorted=n_sorted, extent=extent)

@@ -8,6 +8,11 @@
     the sorted region;
   * the same bitwise identity holds after an elastic restore onto a
     different shard count (subprocess, 8 host devices);
+  * the kernels' full scans stop at the store's live extent (the tail's
+    high-water mark): after every mutation sequence that moves the mark
+    the bounded kernels answer bitwise as the kernels over every slot,
+    and as the jnp oracle, which scans every slot (its distances to the
+    kernels' tolerance);
   * kernel unit tests on a hand-built sorted store: empty bucket,
     single-row bucket, fully tombstoned bucket -- spans and results.
 """
@@ -146,6 +151,146 @@ def test_csr_bitwise_after_elastic_restore():
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = os.path.join(_REPO, "src")
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
+                         capture_output=True, text=True, env=env,
+                         timeout=900)
+    assert out.returncode == 0, out.stdout + "\n" + out.stderr
+    assert "OK" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# The full scans stop at the live extent; answers do not move
+# ---------------------------------------------------------------------------
+
+EXTENT_CASES = ["tail", "reuse_tombstones", "grow_past_pow2", "compact",
+                "legacy", "force_full_scan", "two_shards"]
+GRID_STEPS = ops.SCAN_COUNTERS.index("grid_steps")
+
+
+def _extent_answers(idx, queries, ext):
+    """The query's answers and scan counters with the full scans bounded
+    at ``ext`` (the kernels) or unbounded (``ext=None``: the oracle)."""
+    idx._query_fns.clear()              # keyed on neither knob below
+    idx.use_kernel = ext is not None
+    idx._scan_extent = lambda: ext
+    try:
+        r = idx.query(queries)
+    finally:
+        del idx._scan_extent
+        idx.use_kernel = True
+    return r
+
+
+def _check_extent(idx, queries):
+    """The bounded kernels against the kernels over every slot and the
+    oracle, through the CSR gather (when the store has a sorted region)
+    and the full-scan fallback a one-tile window forces."""
+    st = idx.store
+    ext, cap = idx._scan_extent(), st.capacity
+    hw = idx.layout["tail_hw"]
+    assert st.n_sorted + hw <= ext < cap, (st.n_sorted, hw, ext, cap)
+    # no valid row past the mark, and the mark is the highest valid row
+    valid = np.asarray(st.valid)
+    assert not valid[:, st.n_sorted + hw:].any()
+    assert hw == 0 or valid[:, st.n_sorted + hw - 1].any()
+    oracle = _extent_answers(idx, queries, None)
+    assert oracle.n_within_cr.sum() > 0
+    windows = ["default"]
+    if st.n_sorted and idx.use_csr:
+        windows.append("tight")
+    for window in windows:
+        if window == "tight":
+            idx._gather_window = lambda n: 1
+        try:
+            got = _extent_answers(idx, queries, ext)
+            full = _extent_answers(idx, queries, cap)
+        finally:
+            idx.__dict__.pop("_gather_window", None)
+        np.testing.assert_array_equal(_bits(got.topk_dist),
+                                      _bits(full.topk_dist))
+        # the oracle's distances round as the kernels' do not
+        np.testing.assert_allclose(got.topk_dist, oracle.topk_dist,
+                                   rtol=1e-5, atol=1e-5)
+        for want in (full, oracle):
+            np.testing.assert_array_equal(got.topk_gid, want.topk_gid)
+            np.testing.assert_array_equal(got.n_within_cr, want.n_within_cr)
+        fell_back = got.scan_counters[:, 0].any()
+        if window == "tight" or not (st.n_sorted and idx.use_csr):
+            # a full scan of the store: row tiles x extent tiles
+            assert window == "default" or fell_back
+            tiles = lambda n: -(-n // ops.TILE_N)
+            rows = got.scan_counters[:, GRID_STEPS] // tiles(ext)
+            assert (rows >= 1).all()
+            np.testing.assert_array_equal(
+                got.scan_counters[:, GRID_STEPS], rows * tiles(ext))
+            np.testing.assert_array_equal(
+                full.scan_counters[:, GRID_STEPS], rows * tiles(cap))
+
+
+def _extent_case(case):
+    """Run one mutation sequence and check the bounded scans after it."""
+    S = 2 if case == "two_shards" else 1
+    cfg = LSHConfig(d=16, k=4, W=1.2, r=0.3, c=4.0, L=4, n_shards=S,
+                    scheme=Scheme.LAYERED, seed=0)
+    mesh = make_mesh((S,), ("shard",))
+    data, _, _ = planted_random(n=1536, m=1, d=16, r=0.3, seed=1)
+    rng = np.random.default_rng(2)
+    near = np.concatenate([rng.choice(640, 16), 640 + rng.choice(896, 16)])
+    queries = jnp.asarray(data[near] + rng.normal(
+        scale=0.3 / 4, size=(32, 16)).astype(np.float32))
+    data = jnp.asarray(data)
+    idx = DistributedLSHIndex(cfg, mesh, use_kernel=True, k_neighbors=4,
+                              merge_min_rows=10 ** 9)
+    if case == "legacy":
+        idx.build(data[:900])
+        assert idx.store.n_sorted == 0 and idx.layout["tail_hw"] >= 900 // S
+        return _check_extent(idx, queries)
+    idx.build(data[:640])
+    idx.compact()
+    if case == "grow_past_pow2":
+        idx.insert(data[640:700])          # one tile of tail
+        assert idx._scan_extent() == idx.store.n_sorted + ops.TILE_N
+        cap = idx.store.capacity
+        idx.insert(data[700:1536])         # past 4 tiles, and the capacity
+        assert idx.store.capacity > cap
+        assert idx._scan_extent() == idx.store.n_sorted + 8 * ops.TILE_N
+        return _check_extent(idx, queries)
+    idx.insert(data[640:900])
+    hw = idx.layout["tail_hw"]
+    assert hw == 260 // S if S == 1 else hw > 0
+    if case == "reuse_tombstones":
+        idx.delete(np.arange(700, 760))
+        assert idx.layout["tail_hw"] == hw      # deletes keep the mark
+        idx.insert(data[900:950])               # into the freed slots
+        assert idx.layout["tail_hw"] == hw
+    elif case == "compact":
+        idx.compact()
+        assert idx.layout["tail_hw"] == 0
+        assert idx._scan_extent() == idx.store.n_sorted
+    elif case == "force_full_scan":
+        idx.use_csr = False
+    elif case == "two_shards":
+        # the tails differ: the SPMD scan takes the fuller shard's mark
+        valid = np.asarray(idx.store.valid)[:, idx.store.n_sorted:]
+        marks = [int(np.flatnonzero(v).max()) + 1 for v in valid]
+        assert marks[0] != marks[1] and hw == max(marks), (marks, hw)
+    return _check_extent(idx, queries)
+
+
+@pytest.mark.parametrize("case", EXTENT_CASES)
+def test_bounded_scans_answer_as_the_unbounded(case):
+    if case != "two_shards":
+        return _extent_case(case)
+    script = f"""
+    import sys
+    sys.path.insert(0, {os.path.join(_REPO, "tests")!r})
+    from test_csr_store import _extent_case
+    _extent_case("two_shards")
+    print("OK")
+    """
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["PYTHONPATH"] = os.path.join(_REPO, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
                          capture_output=True, text=True, env=env,

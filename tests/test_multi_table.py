@@ -211,7 +211,7 @@ for T in (1, 2, 4):
     assert c == {"all_to_all": 1}, (T, c)
 
     qf = idx._make_query_fn(64, st.capacity, idx._query_capacity(8),
-                            False, 4, st.n_sorted, 4)
+                            False, 4, st.n_sorted, 4, idx._scan_extent())
     c = jaxpr_pass.collective_counts(jax.make_jaxpr(qf)(
         queries[:64, :32], jnp.arange(64, dtype=jnp.int32),
         st.x, st.packed, st.gid, st.table, st.valid,

@@ -199,7 +199,8 @@ def test_query_jaxpr_size_flat_in_tables():
         idx.build(data)
         st = idx.store
         qf = idx._make_query_fn(64, st.capacity, idx._query_capacity(8),
-                                False, 4, st.n_sorted, 4)
+                                False, 4, st.n_sorted, 4,
+                                idx._scan_extent())
         q_eqns[T] = jaxpr_pass.eqn_count(jax.make_jaxpr(qf)(
             queries[:64], jnp.arange(64, dtype=jnp.int32),
             st.x, st.packed, st.gid, st.table, st.valid,

@@ -7,7 +7,9 @@
     expanded rows; zeros off the CSR path;
   * served: a gather window forced too small falls back on every scan
     step, a roomy one on none, with the same answers; the synchronous
-    flush and the async pipeline count the same on the same calls;
+    flush and the async pipeline count the same on the same calls; the
+    fallback's grid follows the store's live extent, and the scan step
+    compiles once per power-of-two bucket of the tail's tiles;
   * spans: under a CPU profiler trace the spans of one batch carry its
     number as the arg ``batch``.
 """
@@ -170,6 +172,36 @@ def test_sync_and_async_count_the_same(served):
     assert st.admission_wait_s >= 0.0 and st.admitted == 24
     assert "fallbacks=" in st.summary() and "qps=" not in st.summary()
     assert not hasattr(st, "query_time_s")
+
+
+def test_grid_steps_follow_the_extent_and_one_bucket_compiles_once(
+        monkeypatch):
+    """A full scan's grid is its row tiles times the store tiles up to
+    the live extent, not the capacity's; inserts that keep the tail in
+    one power-of-two bucket of tiles add no scan compile, and one that
+    crosses into the next adds one."""
+    idx, queries = _index()              # its own: the inserts write
+    monkeypatch.setattr(idx, "_gather_window", lambda n: 1)   # fall back
+    tiles = lambda n: -(-n // ops.TILE_N)
+    scan_keys = lambda: sum(key[0] == "scan" for key in idx._query_fns)
+    more = np.random.default_rng(3).normal(size=(150, 8)).astype(
+        np.float32)
+    with AsyncLSHService(idx, bucket_size=8,
+                         max_latency_ms=float("inf")) as svc:
+        steps, keys = [], []
+        for rows in (queries[:4], queries[4:20], more):
+            svc.insert(rows).result(timeout=60)
+            rec = _serve(svc, queries[:8])[0].batch
+            assert rec.fallback
+            steps.append(rec.grid_steps)
+            keys.append(scan_keys())
+    st = idx.store
+    assert idx.layout["tail_hw"] == 170 and st.capacity > st.n_sorted + 256
+    # one row tile (8 queries) over 1, 1 and 2 tiles of tail
+    assert steps == [tiles(st.n_sorted + ops.TILE_N)] * 2 + [
+        tiles(st.n_sorted + 2 * ops.TILE_N)]
+    assert steps[-1] < tiles(st.capacity)
+    assert keys == [keys[0], keys[0], keys[0] + 1]
 
 
 def test_spans_of_one_batch_share_its_number(tmp_path):

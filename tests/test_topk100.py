@@ -66,14 +66,18 @@ def _grid_steps(idx, rec):
     """The kernels' (row tile, store tile) grid of the branch taken: the
     scan searches one row tile (the bucket's 64 live rows); the gather
     expands them by L into row tiles of G store tiles each, beside the
-    tail's full scan."""
+    tail's full scan.  Both full scans stop at the store's live extent:
+    the sorted region plus the tail's high-water mark rounded up to a
+    power of two of tiles, short of the capacity here."""
     tiles = lambda n: -(-n // ops.TILE_N)
     st = idx.store
+    ext = st.n_sorted + ops.TILE_N * 2 ** (
+        tiles(idx.layout["tail_hw"]) - 1).bit_length()
+    assert ext == idx._scan_extent() < st.capacity
     if rec.fallback:
-        return tiles(st.capacity)
+        return tiles(ext)
     expanded = -(-BUCKET * CFG.L // ops.TILE_R)
-    return (expanded * rec.window_tiles
-            + tiles(st.capacity - st.n_sorted))
+    return expanded * rec.window_tiles + tiles(ext - st.n_sorted)
 
 
 def _serve_and_check(idx, x, q, deleted=()):

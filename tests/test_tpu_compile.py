@@ -97,7 +97,7 @@ def test_index_query_scan_compiles_for_v5e_mesh(topo, monkeypatch):
     sh = NamedSharding(mesh, P("shard"))
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
     i32 = jnp.int32
-    fn = idx._make_query_scan_fn(m, cap, Cq, 10, ns, 4)
+    fn = idx._make_query_scan_fn(m, cap, Cq, 10, ns, 4, cap)
     hlo = _hlo(fn, s((S * S * Cq, d + 2), i32), s((S, cap, d), jnp.float32),
                s((S, cap, 2), jnp.uint32), s((S, cap), i32),
                s((S, cap), i32), s((S, cap), jnp.bool_), s((S, cap), i32),
@@ -114,3 +114,32 @@ def test_index_query_scan_compiles_for_v5e_mesh(topo, monkeypatch):
     assert rows == {"bucket_gather_pallas": Rc * L,
                     "bucket_search_pallas_tail": 128,
                     "bucket_search_pallas_fallback": 128}, rows
+
+
+def test_index_full_scans_stop_at_the_extent_in_place(topo, monkeypatch):
+    """With a live extent short of the capacity, the scan step's
+    fallback scans the store columns in place, its grid bounded: no pad,
+    slice or copy of the stored points feeds it.  At an extent that
+    reaches a capacity off the tile grid it still scans a padded copy."""
+    from repro.core import DistributedLSHIndex, LSHConfig, Scheme
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "_on_cpu", lambda: False)
+    S, d, cap, ns, m = 4, 128, 2000, 1000, 64
+    mesh = Mesh(np.array(topo.devices[:S]), ("shard",))
+    cfg = LSHConfig(d=d, k=10, W=3.0, r=0.3, c=5.0, L=L, n_shards=S,
+                    scheme=Scheme.LAYERED)
+    idx = DistributedLSHIndex(cfg, mesh, use_kernel=True, k_neighbors=10)
+    Cq = idx._query_capacity(m // S)
+    sh = NamedSharding(mesh, P("shard"))
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    i32 = jnp.int32
+    args = (s((S * S * Cq, d + 2), i32), s((S, cap, d), jnp.float32),
+            s((S, cap, 2), jnp.uint32), s((S, cap), i32), s((S, cap), i32),
+            s((S, cap), jnp.bool_), s((S, cap), i32), s((S, cap), i32))
+    points_copy = re.compile(
+        r"f32\[\d+,%d\]\S* (?:pad|slice|copy)\([^\n]*fallback" % d)
+    for ext in (ns + 128, cap):
+        hlo = _hlo(idx._make_query_scan_fn(m, cap, Cq, 10, ns, 4, ext),
+                   *args)
+        assert "bucket_search_pallas_fallback" in hlo
+        assert bool(points_copy.search(hlo)) == (ext == cap), ext
