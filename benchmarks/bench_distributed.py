@@ -118,6 +118,7 @@ import json, time
 import jax, numpy as np
 import jax.numpy as jnp
 from repro.analysis import jaxpr_pass, load_contracts
+from repro.analysis.check import query_stages
 from repro.compat import make_mesh
 from repro.core import (LSHConfig, Scheme, DistributedLSHIndex,
                         lsh_topk_reference, nearest_neighbors, recall_at_k,
@@ -145,18 +146,19 @@ for T in TABLES:
     t0 = time.monotonic(); idx.query(queries); t_cold = time.monotonic()-t0
     trace[f"compile_s_T{{T}}"] = round(t_cold, 3)
     st = idx.store
-    qf = idx._make_query_fn(M, st.capacity, idx._query_capacity(M // 8),
-                            False, K, st.n_sorted, 4, idx._scan_extent())
-    qj = jax.make_jaxpr(qf)(
-        queries, jnp.arange(M, dtype=jnp.int32), st.x, st.packed, st.gid,
-        st.table, st.valid, st.bucket_start, st.bucket_end)
     # structural counters from the analyzer (primitive identity, not
-    # text regex); counts are recorded in the --json trace and gated by
-    # check_regression (ratio for eqns, exact for collectives)
-    trace[f"jaxpr_eqns_T{{T}}"] = jaxpr_pass.eqn_count(qj)
-    qc = jaxpr_pass.collective_counts(qj)
-    assert not jaxpr_pass.check_collectives(qc, budgets["query"]), (T, qc)
-    trace[f"collectives_query_T{{T}}"] = qc.get("all_to_all", 0)
+    # text regex), summed over the query's three staged programs; counts
+    # are recorded in the --json trace and gated by check_regression
+    # (ratio for eqns, exact for collectives)
+    n_eqns = n_a2a = 0
+    for phase, fn, args in query_stages(idx, queries, K, G=4):
+        qj = jax.make_jaxpr(fn)(*args)
+        n_eqns += jaxpr_pass.eqn_count(qj)
+        qc = jaxpr_pass.collective_counts(qj)
+        assert not jaxpr_pass.check_collectives(qc, budgets[phase]), (T, qc)
+        n_a2a += qc.get("all_to_all", 0)
+    trace[f"jaxpr_eqns_T{{T}}"] = n_eqns
+    trace[f"collectives_query_T{{T}}"] = n_a2a
     ins = idx._make_insert_fn(M // 8, idx._dispatch_capacity(M // 8 * T),
                               st.capacity, st.n_sorted)
     ic = jaxpr_pass.collective_counts(jax.make_jaxpr(ins)(

@@ -145,7 +145,7 @@ def check_contract_report(path: str) -> list[str]:
             f"contract report {path}: ok=false "
             f"({len(viol)} violation(s); first: {viol[0]})")
     phases = report.get("jaxpr", {}).get("phases", {})
-    for phase in ("insert", "query", "delete",
+    for phase in ("insert", "delete",
                   "query_dispatch", "query_scan", "query_return"):
         reps = phases.get(phase)
         if not reps:

@@ -3,12 +3,12 @@
 This package is the repo's enforcement substrate for the paper's central
 claim — a *statically provable* network bound.  The distributed index
 promises one fused collective per phase (insert: 1 all_to_all, query:
-dispatch + routed-return = 2, delete: 0), a jaxpr that stays flat as the
-table count T grows, no O(R*N) intermediates, donated store buffers that
-the compiled executable actually aliases, and a hot path free of host
-syncs.  Those invariants live declaratively in ``contracts.json`` and
-are verified structurally (primitive identity, never text regex) by
-three passes:
+1 in its dispatch step + 1 in its routed-return step = 2, delete: 0),
+a jaxpr that stays flat as the table count T grows, no O(R*N)
+intermediates, donated store buffers that the compiled executable
+actually aliases, and a hot path free of host syncs.  Those invariants
+live declaratively in ``contracts.json`` and are verified structurally
+(primitive identity, never text regex) by three passes:
 
 - :mod:`repro.analysis.jaxpr_pass` — ClosedJaxpr walk: collective
   counts, equation counts / flatness in T, intermediate-size ceilings,

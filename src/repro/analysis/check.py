@@ -1,11 +1,12 @@
 """SPMD contract gate: ``python -m repro.analysis.check``.
 
-Traces the real ``DistributedLSHIndex`` insert/query/delete step
-functions on 8 XLA host devices at T in {1, 2, 4} (the manifest's
-``check_config``), runs the three analysis passes against
-``contracts.json``, writes a machine-readable JSON report, and exits
-nonzero on any violation.  CI runs this in the fast lane and uploads
-the report next to the bench baseline;
+Traces the real ``DistributedLSHIndex`` insert and delete steps and the
+query's three staged steps (dispatch / scan / return) on 8 XLA host
+devices at T in {1, 2, 4} (the manifest's ``check_config``), runs the
+three analysis passes against ``contracts.json``, writes a
+machine-readable JSON report, and exits nonzero on any violation.  CI
+runs this in the fast lane and uploads the report next to the bench
+baseline;
 ``benchmarks/check_regression.py --contracts`` refuses to gate without
 it.
 
@@ -55,6 +56,32 @@ def _run_repolint(contracts: Dict[str, Any], root: str,
     return report
 
 
+def query_stages(idx, queries, K: int, donate: bool = False,
+                 G: int | None = None) -> List[tuple]:
+    """The index's three compiled query steps for ``queries``, with
+    arguments to trace or lower them: ``[(phase, fn, args)]`` for
+    dispatch, scan and return.  ``G`` defaults to the index's own gather
+    window."""
+    import jax.numpy as jnp
+
+    st, cfg = idx.store, idx.cfg
+    S, m = cfg.n_shards, queries.shape[0]
+    Cq = idx._query_capacity(m // S)
+    if G is None:
+        G = idx._gather_window(S * Cq * cfg.L)
+    return [
+        ("query_dispatch", idx._make_query_dispatch_fn(m, Cq, donate),
+         (queries, jnp.arange(m, dtype=jnp.int32))),
+        ("query_scan",
+         idx._make_query_scan_fn(m, Cq, K, st.n_sorted, G,
+                                 idx._scan_extent()),
+         (jnp.zeros((S * S * Cq, cfg.d + 2), jnp.int32), st.x, st.packed,
+          st.gid, st.table, st.valid, st.bucket_start, st.bucket_end)),
+        ("query_return", idx._make_query_return_fn(m, K),
+         (jnp.zeros((S * m, 2 * K + 1), jnp.int32),)),
+    ]
+
+
 def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
                          report: Dict[str, Any]) -> List[str]:
     """Trace + compile the real step fns; returns violation messages."""
@@ -83,7 +110,7 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
     m, K, G_probe = int(cc["m"]), int(cc["k_neighbors"]), int(cc["probe"])
 
     violations: List[str] = []
-    PHASES = ("insert", "query", "delete",
+    PHASES = ("insert", "delete",
               "query_dispatch", "query_scan", "query_return")
     phases: Dict[str, Dict[str, Any]] = {p: {} for p in PHASES}
     eqns: Dict[str, Dict[int, int]] = {p: {} for p in PHASES}
@@ -105,58 +132,36 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
                  jnp.ones(m, bool), st.x, st.packed, st.gid, st.table,
                  st.key, st.valid)
 
-        Cq = idx._query_capacity(n_loc)
-        G = idx._gather_window(S * Cq * cfg.L)
-        ext = idx._scan_extent()
-        qf = idx._make_query_fn(m, st.capacity, Cq, False, K,
-                                st.n_sorted, G, ext)
-        qargs = (queries, jnp.arange(m, dtype=jnp.int32), st.x, st.packed,
-                 st.gid, st.table, st.valid, st.bucket_start, st.bucket_end)
-
         n_del = 8
         dfn = idx._make_delete_fn(n_del, st.capacity, st.n_sorted)
         padded = np.full((n_del,), np.iinfo(np.int32).max, np.int32)
         dargs = (jnp.asarray(padded), st.valid, st.gid)
 
-        # staged query pipeline: the same step cut at its a2a boundaries
-        # (serving/pipeline.py overlaps batches through these three fns)
-        qids = jnp.arange(m, dtype=jnp.int32)
-        sdfn = idx._make_query_dispatch_fn(m, Cq, False)
-        sdargs = (queries, qids)
-        ssfn = idx._make_query_scan_fn(m, st.capacity, Cq, K,
-                                       st.n_sorted, G, ext)
-        ssargs = (jnp.zeros((S * S * Cq, cc["d"] + 2), jnp.int32),
-                  st.x, st.packed, st.gid, st.table, st.valid,
-                  st.bucket_start, st.bucket_end)
-        srfn = idx._make_query_return_fn(m, K)
-        srargs = (jnp.zeros((S * m, 2 * K + 1), jnp.int32),)
-
-        qtrace = qf
+        # the query: its three compiled steps, as both front ends run them
+        stages = query_stages(idx, queries, K)
         if seed == "jaxpr-growth":
             # inject per-table work: eqn count now grows linearly in T
-            def qtrace(*a, _qf=qf, _T=T):
-                out = _qf(*a)
+            def rtrace(*a, _rf=stages[2][1], _T=T):
+                out = _rf(*a)
                 d = out[0]
                 for _ in range(120 * (_T - 1)):
                     d = jnp.sin(d)
                 return (d,) + tuple(out[1:])
+            stages[2] = ("query_return", rtrace, stages[2][2])
         elif seed == "extra-collective" and T == hlo_T:
-            # inject a rogue replicating all_gather after the query
-            def qtrace(*a, _qf=qf):
-                out = _qf(*a)
+            # inject a rogue replicating all_gather after the return
+            def rtrace(*a, _rf=stages[2][1]):
+                out = _rf(*a)
                 gather = jax.jit(shard_map(
                     lambda y: jax.lax.all_gather(y, "shard", axis=0,
                                                  tiled=True),
                     mesh=mesh, in_specs=(P("shard"),), out_specs=P(),
                     check_vma=False))
                 return out + (gather(out[0]),)
+            stages[2] = ("query_return", rtrace, stages[2][2])
 
-        for phase, fn, fargs in (("insert", ifn, iargs),
-                                 ("query", qtrace, qargs),
-                                 ("delete", dfn, dargs),
-                                 ("query_dispatch", sdfn, sdargs),
-                                 ("query_scan", ssfn, ssargs),
-                                 ("query_return", srfn, srargs)):
+        for phase, fn, fargs in [("insert", ifn, iargs),
+                                 ("delete", dfn, dargs)] + stages:
             cj = jax.make_jaxpr(fn)(*fargs)
             rep = jaxpr_pass.analyze_phase(cj, phase, T, contracts)
             phases[phase][str(T)] = rep
@@ -164,12 +169,7 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
             violations.extend(rep["violations"])
 
         if T == hlo_T:
-            hlo_ctx = {"idx": idx, "ifn": ifn, "iargs": iargs,
-                       "qargs": qargs, "m": m, "cap": st.capacity,
-                       "Cq": Cq, "K": K, "ns": st.n_sorted, "G": G,
-                       "ext": ext,
-                       "ssfn": ssfn, "ssargs": ssargs,
-                       "srfn": srfn, "srargs": srargs}
+            hlo_ctx = {"idx": idx, "ifn": ifn, "iargs": iargs}
 
     ratio = manifest.flatness_ratio(contracts)
     flat_report: Dict[str, Any] = {"max_ratio": ratio, "eqns": {}}
@@ -180,28 +180,17 @@ def _run_compiled_passes(contracts: Dict[str, Any], seed: str | None,
     report["jaxpr"] = {"phases": phases, "flatness": flat_report}
 
     # ---- HLO / memory pass on the compiled executables at T=hlo_T ----
-    idx = hlo_ctx["idx"]
-    compiled_insert = hlo_ctx["ifn"].lower(*hlo_ctx["iargs"]).compile()
-    donate_query = seed != "broken-donation"
-    qfn = idx._make_query_fn(hlo_ctx["m"], hlo_ctx["cap"], hlo_ctx["Cq"],
-                             donate_query, hlo_ctx["K"], hlo_ctx["ns"],
-                             hlo_ctx["G"], hlo_ctx["ext"])
-    compiled_query = qfn.lower(*hlo_ctx["qargs"]).compile()
-    # the staged stages as the pipeline runs them: dispatch donates the
+    # the query steps as the front ends run them: dispatch donates the
     # staging buffer; scan/return always donate the routed payloads
-    sdfn = idx._make_query_dispatch_fn(hlo_ctx["m"], hlo_ctx["Cq"],
-                                       donate_query)
-    compiled_dispatch = sdfn.lower(*hlo_ctx["qargs"][:2]).compile()
-    compiled_scan = hlo_ctx["ssfn"].lower(*hlo_ctx["ssargs"]).compile()
-    compiled_return = hlo_ctx["srfn"].lower(*hlo_ctx["srargs"]).compile()
+    executables = [("insert",
+                    hlo_ctx["ifn"].lower(*hlo_ctx["iargs"]).compile())]
+    for phase, fn, fargs in query_stages(
+            hlo_ctx["idx"], queries, K, donate=seed != "broken-donation"):
+        executables.append((phase, fn.lower(*fargs).compile()))
 
     hlo_report: Dict[str, Any] = {"n_tables": hlo_T, "donation": {},
                                   "memory": {}, "collectives": {}}
-    for phase, compiled in (("insert", compiled_insert),
-                            ("query", compiled_query),
-                            ("query_dispatch", compiled_dispatch),
-                            ("query_scan", compiled_scan),
-                            ("query_return", compiled_return)):
+    for phase, compiled in executables:
         text = compiled.as_text()
         don = hlo_pass.donation_report(text, phase, contracts)
         mem = hlo_pass.memory_report(compiled, phase, contracts)
@@ -278,7 +267,7 @@ def main(argv=None) -> int:
             print(f"  - {v}")
     else:
         jx = report.get("jaxpr", {}).get("phases", {})
-        for phase in ("insert", "query", "delete",
+        for phase in ("insert", "delete",
                       "query_dispatch", "query_scan", "query_return"):
             for t, rep in sorted(jx.get(phase, {}).items()):
                 coll = rep["collectives"] or "{}"

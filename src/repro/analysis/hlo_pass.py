@@ -5,10 +5,10 @@ Extends the descriptive parsers in ``repro.launch.hlo_analysis`` /
 
 - **Donation**: donated buffers must be honored by XLA.  Insert donates
   the six store columns and XLA aliases them output<-input
-  (``input_output_alias`` in the module header).  The query buffer under
-  ``donate=True`` has no shape-matching output, so XLA records it as a
-  ``buffer_donor`` instead — both forms count as honored; a donation
-  that appears in neither was silently copied.
+  (``input_output_alias`` in the module header).  The query
+  dispatch's buffer under ``donate=True`` has no shape-matching output,
+  so XLA records it as a ``buffer_donor`` instead — both forms count as
+  honored; a donation that appears in neither was silently copied.
 - **Memory**: ``compiled.memory_analysis()`` temp bytes vs budget.
 - **VMEM**: the Pallas kernels' declared per-step VMEM footprint
   (``vmem_bytes_per_step``) vs budget, evaluated at the *maximum*

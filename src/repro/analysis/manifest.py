@@ -33,7 +33,8 @@ def load_contracts(path: str | None = None) -> dict:
     missing = [k for k in _REQUIRED_JAXPR if k not in doc["jaxpr"]]
     if missing:
         raise ValueError(f"{path}: missing jaxpr contract keys {missing}")
-    for phase in ("insert", "query", "delete"):
+    for phase in ("insert", "delete",
+                  "query_dispatch", "query_scan", "query_return"):
         if phase not in doc["jaxpr"]["collectives"]:
             raise ValueError(f"{path}: no collective budget for phase {phase!r}")
     ratio = doc["jaxpr"]["flatness"]["max_ratio"]

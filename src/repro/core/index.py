@@ -176,6 +176,14 @@ def _i2f(x: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(x, jnp.float32)
 
 
+def _keys_of(cfg: LSHConfig, p: HashParams, offs: jax.Array):
+    """One table's offsets (L, d) -> (Key, packedH) per offset."""
+    hk = hash_h(p, offs, cfg.W)                 # (L, k)
+    packed = pack_buckets(p, hk)                # (L, 2)
+    keyv = shard_key(p, cfg, hk)                # (L,)
+    return keyv, packed
+
+
 # ---------------------------------------------------------------------------
 # Streaming store
 # ---------------------------------------------------------------------------
@@ -361,8 +369,8 @@ class DistributedLSHIndex:
 
     def __init__(self, cfg: LSHConfig, mesh: Mesh, axis: str = "shard",
                  slack: float = 4.0, use_kernel: Optional[bool] = None,
-                 k_neighbors: int = 1, use_csr: bool = True,
-                 merge_min_rows: int = 1024, merge_frac: float = 0.25):
+                 k_neighbors: int = 1, merge_min_rows: int = 1024,
+                 merge_frac: float = 0.25):
         """use_kernel=True routes the per-shard bucket search through the
         Pallas streaming kernels (kernels/bucket_search.py) instead of the
         jnp mask formulation -- identical results (tested), O(R*N) score
@@ -375,13 +383,15 @@ class DistributedLSHIndex:
         K best (dist, gid) pairs within cr, union-merged across shards
         and tables.
 
-        use_csr=False pins the kernel path to the full-scan kernel even
-        on a bucket-sorted store (the comparison baseline; results are
-        bitwise identical either way).  ``merge_min_rows``/``merge_frac``
-        set the LSM merge policy: after an insert, once the unsorted tail
-        holds more than ``merge_min_rows`` live rows AND more than
-        ``merge_frac`` of all live rows, the tail is folded into the
-        sorted region (a ``compact()``-style rewrite)."""
+        The store's layout alone picks the kernel path: the CSR bucket
+        gather over a bucket-sorted region, the full scan over an
+        unsorted store and when the gather window overflows (results
+        are bitwise identical either way).  ``merge_min_rows`` /
+        ``merge_frac`` set the LSM merge policy: after an insert, once
+        the unsorted tail holds more than ``merge_min_rows`` live rows
+        AND more than ``merge_frac`` of all live rows, the tail is
+        folded into the sorted region (a ``compact()``-style
+        rewrite)."""
         if mesh.shape[axis] != cfg.n_shards:
             raise ValueError(
                 f"mesh axis {axis}={mesh.shape[axis]} != n_shards={cfg.n_shards}")
@@ -392,7 +402,6 @@ class DistributedLSHIndex:
         self.slack = slack
         self.use_kernel = (jax.default_backend() != "cpu"
                            if use_kernel is None else use_kernel)
-        self.use_csr = use_csr
         self.merge_min_rows = merge_min_rows
         self.merge_frac = merge_frac
         if not 1 <= k_neighbors <= 128:
@@ -1092,40 +1101,21 @@ class DistributedLSHIndex:
         }
 
     # ------------------------------------------------------------------
-    # Query: one routed step built from three stage bodies (dispatch /
-    # scan / return) shared between the fused synchronous path and the
-    # separately-invocable staged path the serving pipeline overlaps.
+    # Query: one routed step in three compiled stages (dispatch / scan /
+    # return), cut at its two all_to_all boundaries.  Stage payloads are
+    # exact int32 buffers (floats bitcast), so nothing is lost crossing a
+    # boundary.  Each stage call only ENQUEUES device work (jax dispatch
+    # is async), so a pipeline can issue batch i+1's dispatch before
+    # batch i's scan and return have executed -- the host blocks only
+    # when it fetches a retired batch's results.  ``query()`` runs the
+    # same three compiled programs back to back.
     # ------------------------------------------------------------------
-    def _query_bodies(self, m: int, Cq: int, cap: int, K: int, ns: int,
-                      G: int, extent: int):
-        """Build the three per-shard stage bodies of the query step.
-
-        ``_make_query_fn`` composes all three inside ONE shard_map (the
-        synchronous path); ``_make_query_dispatch_fn`` / ``_scan_fn`` /
-        ``_return_fn`` wrap each body in its own shard_map so a serving
-        pipeline can enqueue batch i+1's dispatch all_to_all while batch
-        i is still in its scan / return stages.  The bodies are shared
-        closures, so the staged path is op-for-op the fused trace cut at
-        the two all_to_all boundaries; stage payloads are exact int32
-        buffers (floats bitcast), so no precision is lost crossing a
-        boundary and staged results are bitwise identical (tested).
-        ``extent`` (``_scan_extent``) bounds the kernels' full scans.
-        """
+    def _make_query_dispatch_fn(self, m: int, Cq: int, donate: bool):
         cfg = self.cfg
         sparams, skeys = self.stacked_params, self.stacked_keys
-        S, L, T, d = cfg.n_shards, cfg.L, cfg.n_tables, cfg.d
+        S, L, T = cfg.n_shards, cfg.L, cfg.n_tables
         axis = self.axis
         m_loc = m // S
-        Rc = self._scan_rows(m, Cq)         # received rows the scan searches
-        use_kernel = self.use_kernel
-        use_csr = self.use_csr
-
-        def keys_of(p, offs):
-            """One table's offsets (L, d) -> (Key, packedH) per offset."""
-            hk = hash_h(p, offs, cfg.W)                 # (L, k)
-            packed = pack_buckets(p, hk)                # (L, 2)
-            keyv = shard_key(p, cfg, hk)                # (L,)
-            return keyv, packed
 
         def live_mask(keyv, packed):
             if cfg.scheme == Scheme.SIMPLE:
@@ -1146,7 +1136,7 @@ class DistributedLSHIndex:
                 offs = jax.vmap(
                     lambda i, q: query_offsets(bk, i, q, L, cfg.r))(
                         qid_loc, q_loc)                  # (m_loc, L, d)
-                keyv, packed = jax.vmap(lambda o: keys_of(p, o))(offs)
+                keyv, packed = jax.vmap(lambda o: _keys_of(cfg, p, o))(offs)
                 return keyv, jax.vmap(live_mask)(keyv, packed)
             key_t, live_t = jax.vmap(route_table)(sparams, skeys)
             keyv = jnp.swapaxes(key_t, 0, 1)             # (m_loc, T, L)
@@ -1170,6 +1160,29 @@ class DistributedLSHIndex:
             sbuf = scatter_rows(slot, keep, payload, nslots, IMAX)
             r = _a2a(sbuf, axis)                         # (S*Cq, d+2)
             return r, fq_local, drops
+
+        def dispatch_shard(q_loc, qid_loc):
+            r, fq_local, drops = dispatch_body(q_loc, qid_loc)
+            return r, fq_local, drops[None]
+
+        spec = P(self.axis)
+        return jax.jit(shard_map(
+            dispatch_shard, mesh=self.mesh,
+            in_specs=(spec, spec), out_specs=(spec,) * 3,
+            check_vma=False,   # pallas out_shape has no vma annotation
+        ), donate_argnums=(0,) if donate else ())
+
+    def _make_query_scan_fn(self, m: int, Cq: int, K: int, ns: int, G: int,
+                            extent: int):
+        """``ns`` (the store's ``n_sorted``) picks the bucket search's
+        path; ``G`` sizes the gather window and ``extent``
+        (``_scan_extent``) bounds the kernels' full scans."""
+        cfg = self.cfg
+        sparams, skeys = self.stacked_params, self.stacked_keys
+        S, L, T, d = cfg.n_shards, cfg.L, cfg.n_tables, cfg.d
+        axis = self.axis
+        Rc = self._scan_rows(m, Cq)         # received rows the scan searches
+        use_kernel = self.use_kernel
 
         @jax.named_scope("lsh.scan")
         def scan_body(r, store_x, store_packed, store_gid, store_table,
@@ -1210,7 +1223,7 @@ class DistributedLSHIndex:
             # where-select, which paid O(T*L*k*d)) ----
             roffs = query_offsets_by_table(
                 skeys, rtab_safe, rid_safe, rq, L, cfg.r)  # (R, L, d)
-            rkey, rpacked = jax.vmap(keys_of)(
+            rkey, rpacked = jax.vmap(lambda p, o: _keys_of(cfg, p, o))(
                 sparams.gather(rtab_safe), roffs)          # (R, L) (R, L, 2)
             mine = (jnp.mod(rkey, S) == me) & rvalid[:, None]  # (R, L)
             # first-occurrence dedupe of H-buckets within the selected set
@@ -1220,11 +1233,11 @@ class DistributedLSHIndex:
             probe = mine & firstocc                            # (R, L)
 
             # ---- bucket search (Fig 3.2 Reduce body), local top-K,
-            # stored rows only answer probes of their own table.  One
-            # typed call surface for all three paths: the Pallas CSR
-            # gather (sorted store), the Pallas full scan, and the jnp
-            # oracle (use_kernel=False; always a full scan -- it is the
-            # XLA lowering for sharded dry runs) ----
+            # stored rows only answer probes of their own table.  The
+            # store's layout picks the path: the Pallas CSR gather over a
+            # sorted region (ns > 0), else the Pallas full scan; the jnp
+            # oracle (use_kernel=False) is always a full scan -- it is
+            # the XLA lowering for sharded dry runs ----
             qbatch = QueryBatch(
                 q=rq, qsq=jnp.sum(rq ** 2, -1),
                 buckets=jax.lax.bitcast_convert_type(
@@ -1240,8 +1253,7 @@ class DistributedLSHIndex:
             row_d, row_g, row_emit, counters = kops.bucket_search(
                 query=qbatch, store=sview,
                 cr2=float(np.float32((cfg.c * cfg.r) ** 2)), L=L, k=K,
-                use_kernel=use_kernel, force_full_scan=not use_csr,
-                window_tiles=G)
+                use_kernel=use_kernel, window_tiles=G)
 
             # ---- local union across tables: this shard holds at most
             # one live row per (qid, table), so scatter per-row top-Ks
@@ -1267,66 +1279,6 @@ class DistributedLSHIndex:
                 _f2i(loc_d), loc_g, emit[:, None]], axis=1)  # (m, 2K+1)
             return ret, recv_load, counters
 
-        @jax.named_scope("lsh.return")
-        def return_body(ret):
-            """Stage 3: ONE routed all_to_all ships each qid's local
-            top-K (+ emit count) only to the qid's OWNER shard
-            (qid // m_loc), replacing the old all_gather + replicated
-            K-way merge + emit psum: O(m*K) received per shard instead
-            of O(S*m*K)."""
-            recv = _a2a(ret, axis).reshape(S, m_loc, 2 * K + 1)
-            cand_d = jnp.moveaxis(_i2f(recv[:, :, :K]), 0, 1)
-            cand_g = jnp.moveaxis(recv[:, :, K:2 * K], 0, 1)
-            gtopd, gtopg = merge_topk(
-                cand_d.reshape(m_loc, S * K),
-                cand_g.reshape(m_loc, S * K), K)            # (m_loc, K)
-            gemit = recv[:, :, 2 * K].sum(axis=0).astype(jnp.int32)
-            return gtopd, gtopg, gemit
-
-        return dispatch_body, scan_body, return_body
-
-    def _make_query_fn(self, m: int, cap: int, Cq: int, donate: bool,
-                       K: int, ns: int, G: int, extent: int):
-        dispatch_body, scan_body, return_body = self._query_bodies(
-            m, Cq, cap, K, ns, G, extent)
-
-        def query_shard(q_loc, qid_loc, store_x, store_packed, store_gid,
-                        store_table, store_valid, store_bs, store_be):
-            r, fq_local, drops = dispatch_body(q_loc, qid_loc)
-            # stores arrive with a leading per-shard block dim of 1
-            ret, recv_load, counters = scan_body(
-                r, store_x[0], store_packed[0], store_gid[0],
-                store_table[0], store_valid[0], store_bs[0], store_be[0])
-            gtopd, gtopg, gemit = return_body(ret)
-            return (gtopd, gtopg, gemit, fq_local, recv_load[None],
-                    drops[None], counters[None])
-
-        spec = P(self.axis)
-        return jax.jit(shard_map(
-            query_shard, mesh=self.mesh,
-            in_specs=(spec,) * 9, out_specs=(spec,) * 7,
-            check_vma=False,   # pallas out_shape has no vma annotation
-        ), donate_argnums=(0,) if donate else ())
-
-    def _make_query_dispatch_fn(self, m: int, Cq: int, donate: bool):
-        # cap/K/ns/G/extent shape only the scan/return bodies
-        dispatch_body, _, _ = self._query_bodies(m, Cq, 0, 1, 0, 1, 0)
-
-        def dispatch_shard(q_loc, qid_loc):
-            r, fq_local, drops = dispatch_body(q_loc, qid_loc)
-            return r, fq_local, drops[None]
-
-        spec = P(self.axis)
-        return jax.jit(shard_map(
-            dispatch_shard, mesh=self.mesh,
-            in_specs=(spec, spec), out_specs=(spec,) * 3,
-            check_vma=False,
-        ), donate_argnums=(0,) if donate else ())
-
-    def _make_query_scan_fn(self, m: int, cap: int, Cq: int, K: int,
-                            ns: int, G: int, extent: int):
-        _, scan_body, _ = self._query_bodies(m, Cq, cap, K, ns, G, extent)
-
         def scan_shard(r, store_x, store_packed, store_gid, store_table,
                        store_valid, store_bs, store_be):
             # stores arrive with a leading per-shard block dim of 1
@@ -1343,8 +1295,25 @@ class DistributedLSHIndex:
         ), donate_argnums=(0,))   # the routed recv buffer dies here
 
     def _make_query_return_fn(self, m: int, K: int):
-        # Cq/cap/ns/G/extent shape only the dispatch/scan bodies
-        _, _, return_body = self._query_bodies(m, 8, 0, K, 0, 1, 0)
+        S = self.cfg.n_shards
+        axis = self.axis
+        m_loc = m // S
+
+        @jax.named_scope("lsh.return")
+        def return_body(ret):
+            """Stage 3: ONE routed all_to_all ships each qid's local
+            top-K (+ emit count) only to the qid's OWNER shard
+            (qid // m_loc), replacing the old all_gather + replicated
+            K-way merge + emit psum: O(m*K) received per shard instead
+            of O(S*m*K)."""
+            recv = _a2a(ret, axis).reshape(S, m_loc, 2 * K + 1)
+            cand_d = jnp.moveaxis(_i2f(recv[:, :, :K]), 0, 1)
+            cand_g = jnp.moveaxis(recv[:, :, K:2 * K], 0, 1)
+            gtopd, gtopg = merge_topk(
+                cand_d.reshape(m_loc, S * K),
+                cand_g.reshape(m_loc, S * K), K)            # (m_loc, K)
+            gemit = recv[:, :, 2 * K].sum(axis=0).astype(jnp.int32)
+            return gtopd, gtopg, gemit
 
         def return_shard(ret):
             return return_body(ret)
@@ -1356,70 +1325,85 @@ class DistributedLSHIndex:
             check_vma=False,
         ), donate_argnums=(0,))   # the return payload dies here
 
-    def query(self, queries: jax.Array, donate: bool = False,
-              k_neighbors: Optional[int] = None,
-              batch: Optional[int] = None) -> QueryResult:
-        """Answer a batch of queries (m, d), m divisible by n_shards.
-
-        donate=True donates the query buffer to the compiled executable
-        (serving front-ends stage queries into a scratch buffer that is
-        dead after the call -- avoids one device copy per flush).
-
-        k_neighbors overrides the index-level default K for this call
-        (each distinct K compiles its own executable, cached).
-
-        batch: the caller's number for this batch, the ``lsh.query``
-        span's arg (``core/spans.py``).
-        """
-        with span("lsh.query", batch=batch):
-            if self.store is None:
-                raise RuntimeError("call build() or insert() first")
-            cfg = self.cfg
-            S = cfg.n_shards
-            m = queries.shape[0]
-            if m % S:
-                raise ValueError(f"m={m} must divide by n_shards={S}")
-            K = self.k_neighbors if k_neighbors is None else k_neighbors
-            if not 1 <= K <= 128:
-                raise ValueError(f"k_neighbors={K} not in [1, 128]")
-            m_loc = m // S
-            Cq = self._query_capacity(m_loc)
-            st = self.store
-            G = self._gather_window(S * Cq * cfg.L)
-            ext = self._scan_extent()
-
-            key = (m, st.capacity, Cq, donate, K, st.n_sorted, G, ext,
-                   self.use_csr)
-            fn = self._query_fns.get(key)
-            if fn is None:
-                fn = self._query_fns[key] = self._make_query_fn(
-                    m, st.capacity, Cq, donate, K, st.n_sorted, G, ext)
-            qids = jnp.arange(m, dtype=jnp.int32)
-            # each shard returned exactly its own qids' results (the routed
-            # return path); the sharded outputs concatenate to (m, K)
-            return _host_query_result(*fn(
-                queries, qids, st.x, st.packed, st.gid, st.table, st.valid,
-                st.bucket_start, st.bucket_end))
-
-    # ------------------------------------------------------------------
-    # Staged query: the same step as separately-invocable stages.  Each
-    # stage call only ENQUEUES device work (jax dispatch is async), so a
-    # pipeline can issue batch i+1's dispatch before batch i's scan and
-    # return have executed -- the host blocks only when it fetches a
-    # retired batch's results.
-    # ------------------------------------------------------------------
-    def _check_query_batch(self, queries: jax.Array,
-                           k_neighbors: Optional[int]) -> tuple[int, int]:
+    def _check_query_batch(self, m: int, k_neighbors: Optional[int]) -> int:
+        """Refuse a batch of ``m`` queries the query steps cannot take;
+        return its K."""
         if self.store is None:
             raise RuntimeError("call build() or insert() first")
         S = self.cfg.n_shards
-        m = queries.shape[0]
         if m % S:
             raise ValueError(f"m={m} must divide by n_shards={S}")
         K = self.k_neighbors if k_neighbors is None else k_neighbors
         if not 1 <= K <= 128:
             raise ValueError(f"k_neighbors={K} not in [1, 128]")
-        return m, K
+        return K
+
+    def _stage_fn(self, key: tuple, make):
+        """The compiled stage program cached under ``key``."""
+        fn = self._query_fns.get(key)
+        if fn is None:
+            fn = self._query_fns[key] = make()
+        return fn
+
+    def _dispatch(self, queries: jax.Array, donate: bool,
+                  batch: Optional[int]) -> DispatchedBatch:
+        m = queries.shape[0]
+        Cq = self._query_capacity(m // self.cfg.n_shards)
+        fn = self._stage_fn(
+            ("dispatch", m, Cq, donate),
+            lambda: self._make_query_dispatch_fn(m, Cq, donate))
+        recv, fq, drops = fn(queries, jnp.arange(m, dtype=jnp.int32))
+        return DispatchedBatch(recv=recv, fq=fq, drops=drops, m=m, Cq=Cq,
+                               batch=batch)
+
+    def _scan(self, disp: DispatchedBatch, K: int) -> ScannedBatch:
+        st, m, Cq = self.store, disp.m, disp.Cq
+        G = self._gather_window(self.cfg.n_shards * Cq * self.cfg.L)
+        ext = self._scan_extent()
+        fn = self._stage_fn(
+            ("scan", m, st.capacity, Cq, K, st.n_sorted, G, ext),
+            lambda: self._make_query_scan_fn(m, Cq, K, st.n_sorted, G, ext))
+        ret, recv_load, counters = fn(
+            disp.recv, st.x, st.packed, st.gid, st.table, st.valid,
+            st.bucket_start, st.bucket_end)
+        return ScannedBatch(ret=ret, recv_load=recv_load, counters=counters,
+                            m=m, K=K, batch=disp.batch)
+
+    def _return(self, scanned: ScannedBatch
+                ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        m, K = scanned.m, scanned.K
+        fn = self._stage_fn(("return", m, K),
+                            lambda: self._make_query_return_fn(m, K))
+        return fn(scanned.ret)
+
+    def query(self, queries: jax.Array, donate: bool = False,
+              k_neighbors: Optional[int] = None,
+              batch: Optional[int] = None) -> QueryResult:
+        """Answer a batch of queries (m, d), m divisible by n_shards.
+
+        Runs the three compiled stages of ``query_dispatch`` /
+        ``query_scan`` / ``query_return`` back to back, under the one
+        ``lsh.query`` span, and fetches the result (blocks).
+
+        donate=True donates the query buffer to the compiled dispatch
+        (serving front-ends stage queries into a scratch buffer that is
+        dead after the call -- avoids one device copy per flush).
+
+        k_neighbors overrides the index-level default K for this call
+        (each distinct K compiles its own scan and return, cached).
+
+        batch: the caller's number for this batch, the ``lsh.query``
+        span's arg (``core/spans.py``).
+        """
+        with span("lsh.query", batch=batch):
+            K = self._check_query_batch(queries.shape[0], k_neighbors)
+            disp = self._dispatch(queries, donate, batch)
+            scanned = self._scan(disp, K)
+            # each shard returned exactly its own qids' results (the routed
+            # return path); the sharded outputs concatenate to (m, K)
+            return _host_query_result(
+                *self._return(scanned), disp.fq, scanned.recv_load,
+                disp.drops, scanned.counters)
 
     def query_dispatch(self, queries: jax.Array, donate: bool = False,
                        batch: Optional[int] = None) -> DispatchedBatch:
@@ -1432,17 +1416,8 @@ class DistributedLSHIndex:
         of its ``lsh.dispatch`` / ``lsh.scan`` / ``lsh.return`` spans.
         """
         with span("lsh.dispatch", batch=batch):
-            m, _ = self._check_query_batch(queries, None)
-            Cq = self._query_capacity(m // self.cfg.n_shards)
-            key = ("dispatch", m, Cq, donate)
-            fn = self._query_fns.get(key)
-            if fn is None:
-                fn = self._query_fns[key] = self._make_query_dispatch_fn(
-                    m, Cq, donate)
-            qids = jnp.arange(m, dtype=jnp.int32)
-            recv, fq, drops = fn(queries, qids)
-        return DispatchedBatch(recv=recv, fq=fq, drops=drops, m=m, Cq=Cq,
-                               batch=batch)
+            self._check_query_batch(queries.shape[0], None)
+            return self._dispatch(queries, donate, batch)
 
     def query_scan(self, disp: DispatchedBatch,
                    k_neighbors: Optional[int] = None) -> ScannedBatch:
@@ -1450,27 +1425,9 @@ class DistributedLSHIndex:
 
         Consumes (donates) ``disp.recv``; no collectives are issued.
         """
-        if self.store is None:
-            raise RuntimeError("call build() or insert() first")
-        K = self.k_neighbors if k_neighbors is None else k_neighbors
-        if not 1 <= K <= 128:
-            raise ValueError(f"k_neighbors={K} not in [1, 128]")
+        K = self._check_query_batch(disp.m, k_neighbors)
         with span("lsh.scan", batch=disp.batch):
-            st = self.store
-            G = self._gather_window(
-                self.cfg.n_shards * disp.Cq * self.cfg.L)
-            ext = self._scan_extent()
-            key = ("scan", disp.m, st.capacity, disp.Cq, K, st.n_sorted, G,
-                   ext, self.use_csr)
-            fn = self._query_fns.get(key)
-            if fn is None:
-                fn = self._query_fns[key] = self._make_query_scan_fn(
-                    disp.m, st.capacity, disp.Cq, K, st.n_sorted, G, ext)
-            ret, recv_load, counters = fn(
-                disp.recv, st.x, st.packed, st.gid, st.table, st.valid,
-                st.bucket_start, st.bucket_end)
-        return ScannedBatch(ret=ret, recv_load=recv_load, counters=counters,
-                            m=disp.m, K=K, batch=disp.batch)
+            return self._scan(disp, K)
 
     def query_return(self, scanned: ScannedBatch
                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -1481,24 +1438,4 @@ class DistributedLSHIndex:
         to block on the batch.
         """
         with span("lsh.return", batch=scanned.batch):
-            key = ("return", scanned.m, scanned.K)
-            fn = self._query_fns.get(key)
-            if fn is None:
-                fn = self._query_fns[key] = self._make_query_return_fn(
-                    scanned.m, scanned.K)
-            return fn(scanned.ret)
-
-    def query_staged(self, queries: jax.Array, donate: bool = False,
-                     k_neighbors: Optional[int] = None) -> QueryResult:
-        """Run the three stages back-to-back and fetch the result.
-
-        Semantically identical to ``query()`` (bitwise -- the stages are
-        the fused trace cut at its all_to_all boundaries); used by
-        equivalence tests and as the simplest staged-path reference.
-        """
-        disp = self.query_dispatch(queries, donate=donate)
-        scanned = self.query_scan(disp, k_neighbors=k_neighbors)
-        gtopd, gtopg, gemit = self.query_return(scanned)
-        return _host_query_result(gtopd, gtopg, gemit, disp.fq,
-                                  scanned.recv_load, disp.drops,
-                                  scanned.counters)
+            return self._return(scanned)

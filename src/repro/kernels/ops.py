@@ -312,7 +312,6 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
 
 def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
                   k: int = 1, use_kernel: bool = True,
-                  force_full_scan: bool = False,
                   window_tiles: int = DEFAULT_WINDOW_TILES):
     """Streaming masked top-K NN scan over one shard's store.
 
@@ -331,13 +330,14 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     rows) and the (row tile, store tile) grid steps the Pallas calls of
     the path taken ran (0 for the oracle).
 
-    Dispatch: a bucket-sorted store (``store.n_sorted > 0``) uses the CSR
+    Dispatch: ``use_kernel`` and the store's layout alone.  A
+    bucket-sorted store (``store.n_sorted > 0``) uses the CSR
     bucket-gather kernel over the sorted region plus a full scan of the
     insert tail -- bitwise identical to the full scan, touching only each
-    probe's own bucket rows.  ``force_full_scan=True`` pins the full-scan
-    kernel (the comparison baseline); ``use_kernel=False`` runs the pure
-    jnp oracle (always a full scan -- it is the ground truth the kernels
-    are tested against, and the XLA path for sharded lowering).
+    probe's own bucket rows; an unsorted store (``n_sorted == 0``) takes
+    the full-scan kernel.  ``use_kernel=False`` runs the pure jnp oracle
+    (always a full scan -- it is the ground truth the kernels are tested
+    against, and the XLA path for sharded lowering).
     ``window_tiles`` sizes the gather window (see bucket_gather_pallas);
     oversized spans trigger the traced full-scan fallback, so the value
     only affects performance, never results.  The kernels' full scans
@@ -351,7 +351,7 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     R = query.q.shape[0]
     interpret = _on_cpu()
     query_p = _pad_query(query)
-    if store.n_sorted > 0 and not force_full_scan:
+    if store.n_sorted > 0:
         return _csr_search(query, query_p, store, cr2, L=L, k=k,
                            window_tiles=window_tiles, interpret=interpret)
     ext = _extent(store)

@@ -21,11 +21,10 @@ retired, so a donated buffer is never scribbled while a compiled stage
 may still read it.  ``depth`` in-flight batches therefore need ``depth``
 slots (default 2 -- classic double buffering).
 
-Results are bitwise identical to the synchronous ``flush`` path: the
-stage bodies are the fused trace cut at its collective boundaries, the
-stage payloads are exact int32 buffers, and retirement applies the same
-numpy post-processing in the same submission order (tested in
-tests/test_serving_pipeline.py).
+Results are bitwise identical to the synchronous ``flush`` path: its
+``query()`` runs the same three compiled stage programs back to back,
+and retirement applies the same numpy post-processing in the same
+submission order (tested in tests/test_serving_pipeline.py).
 
 Spans (``core/spans.py``; args ``batch`` = the submit sequence number):
 ``lsh.enqueue`` covers staging plus the three stage calls, ``lsh.retire``

@@ -15,6 +15,7 @@ Three layers, mirroring the passes:
     subprocess: exit 0 on main, nonzero for every seeded violation
     class (the compile-heavy classes are nightly/slow).
 """
+from collections import Counter
 import json
 import os
 import subprocess
@@ -75,7 +76,7 @@ def test_rogue_all_gather_fails_query_budget():
     counts = jaxpr_pass.collective_counts(cj)
     assert counts.get("all_gather") == 1, counts
     viol = jaxpr_pass.check_collectives(
-        counts, CONTRACTS["jaxpr"]["collectives"]["query"], "toy")
+        counts, CONTRACTS["jaxpr"]["collectives"]["query_dispatch"], "toy")
     assert viol and "all_gather" in viol[0]
 
 
@@ -136,7 +137,8 @@ def test_wide_dtype_drift_flagged_but_prng_keys_exempt():
 def test_flatness_check():
     ratio = flatness_ratio(CONTRACTS)
     assert jaxpr_pass.check_flatness({1: 800, 2: 804, 4: 806}, ratio) == []
-    viol = jaxpr_pass.check_flatness({1: 800, 4: 1600}, ratio, "query")
+    viol = jaxpr_pass.check_flatness({1: 800, 4: 1600}, ratio,
+                                     "query_dispatch")
     assert viol and "not flat" in viol[0]
 
 
@@ -160,9 +162,11 @@ def test_alias_parser_handles_nested_braces():
 
 
 def test_donation_report_negative_on_undonated_buffer():
-    rep = hlo_pass.donation_report("HloModule bare", "query", CONTRACTS)
+    rep = hlo_pass.donation_report("HloModule bare", "query_dispatch",
+                                   CONTRACTS)
     assert rep["violations"] and "copied" in rep["violations"][0]
-    rep = hlo_pass.donation_report(_DONOR_HEADER, "query", CONTRACTS)
+    rep = hlo_pass.donation_report(_DONOR_HEADER, "query_dispatch",
+                                   CONTRACTS)
     assert rep["violations"] == []
     # insert requires the six store columns actually aliased
     rep = hlo_pass.donation_report(_HEADER, "insert", CONTRACTS)
@@ -299,18 +303,23 @@ def test_check_seeded_host_sync_fails_fast(tmp_path):
 
 @pytest.mark.multidevice
 def test_check_passes_on_main(tmp_path):
-    """The full gate (real insert/query/delete steps at T in {1,2,4},
-    8 host devices) holds on the current tree."""
+    """The full gate (real insert/delete steps and the query's three
+    staged steps at T in {1,2,4}, 8 host devices) holds on the current
+    tree; the query's two all_to_all are its dispatch's and its
+    return's."""
     proc, report = _run_check(tmp_path)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert report["ok"] and report["violations"] == []
     ph = report["jaxpr"]["phases"]
     for T in ("1", "2", "4"):
         assert ph["insert"][T]["collectives"] == {"all_to_all": 1}
-        assert ph["query"][T]["collectives"] == {"all_to_all": 2}
         assert ph["delete"][T]["collectives"] == {}
+        stages = [ph[f"query_{s}"][T]["collectives"]
+                  for s in ("dispatch", "scan", "return")]
+        assert stages == [{"all_to_all": 1}, {}, {"all_to_all": 1}]
+        assert sum(map(Counter, stages), Counter()) == {"all_to_all": 2}
     assert report["hlo"]["donation"]["insert"]["aliased_params"]
-    don = report["hlo"]["donation"]["query"]
+    don = report["hlo"]["donation"]["query_dispatch"]
     assert don["aliased_params"] or don["donor_params"]
 
 

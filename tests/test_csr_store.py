@@ -1,7 +1,8 @@
 """CSR bucket-sorted store tests (the bucket-gather tentpole contract).
 
   * property: the sorted-CSR gather path answers BITWISE identically to
-    the full-scan kernel -- distances compared as uint32 bit patterns --
+    the full-scan fallback a one-tile gather window forces -- distances
+    compared as uint32 bit patterns --
     for T in {1, 2, 4} and every tail state the LSM layout can reach:
     freshly compacted (tail 0), a small unsorted tail, a tail past the
     merge threshold (auto-merge fires), and post-delete tombstones in
@@ -16,6 +17,7 @@
   * kernel unit tests on a hand-built sorted store: empty bucket,
     single-row bucket, fully tombstoned bucket -- spans and results.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -42,13 +44,16 @@ def _bits(x):
 
 
 def _assert_csr_equals_full(idx, queries):
-    """Query once through the CSR gather, once pinned to the full scan;
-    the results must agree bit-for-bit."""
-    idx.use_csr = True
+    """Query once through the CSR gather, once under a one-tile gather
+    window, which forces the full-scan fallback; the results must agree
+    bit-for-bit."""
     a = idx.query(queries)
-    idx.use_csr = False
-    b = idx.query(queries)
-    idx.use_csr = True
+    idx._gather_window = lambda n: 1
+    try:
+        b = idx.query(queries)
+    finally:
+        del idx._gather_window
+    assert b.scan_counters[:, 0].all(), b.scan_counters   # fell back
     np.testing.assert_array_equal(_bits(a.topk_dist), _bits(b.topk_dist))
     np.testing.assert_array_equal(a.topk_gid, b.topk_gid)
     np.testing.assert_array_equal(a.n_within_cr, b.n_within_cr)
@@ -135,10 +140,10 @@ def test_csr_bitwise_after_elastic_restore():
     assert lay["n_sorted"] > 0 and lay["tail_rows"] == 0, lay
     assert lay["sorted_rows"] == r.n_live == 768 * 2, lay
 
-    r.use_csr = True
     a = r.query(queries)
-    r.use_csr = False
+    r._gather_window = lambda n: 1      # forces the full-scan fallback
     b = r.query(queries)
+    assert b.scan_counters[:, 0].all(), b.scan_counters
     np.testing.assert_array_equal(
         np.asarray(a.topk_dist).view(np.uint32),
         np.asarray(b.topk_dist).view(np.uint32))
@@ -164,7 +169,7 @@ def test_csr_bitwise_after_elastic_restore():
 # ---------------------------------------------------------------------------
 
 EXTENT_CASES = ["tail", "reuse_tombstones", "grow_past_pow2", "compact",
-                "legacy", "force_full_scan", "two_shards"]
+                "legacy", "two_shards"]
 GRID_STEPS = ops.SCAN_COUNTERS.index("grid_steps")
 
 
@@ -197,7 +202,7 @@ def _check_extent(idx, queries):
     oracle = _extent_answers(idx, queries, None)
     assert oracle.n_within_cr.sum() > 0
     windows = ["default"]
-    if st.n_sorted and idx.use_csr:
+    if st.n_sorted:
         windows.append("tight")
     for window in windows:
         if window == "tight":
@@ -216,7 +221,7 @@ def _check_extent(idx, queries):
             np.testing.assert_array_equal(got.topk_gid, want.topk_gid)
             np.testing.assert_array_equal(got.n_within_cr, want.n_within_cr)
         fell_back = got.scan_counters[:, 0].any()
-        if window == "tight" or not (st.n_sorted and idx.use_csr):
+        if window == "tight" or not st.n_sorted:
             # a full scan of the store: row tiles x extent tiles
             assert window == "default" or fell_back
             tiles = lambda n: -(-n // ops.TILE_N)
@@ -268,8 +273,6 @@ def _extent_case(case):
         idx.compact()
         assert idx.layout["tail_hw"] == 0
         assert idx._scan_extent() == idx.store.n_sorted
-    elif case == "force_full_scan":
-        idx.use_csr = False
     elif case == "two_shards":
         # the tails differ: the SPMD scan takes the fuller shard's mark
         valid = np.asarray(idx.store.valid)[:, idx.store.n_sorted:]
@@ -329,6 +332,12 @@ def _degenerate_store():
     return query, store
 
 
+def _tail_only(kw):
+    """The same search over the store's rows seen as an unsorted tail
+    (``n_sorted == 0``): the full-scan kernel."""
+    return dict(kw, store=dataclasses.replace(kw["store"], n_sorted=0))
+
+
 def test_probe_spans_degenerate_buckets():
     query, store = _degenerate_store()
     start, end = ops.csr_probe_spans(query, store)
@@ -340,7 +349,7 @@ def test_gather_degenerate_buckets_match_full_scan():
     query, store = _degenerate_store()
     kw = dict(query=query, store=store, cr2=100.0, L=1, k=4)
     td, tg, cnt, _ = ops.bucket_search(**kw)
-    td_f, tg_f, cnt_f, _ = ops.bucket_search(**kw, force_full_scan=True)
+    td_f, tg_f, cnt_f, _ = ops.bucket_search(**_tail_only(kw))
     np.testing.assert_array_equal(_bits(td), _bits(td_f))
     np.testing.assert_array_equal(np.asarray(tg), np.asarray(tg_f))
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_f))
@@ -364,7 +373,7 @@ def test_gather_tight_radius_filters_inside_bucket():
     query, store = _degenerate_store()
     kw = dict(query=query, store=store, cr2=5.0, L=1, k=4)  # rows 1,2 only
     td, tg, cnt, _ = ops.bucket_search(**kw)
-    td_f, tg_f, cnt_f, _ = ops.bucket_search(**kw, force_full_scan=True)
+    td_f, tg_f, cnt_f, _ = ops.bucket_search(**_tail_only(kw))
     np.testing.assert_array_equal(_bits(td), _bits(td_f))
     np.testing.assert_array_equal(np.asarray(tg), np.asarray(tg_f))
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_f))

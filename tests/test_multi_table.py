@@ -186,11 +186,13 @@ print("OK")
 @pytest.mark.multidevice
 def test_collective_count_independent_of_tables():
     """Compiled-trace proof: one fused all_to_all for insert, exactly two
-    for query (dispatch + routed return), zero all_gather/psum -- for any
-    T.  This is the acceptance criterion for the one-collective-per-phase
-    refactor."""
+    for a query (one in its dispatch step, one in its routed return, none
+    in its scan), zero all_gather/psum -- for any T.  This is the
+    acceptance criterion for the one-collective-per-phase refactor."""
     out = _run(COMMON + """
+from collections import Counter
 from repro.analysis import jaxpr_pass, load_contracts
+from repro.analysis.check import query_stages
 
 budgets = load_contracts()["jaxpr"]["collectives"]
 
@@ -210,14 +212,14 @@ for T in (1, 2, 4):
     assert not jaxpr_pass.check_collectives(c, budgets["insert"]), (T, c)
     assert c == {"all_to_all": 1}, (T, c)
 
-    qf = idx._make_query_fn(64, st.capacity, idx._query_capacity(8),
-                            False, 4, st.n_sorted, 4, idx._scan_extent())
-    c = jaxpr_pass.collective_counts(jax.make_jaxpr(qf)(
-        queries[:64, :32], jnp.arange(64, dtype=jnp.int32),
-        st.x, st.packed, st.gid, st.table, st.valid,
-        st.bucket_start, st.bucket_end))
-    assert not jaxpr_pass.check_collectives(c, budgets["query"]), (T, c)
-    assert c == {"all_to_all": 2}, (T, c)
+    stages = []
+    for phase, fn, args in query_stages(idx, queries[:64, :32], 4, G=4):
+        c = jaxpr_pass.collective_counts(jax.make_jaxpr(fn)(*args))
+        assert not jaxpr_pass.check_collectives(c, budgets[phase]), (T, c)
+        stages.append(c)
+    assert stages == [{"all_to_all": 1}, {}, {"all_to_all": 1}], (T, stages)
+    # the paper's invariant: two all_to_all per query, at every T
+    assert sum(map(Counter, stages), Counter()) == {"all_to_all": 2}
 print("OK")
 """)
     assert "OK" in out

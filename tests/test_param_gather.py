@@ -183,6 +183,7 @@ def test_query_jaxpr_size_flat_in_tables():
     import jax, numpy as np
     import jax.numpy as jnp
     from repro.analysis import jaxpr_pass, load_contracts
+    from repro.analysis.check import query_stages
     from repro.compat import make_mesh
     from repro.core import LSHConfig, Scheme, DistributedLSHIndex
     from repro.data import planted_random
@@ -198,13 +199,10 @@ def test_query_jaxpr_size_flat_in_tables():
         idx = DistributedLSHIndex(cfg, mesh)
         idx.build(data)
         st = idx.store
-        qf = idx._make_query_fn(64, st.capacity, idx._query_capacity(8),
-                                False, 4, st.n_sorted, 4,
-                                idx._scan_extent())
-        q_eqns[T] = jaxpr_pass.eqn_count(jax.make_jaxpr(qf)(
-            queries[:64], jnp.arange(64, dtype=jnp.int32),
-            st.x, st.packed, st.gid, st.table, st.valid,
-            st.bucket_start, st.bucket_end))
+        # a query is its three staged programs: count them together
+        q_eqns[T] = sum(
+            jaxpr_pass.eqn_count(jax.make_jaxpr(fn)(*args))
+            for _, fn, args in query_stages(idx, queries[:64], 4, G=4))
         n_loc = 64 // 8
         inf = idx._make_insert_fn(n_loc, idx._dispatch_capacity(n_loc * T),
                                   st.capacity, st.n_sorted)

@@ -30,7 +30,9 @@ from repro.serving import AsyncLSHService, ShardedLSHService
 COL = {name: i for i, name in enumerate(ops.SCAN_COUNTERS)}
 
 
-def _sorted_store(n=300, d=8, n_buckets=40, seed=0):
+def _sorted_store(n=300, d=8, n_buckets=40, seed=0, tail_only=False):
+    """A bucket-sorted store; ``tail_only`` gives the same rows as an
+    unsorted tail (``n_sorted == 0``), which the full scan reads."""
     rng = np.random.default_rng(seed)
     packed = np.zeros((n, 2), np.int32)
     packed[:, 1] = np.sort(rng.integers(0, n_buckets, n))
@@ -40,7 +42,7 @@ def _sorted_store(n=300, d=8, n_buckets=40, seed=0):
         jnp.asarray(rng.normal(size=(n, d)).astype(np.float32)),
         jnp.asarray(packed), jnp.arange(n, dtype=jnp.int32),
         jnp.ones((n,), jnp.int32), bucket_start=jnp.asarray(bs),
-        bucket_end=jnp.asarray(be), n_sorted=n)
+        bucket_end=jnp.asarray(be), n_sorted=0 if tail_only else n)
 
 
 def _probes(R=40, L=4, d=8, n_buckets=50, seed=1):
@@ -81,14 +83,17 @@ def test_counters_report_what_the_guard_saw():
         assert c[COL["fallback"]] == int(need > window)
 
 
-@pytest.mark.parametrize("kw", [dict(force_full_scan=True),
+@pytest.mark.parametrize("kw", [dict(tail_only=True),
                                 dict(use_kernel=False)])
 def test_counters_are_zero_off_the_csr_path(kw):
-    """The guard's counters are zero there; what the path returned is
+    """The guard's counters are zero there (the full-scan kernel over
+    the rows as a tail, and the oracle); what the path returned is
     counted on every path."""
-    store, query = _sorted_store(), _probes()
-    _, g, cnt, c = ops.bucket_search(query=query, store=store, cr2=4.0,
-                                     L=4, k=4, **kw)
+    store = _sorted_store(tail_only=kw.get("tail_only", False))
+    query = _probes()
+    _, g, cnt, c = ops.bucket_search(
+        query=query, store=store, cr2=4.0, L=4, k=4,
+        use_kernel=kw.get("use_kernel", True))
     c = np.asarray(c)
     assert not c[:len(ops.GUARD_COUNTERS)].any()
     assert c[COL["hits"]] == np.asarray(cnt).sum() > 0

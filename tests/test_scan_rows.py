@@ -4,9 +4,9 @@
 of the receive buffer are compacted to Rc = min(S*Cq, m*T) rows before
 the bucket search:
 
-  * answers are bitwise those of the uncompacted scan, of the full scan
-    (``use_csr=False``) and of the jnp oracle (``use_kernel=False``),
-    through ``query()`` and ``query_staged()``;
+  * answers are bitwise those of the uncompacted scan, of the full-scan
+    fallback a one-tile gather window forces and of the jnp oracle
+    (``use_kernel=False``);
   * the counters show the compaction engaged: the bucket search got
     ceil(Rc*L / TILE_R) * TILE_R expanded rows, with Rc = m*T on one
     and two tables, and Rc = S*Cq under a query capacity below m*T;
@@ -84,34 +84,36 @@ def test_compacted_scan_answers_bitwise(T, query_capacity, monkeypatch):
     window = idx._gather_window
     monkeypatch.setattr(idx, "_gather_window",
                         lambda n: sized.append(n) or window(n))
-    fused = idx.query(queries)
-    _assert_same(idx.query_staged(queries), fused)
-    # the window is sized from the whole receive buffer, both paths
-    assert sized == [Cq * L] * 2
+    res = idx.query(queries)
+    # the window is sized from the whole receive buffer
+    assert sized == [Cq * L]
     tr = ops.TILE_R
-    c = fused.scan_counters[0]
+    c = res.scan_counters[0]
     assert c[COL["probes_expanded"]] == -(-Rc * L // tr) * tr
     assert 0 < c[COL["probes_live"]] <= Rc * L
 
-    idx.use_csr = False                          # the full-scan kernel
-    _assert_same(idx.query(queries), fused)
-    idx.use_csr = True
+    # a one-tile window forces the full-scan fallback
+    monkeypatch.setattr(idx, "_gather_window", lambda n: 1)
+    tight = idx.query(queries)
+    assert tight.scan_counters[0][COL["fallback"]]
+    _assert_same(tight, res)
+    monkeypatch.setattr(idx, "_gather_window", window)
     # the receive buffer searched whole, as before the compaction
     monkeypatch.setattr(idx, "_scan_rows", lambda m, Cq: Cq)
     idx._query_fns.clear()
     whole = idx.query(queries)
-    _assert_same(whole, fused)
+    _assert_same(whole, res)
     if query_capacity is None:
         assert whole.scan_counters[0][COL["probes_expanded"]] == \
             -(-Cq * L // tr) * tr
     oracle, _ = _index(T, query_capacity, use_kernel=False)
-    _assert_same(oracle.query(queries), fused)
+    _assert_same(oracle.query(queries), res)
 
 
 @pytest.mark.multidevice
 def test_compacted_scan_on_four_shards():
     """S = 4: Rc = m*T against S*Cq received rows; answers bitwise those
-    of the uncompacted scan, the full scan and the oracle, for the
+    of the uncompacted scan, the full-scan fallback and the oracle, for the
     layered scheme and for SIMPLE (whose rows of one (qid, table) can
     meet on a shard); ``query_load`` is each shard's valid received
     rows."""
@@ -150,31 +152,35 @@ def test_compacted_scan_on_four_shards():
         Cq = idx._query_capacity(M // S)
         Rc = idx._scan_rows(M, Cq)
         assert Rc == M * T < S * Cq, (Rc, S * Cq)
-        fused = idx.query(queries)
-        same(idx.query_staged(queries), fused)
+        res = idx.query(queries)
         tr = ops.TILE_R
         col = ops.SCAN_COUNTERS.index("probes_expanded")
-        assert (fused.scan_counters[:, col]
-                == -(-Rc * L // tr) * tr).all(), fused.scan_counters
+        assert (res.scan_counters[:, col]
+                == -(-Rc * L // tr) * tr).all(), res.scan_counters
 
         # query_load: every valid row each shard received, before the
         # dedup and the compaction (the paper's load)
         rid = np.asarray(idx.query_dispatch(queries).recv)[:, D]
         per_shard = (rid != IMAX).reshape(S, S * Cq).sum(axis=1)
-        np.testing.assert_array_equal(fused.query_load, per_shard)
-        assert fused.query_load.sum() == fused.fq.sum()
+        np.testing.assert_array_equal(res.query_load, per_shard)
+        assert res.query_load.sum() == res.fq.sum()
 
-        idx.use_csr = False
-        same(idx.query(queries), fused)
-        idx.use_csr = True
+        # a one-tile window forces the fallback on every shard whose
+        # probes span more than one store tile (a shard with few probes
+        # may keep the gather)
+        idx._gather_window = lambda n: 1
+        tight = idx.query(queries)
+        assert tight.scan_counters[:, 0].any(), tight.scan_counters
+        same(tight, res)
+        del idx._gather_window
         idx._scan_rows = lambda m, Cq: S * Cq     # the whole buffer
         idx._query_fns.clear()
         whole = idx.query(queries)
-        same(whole, fused)
+        same(whole, res)
         assert (whole.scan_counters[:, col]
                 == -(-S * Cq * L // tr) * tr).all(), whole.scan_counters
-        same(index(use_kernel=False).query(queries), fused)
-        print("OK", scheme.name, fused.query_load.tolist())
+        same(index(use_kernel=False).query(queries), res)
+        print("OK", scheme.name, res.query_load.tolist())
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"

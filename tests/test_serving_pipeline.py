@@ -3,9 +3,11 @@
 The acceptance contract for the double-buffered query pipeline and the
 ``AsyncLSHService`` worker front-end:
 
-  * the staged query (dispatch / scan / return) is BITWISE identical to
-    the fused ``query()`` -- the stages are the same trace cut at its
-    all_to_all boundaries -- for T in {1, 2}, before and after inserts;
+  * ``query()`` is its three compiled stages (dispatch / scan / return):
+    calling them by hand answers BITWISE as ``query()``, which agrees
+    with the jnp oracle, for T in {1, 2}, before and after inserts;
+  * both front ends and a direct ``query()`` share one compiled program
+    per stage;
   * driving ``AsyncLSHService`` with an interleaved insert/delete/query
     stream answers bitwise identically to ``ShardedLSHService`` on the
     same stream (the pipeline overlaps device work, never reorders);
@@ -28,6 +30,7 @@ import sys
 import textwrap
 import threading
 import time
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -78,25 +81,47 @@ def assert_same_result(a, b):
 
 
 def test_staged_query_bitwise_equals_fused():
-    """query_dispatch/scan/return compose to EXACTLY query() -- same
-    trace, cut at the two all_to_all boundaries -- including with the
-    donated staging buffer and after a streaming insert."""
+    """query_dispatch/scan/return called by hand compose to EXACTLY
+    query() -- including with the donated staging buffer and after a
+    streaming insert -- and query() answers as the jnp oracle (its
+    distances to the kernels' rounding)."""
     out = _run(COMMON + """
+from repro.core.index import _host_query_result
+
+def staged(idx, q, donate=False, k_neighbors=None):
+    disp = idx.query_dispatch(q, donate=donate)
+    scanned = idx.query_scan(disp, k_neighbors=k_neighbors)
+    return _host_query_result(*idx.query_return(scanned), disp.fq,
+                              scanned.recv_load, disp.drops,
+                              scanned.counters)
+
+def assert_oracle(got, want):
+    np.testing.assert_array_equal(got.topk_gid, want.topk_gid)
+    np.testing.assert_array_equal(got.n_within_cr, want.n_within_cr)
+    np.testing.assert_array_equal(got.fq, want.fq)
+    np.testing.assert_allclose(got.topk_dist, want.topk_dist,
+                               rtol=1e-5, atol=1e-5)
+
 for T in (1, 2):
-    idx = DistributedLSHIndex(make_cfg(T=T), mesh8, use_kernel=True,
-                              k_neighbors=5)
-    idx.build(data[:512], capacity=idx._store_capacity(4 * 768 * T))
-    assert_same_result(idx.query_staged(queries), idx.query(queries))
+    idx, oracle = (DistributedLSHIndex(make_cfg(T=T), mesh8,
+                                       use_kernel=kernel, k_neighbors=5)
+                   for kernel in (True, False))
+    for ix in (idx, oracle):
+        ix.build(data[:512], capacity=ix._store_capacity(4 * 768 * T))
+    res = idx.query(queries)
+    assert_same_result(staged(idx, queries), res)
+    assert_oracle(res, oracle.query(queries))
 
     # donated staging buffer (the pipeline's mode): still bitwise
     buf = jnp.array(queries)
-    assert_same_result(idx.query_staged(buf, donate=True),
-                       idx.query(queries))
+    assert_same_result(staged(idx, buf, donate=True), res)
 
-    # a write between staged queries recompiles against the new store
-    idx.insert(data[512:640])
-    assert_same_result(idx.query_staged(queries, k_neighbors=3),
-                       idx.query(queries, k_neighbors=3))
+    # a write between queries recompiles against the new store
+    for ix in (idx, oracle):
+        ix.insert(data[512:640])
+    res = idx.query(queries, k_neighbors=3)
+    assert_same_result(staged(idx, queries, k_neighbors=3), res)
+    assert_oracle(res, oracle.query(queries, k_neighbors=3))
     print(f"staged OK T={T}")
 print("OK")
 """)
@@ -195,14 +220,15 @@ print("OK")
 # In-process single-shard tests (fast-lane coverage over the new code)
 # ---------------------------------------------------------------------
 
-def _small_index(T: int = 1, k_neighbors: int = 4):
+def _small_index(T: int = 1, k_neighbors: int = 4, use_kernel=None):
     from repro.compat import make_mesh
     from repro.core import DistributedLSHIndex, LSHConfig, Scheme
 
     cfg = LSHConfig(d=8, k=4, W=1.2, r=0.3, c=2.0, L=4, n_shards=1,
                     scheme=Scheme.LAYERED, seed=0, n_tables=T)
     mesh = make_mesh((1,), ("shard",))
-    idx = DistributedLSHIndex(cfg, mesh, k_neighbors=k_neighbors)
+    idx = DistributedLSHIndex(cfg, mesh, k_neighbors=k_neighbors,
+                              use_kernel=use_kernel)
     idx.init_store(idx._store_capacity(8 * 256 * T))
     return idx
 
@@ -252,6 +278,43 @@ def test_async_stream_bitwise_inprocess():
         assert "lat(p50/p99)" in st.summary()
     np.testing.assert_array_equal(g0, g1)
     np.testing.assert_array_equal(d0, d1)
+
+
+def test_front_ends_share_one_set_of_query_programs():
+    """ShardedLSHService, AsyncLSHService and a direct query() with the
+    same bucket size, K and donation run the same three compiled stage
+    programs over a sorted store, and answer bitwise alike."""
+    import jax.numpy as jnp
+
+    from repro.serving import AsyncLSHService, ShardedLSHService
+
+    data, queries = _small_data(n=640, m=8)
+    idx = _small_index(use_kernel=True)
+    idx.insert(jnp.asarray(data))
+    idx.compact()
+    assert idx.layout["n_sorted"] > 0
+
+    sync = ShardedLSHService(idx, bucket_size=8,
+                             max_latency_ms=float("inf"))
+    served = [sync.submit_batch(queries)]
+    sync.drain()
+    with AsyncLSHService(idx, bucket_size=8,
+                         max_latency_ms=float("inf")) as asvc:
+        served.append(asvc.submit_batch(queries))
+        asvc.drain()
+    direct = idx.query(jnp.array(queries), donate=True)
+
+    assert Counter(key[0] for key in idx._query_fns) == {
+        "dispatch": 1, "scan": 1, "return": 1}, list(idx._query_fns)
+    assert direct.n_within_cr.sum() > 0
+    for handles in served:
+        np.testing.assert_array_equal(
+            np.stack([h.gids for h in handles]), direct.topk_gid)
+        np.testing.assert_array_equal(
+            np.stack([h.dists for h in handles]).view(np.uint32),
+            direct.topk_dist.view(np.uint32))
+        np.testing.assert_array_equal(
+            [h.n_within_cr for h in handles], direct.n_within_cr)
 
 
 def test_deadline_flush_uses_injected_clock():

@@ -97,7 +97,7 @@ def test_index_query_scan_compiles_for_v5e_mesh(topo, monkeypatch):
     sh = NamedSharding(mesh, P("shard"))
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
     i32 = jnp.int32
-    fn = idx._make_query_scan_fn(m, cap, Cq, 10, ns, 4, cap)
+    fn = idx._make_query_scan_fn(m, Cq, 10, ns, 4, cap)
     hlo = _hlo(fn, s((S * S * Cq, d + 2), i32), s((S, cap, d), jnp.float32),
                s((S, cap, 2), jnp.uint32), s((S, cap), i32),
                s((S, cap), i32), s((S, cap), jnp.bool_), s((S, cap), i32),
@@ -139,7 +139,6 @@ def test_index_full_scans_stop_at_the_extent_in_place(topo, monkeypatch):
     points_copy = re.compile(
         r"f32\[\d+,%d\]\S* (?:pad|slice|copy)\([^\n]*fallback" % d)
     for ext in (ns + 128, cap):
-        hlo = _hlo(idx._make_query_scan_fn(m, cap, Cq, 10, ns, 4, ext),
-                   *args)
+        hlo = _hlo(idx._make_query_scan_fn(m, Cq, 10, ns, 4, ext), *args)
         assert "bucket_search_pallas_fallback" in hlo
         assert bool(points_copy.search(hlo)) == (ext == cap), ext
