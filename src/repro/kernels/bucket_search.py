@@ -23,11 +23,14 @@ comes off the MXU (via -2 Q P^T plus norm epilogue), and the mask, the
 threshold filter and the running top-K reduction all happen in the same
 VMEM residency -- the O(R*N) distance matrix never reaches HBM.  The
 accumulator is a per-row (dist^2, gid) list of length K kept sorted by
-(dist^2, gid) lex order in the revisited output blocks; each point tile
-is merged in with K successor passes over the pool (this tile's masked
-pairs plus the running K): pass k picks the lex-smallest pair strictly
-after the pair pass k-1 picked -- O(K*(TILE_N+K)) VPU work per tile, no
-sort network and no writes into the pool.
+(dist^2, gid) lex order in the revisited output blocks.  A point tile is
+merged in by insertion: only the tile's pairs lex-smaller than the row's
+running K-th pair can enter, and the tile runs one pass per such pair of
+its busiest row (at most K, none on a tile that improves no row).  Pass j
+picks each row's j-th smallest entering pair and inserts it at its rank,
+shifting the accumulator lanes above it by one -- O(TILE_N + KP) VPU work
+per pass, no sort network.  Each kernel also returns the passes it ran,
+summed over its grid.
 
 Mosaic layout (what the TPU compiler accepts): every block is 2-D.
 Per-row scalars travel as (R, 1) columns, per-point scalars as
@@ -38,9 +41,10 @@ wrapper).  The distance matmul runs at HIGHEST precision, so the f32
 contract is the same on the chip as in the CPU interpreter.
 
 Because both kernels feed the SAME (TILE_R, d) x (TILE_N, d) dot_general
-with identical aligned point tiles, and the successor merge is exact
+with identical aligned point tiles, and the insertion merge is exact
 selection over lex (dist^2, gid) order (visit-order independent), the
-gather kernel's results are bitwise identical to the full scan's.
+gather kernel's results are bitwise identical to the full scan's (the
+pass counts depend on the visit order).
 
 Grid: (row tiles, point tiles); the point axis is minor-most, so the
 output blocks for a row tile are revisited across point tiles and act as
@@ -84,60 +88,81 @@ def _sq_dist(q_ref, qsq_ref, p_ref, psq_ref):
 
 def _merge_topk_tile(topd_ref, topg_ref, d2m, gidm, *, K: int, init):
     """Merge one tile's masked (dist, gid) pairs into the running sorted
-    top-K accumulator blocks (shared by both kernels).
+    top-K accumulator blocks (shared by both kernels); returns the
+    insertion passes it ran.
 
-    Candidate pool = this tile's masked pairs + the running K.  gids are
-    unique across the pool (stored rows are unique and the running K came
-    from earlier, disjoint tiles); empty slots are the (F32_MAX, IMAX)
-    sentinel, the lex-largest pair, so fewer-than-K hits pad the tail
-    with sentinels.
+    gids are unique across the tile and the accumulator (stored rows are
+    unique and the running K came from earlier, disjoint tiles); empty
+    slots are the (F32_MAX, IMAX) sentinel, the lex-largest pair, so
+    fewer-than-K hits pad the tail with sentinels.  A tile pair can only
+    enter the top K if it is lex-smaller than the row's running K-th
+    pair, so the merge runs m = min(K, the most such pairs of any row)
+    passes: none on a tile that improves no row.  Pass j inserts the
+    row's j-th smallest improving pair at its rank in the sorted
+    accumulator, shifting the lanes above it up by one; a row with fewer
+    improving pairs inserts the sentinel, which changes nothing.  Lanes
+    >= K keep what was pushed out and are sliced off by the wrapper.
     """
     @pl.when(init)
     def _init():
         topd_ref[...] = jnp.full(topd_ref.shape, F32_MAX, jnp.float32)
         topg_ref[...] = jnp.full(topg_ref.shape, IMAX, jnp.int32)
 
-    pool = ((d2m, gidm), (topd_ref[...], topg_ref[...]))
-    TR, KP = topd_ref.shape
-    lane = jax.lax.broadcasted_iota(jnp.int32, (TR, KP), 1)
+    acc_d, acc_g = topd_ref[...], topg_ref[...]
+    TR, KP = acc_d.shape
+    kd, kg = acc_d[:, K - 1:K], acc_g[:, K - 1:K]     # running K-th pair
+    improves = (d2m < kd) | ((d2m == kd) & (gidm < kg))
+    m = jnp.minimum(jnp.max(jnp.sum(improves.astype(jnp.int32), axis=1)), K)
 
-    def extract(k, carry):
-        last_d, last_g, out_d, out_g = carry
-        after = [(d > last_d) | ((d == last_d) & (g > last_g))
-                 for d, g in pool]
-        bd = functools.reduce(jnp.minimum, [
-            jnp.min(jnp.where(a, d, F32_MAX), axis=1, keepdims=True)
-            for a, (d, _) in zip(after, pool)])               # (TR, 1)
-        bg = functools.reduce(jnp.minimum, [
-            jnp.min(jnp.where(a & (d == bd), g, IMAX), axis=1,
-                    keepdims=True)
-            for a, (d, g) in zip(after, pool)])               # lex tie-break
-        return (bd, bg, jnp.where(lane == k, bd, out_d),
-                jnp.where(lane == k, bg, out_g))
+    @pl.when(m > 0)
+    def _merge():
+        lane = jax.lax.broadcasted_iota(jnp.int32, (TR, KP), 1)
 
-    carry = (jnp.full((TR, 1), -1.0, jnp.float32),            # below any d2
-             jnp.zeros((TR, 1), jnp.int32),
-             jnp.full((TR, KP), F32_MAX, jnp.float32),
-             jnp.full((TR, KP), IMAX, jnp.int32))
-    _, _, out_d, out_g = jax.lax.fori_loop(0, K, extract, carry)
-    topd_ref[...] = out_d
-    topg_ref[...] = out_g
+        def insert(_, carry):
+            last_d, last_g, out_d, out_g = carry
+            after = improves & ((d2m > last_d)
+                                | ((d2m == last_d) & (gidm > last_g)))
+            bd = jnp.min(jnp.where(after, d2m, F32_MAX), axis=1,
+                         keepdims=True)                        # (TR, 1)
+            bg = jnp.min(jnp.where(after & (d2m == bd), gidm, IMAX),
+                         axis=1, keepdims=True)                # lex tie-break
+            below = (out_d < bd) | ((out_d == bd) & (out_g < bg))
+            pos = jnp.sum(below.astype(jnp.int32), axis=1, keepdims=True)
+            keep, put = lane < pos, lane == pos
+            out_d = jnp.where(keep, out_d, jnp.where(
+                put, bd, pltpu.roll(out_d, 1, 1)))
+            out_g = jnp.where(keep, out_g, jnp.where(
+                put, bg, pltpu.roll(out_g, 1, 1)))
+            return bd, bg, out_d, out_g
+
+        carry = (jnp.full((TR, 1), -1.0, jnp.float32),        # below any d2
+                 jnp.zeros((TR, 1), jnp.int32), acc_d, acc_g)
+        _, _, out_d, out_g = jax.lax.fori_loop(0, m, insert, carry)
+        topd_ref[...] = out_d
+        topg_ref[...] = out_g
+
+    return m
 
 
-def _count_and_merge(topd_ref, topg_ref, cnt_ref, d2, hit, gid, *, K, init):
-    """Shared epilogue: hit count + top-K merge of one tile."""
+def _count_and_merge(topd_ref, topg_ref, cnt_ref, passes_ref, d2, hit, gid,
+                     *, K, init):
+    """Shared epilogue: hit count + top-K merge of one tile, and the
+    merge's insertion passes summed over the row tile's grid steps."""
     @pl.when(init)
     def _():
         cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
+        passes_ref[...] = jnp.zeros(passes_ref.shape, jnp.int32)
     cnt_ref[...] += jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
-    _merge_topk_tile(topd_ref, topg_ref, jnp.where(hit, d2, F32_MAX),
-                     jnp.where(hit, gid, IMAX), K=K, init=init)
+    passes_ref[...] += _merge_topk_tile(
+        topd_ref, topg_ref, jnp.where(hit, d2, F32_MAX),
+        jnp.where(hit, gid, IMAX), K=K, init=init)
 
 
 def _bucket_search_kernel(q_ref, qsq_ref, qh_ref, ql_ref, probe_ref,
                           qtab_ref, p_ref, psq_ref, ph_ref, pl_ref, gid_ref,
                           pvalid_ref, ptab_ref, cr2_ref,
-                          topd_ref, topg_ref, cnt_ref, *, L: int, K: int):
+                          topd_ref, topg_ref, cnt_ref, passes_ref, *, L: int,
+                          K: int):
     d2 = _sq_dist(q_ref, qsq_ref, p_ref, psq_ref)    # (TR, TN)
 
     # bucket match: OR over the L probed buckets of each query row
@@ -151,8 +176,8 @@ def _bucket_search_kernel(q_ref, qsq_ref, qh_ref, ql_ref, probe_ref,
     # table (rows of different tables live interleaved in one store)
     hit = (match & (pvalid_ref[...] > 0) & (qtab_ref[...] == ptab_ref[...])
            & (d2 <= cr2_ref[0, 0]))
-    _count_and_merge(topd_ref, topg_ref, cnt_ref, d2, hit, gid_ref[...],
-                     K=K, init=pl.program_id(1) == 0)
+    _count_and_merge(topd_ref, topg_ref, cnt_ref, passes_ref, d2, hit,
+                     gid_ref[...], K=K, init=pl.program_id(1) == 0)
 
 
 def vmem_bytes_per_step(d: int, L: int, K: int) -> int:
@@ -171,7 +196,8 @@ def vmem_bytes_per_step(d: int, L: int, K: int) -> int:
                 + 3 * probes            # bucket hi, bucket lo, probe
                 + TILE_N * d * 4        # p tile
                 + 6 * row)              # psq, hi, lo, gid, pvalid, ptable
-    out_bytes = TILE_R * k_lanes(K) * 4 * 2 + col   # topd, topg, cnt
+    # topd, topg, cnt, passes
+    out_bytes = TILE_R * k_lanes(K) * 4 * 2 + 2 * col
     dist_tile = TILE_R * TILE_N * 4               # d2 scratch residency
     return in_bytes + out_bytes + dist_tile
 
@@ -185,7 +211,7 @@ def gather_vmem_bytes_per_step(d: int, K: int) -> int:
                 + 3 * col               # eqsq, span start, span end
                 + TILE_N * d * 4        # gathered p tile
                 + 3 * row)              # psq, gid, pvalid
-    out_bytes = TILE_R * k_lanes(K) * 4 * 2 + col
+    out_bytes = TILE_R * k_lanes(K) * 4 * 2 + 2 * col
     dist_tile = TILE_R * TILE_N * 4
     return in_bytes + out_bytes + dist_tile
 
@@ -204,7 +230,14 @@ def _outputs(R: int, K: int):
     KP = k_lanes(K)
     return [jax.ShapeDtypeStruct((R, KP), jnp.float32),
             jax.ShapeDtypeStruct((R, KP), jnp.int32),
+            jax.ShapeDtypeStruct((R, 1), jnp.int32),
             jax.ShapeDtypeStruct((R, 1), jnp.int32)]
+
+
+def _passes(passes):
+    """The merge's insertion passes over the whole grid, from the
+    per-row-tile (R, 1) column (every row of a tile holds its tile's)."""
+    return passes[::TILE_R, 0].sum(dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("L", "K", "interpret", "name",
@@ -233,7 +266,8 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
     Returns:
       topd (R, K) f32 masked distance^2, ascending (F32_MAX sentinel pad),
       topg (R, K) int32 gids (IMAX sentinel pad),
-      count (R,) int32 hits within cr.
+      count (R,) int32 hits within cr,
+      passes () int32 the merge's insertion passes over the whole grid.
     Rows are sorted by (distance^2, gid) lex order, so K=1 reproduces the
     old single-best contract exactly; a stored row only matches probes of
     its own table.
@@ -248,7 +282,7 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
     KP = k_lanes(K)
     rows = lambda w: pl.BlockSpec((TILE_R, w), lambda i, j: (i, 0))
     pts = pl.BlockSpec((1, TILE_N), lambda i, j: (0, j))
-    topd, topg, cnt = pl.pallas_call(
+    topd, topg, cnt, passes = pl.pallas_call(
         functools.partial(_bucket_search_kernel, L=L, K=K),
         grid=(R // TILE_R, n_tiles),
         in_specs=[
@@ -257,7 +291,7 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
             pts, pts, pts, pts, pts, pts,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=[rows(KP), rows(KP), rows(1)],
+        out_specs=[rows(KP), rows(KP), rows(1), rows(1)],
         out_shape=_outputs(R, K),
         compiler_params=_PARAMS,
         interpret=interpret,
@@ -267,7 +301,7 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
       store.points, _row(store.psq), _row(store.buckets[:, 0]),
       _row(store.buckets[:, 1]), _row(store.gid), _row(store.valid),
       _row(store.table), jnp.full((1, 1), cr2, jnp.float32))
-    return topd[:, :K], topg[:, :K], cnt[:, 0]
+    return topd[:, :K], topg[:, :K], cnt[:, 0], _passes(passes)
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +311,8 @@ def bucket_search_pallas(*, query: QueryBatch, store: StoreView, cr2,
 
 def _bucket_gather_kernel(base_ref, q_ref, qsq_ref, s_ref, e_ref,
                           p_ref, psq_ref, gid_ref, pvalid_ref, cr2_ref,
-                          topd_ref, topg_ref, cnt_ref, *, K: int):
+                          topd_ref, topg_ref, cnt_ref, passes_ref, *,
+                          K: int):
     i, g = pl.program_id(0), pl.program_id(1)
     d2 = _sq_dist(q_ref, qsq_ref, p_ref, psq_ref)    # (TR, TN)
 
@@ -290,8 +325,8 @@ def _bucket_gather_kernel(base_ref, q_ref, qsq_ref, s_ref, e_ref,
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, TILE_N), 1)
     hit = ((cols >= s_ref[...]) & (cols < e_ref[...])
            & (pvalid_ref[...] > 0) & (d2 <= cr2_ref[0, 0]))
-    _count_and_merge(topd_ref, topg_ref, cnt_ref, d2, hit, gid_ref[...],
-                     K=K, init=g == 0)
+    _count_and_merge(topd_ref, topg_ref, cnt_ref, passes_ref, d2, hit,
+                     gid_ref[...], K=K, init=g == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("K", "G", "interpret"))
@@ -318,8 +353,9 @@ def bucket_gather_pallas(base, q, qsq, start, end, p, psq, gid, pvalid,
         must carry pvalid == 0).
       cr2: scalar threshold (c*r)^2.
       K: neighbours per expanded row (static);  G: window tiles (static).
-    Returns (topd (E, K), topg (E, K), cnt (E,)) with the same sentinel
-    and lex-order contract as ``bucket_search_pallas``.
+    Returns (topd (E, K), topg (E, K), cnt (E,), passes ()) with the same
+    sentinel, lex-order and pass-count contract as
+    ``bucket_search_pallas``.
     """
     E, d = q.shape
     N = p.shape[0]
@@ -338,9 +374,9 @@ def bucket_gather_pallas(base, q, qsq, start, end, p, psq, gid, pvalid,
             pts, pts, pts,
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=[rows(KP), rows(KP), rows(1)],
+        out_specs=[rows(KP), rows(KP), rows(1), rows(1)],
     )
-    topd, topg, cnt = pl.pallas_call(
+    topd, topg, cnt, passes = pl.pallas_call(
         functools.partial(_bucket_gather_kernel, K=K),
         grid_spec=grid_spec,
         out_shape=_outputs(E, K),
@@ -349,4 +385,4 @@ def bucket_gather_pallas(base, q, qsq, start, end, p, psq, gid, pvalid,
         name="bucket_gather_pallas",
     )(base, q, _col(qsq), _col(start), _col(end), p, _row(psq), _row(gid),
       _row(pvalid), jnp.full((1, 1), cr2, jnp.float32))
-    return topd[:, :K], topg[:, :K], cnt[:, 0]
+    return topd[:, :K], topg[:, :K], cnt[:, 0], _passes(passes)

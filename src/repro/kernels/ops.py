@@ -48,7 +48,8 @@ DEFAULT_WINDOW_TILES = 4
 # the CSR overflow guard's five, then what the taken path returned
 GUARD_COUNTERS = ("fallback", "need_tiles", "window_tiles", "probes_live",
                   "probes_expanded")
-SCAN_COUNTERS = GUARD_COUNTERS + ("hits", "filled", "grid_steps")
+SCAN_COUNTERS = GUARD_COUNTERS + ("hits", "filled", "grid_steps",
+                                  "merge_passes")
 
 
 def _on_cpu() -> bool:
@@ -196,13 +197,15 @@ def _grid_steps(rows: int, points: int) -> int:
     return -(-rows // TILE_R) * -(-points // TILE_N)
 
 
-def _counters(guard, cnt, topg, grid_steps) -> jax.Array:
+def _counters(guard, cnt, topg, grid_steps, passes) -> jax.Array:
     """The ``SCAN_COUNTERS`` vector: the guard's five, then the hits
-    within cr, the top-K ranks that hold a gid and the kernels' grid
-    steps, read off what the taken path returned."""
+    within cr, the top-K ranks that hold a gid, the kernels' grid steps
+    and their top-K merge's insertion passes, read off what the taken
+    path returned."""
     return jnp.concatenate([guard, jnp.stack([
         cnt.sum(dtype=jnp.int32), (topg != IMAX).sum(dtype=jnp.int32),
-        jnp.asarray(grid_steps, jnp.int32)])])
+        jnp.asarray(grid_steps, jnp.int32),
+        jnp.asarray(passes, jnp.int32)])])
 
 
 def _no_guard() -> jax.Array:
@@ -268,7 +271,7 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
 
     def run_csr(_):
         with jax.named_scope("gather"):
-            gd, gg, gc = bucket_gather_pallas(
+            gd, gg, gc, passes = bucket_gather_pallas(
                 base, eq, eqsq, es, ee, sorted_view.points,
                 sorted_view.psq, sorted_view.gid, sorted_view.valid, cr2,
                 K=k, G=G, interpret=interpret)
@@ -284,30 +287,32 @@ def _csr_search(query: QueryBatch, query_p: QueryBatch, store: StoreView,
             cnt = rc.reshape(R, L).sum(axis=1)
         if ext > ns:                       # unsorted insert tail
             with jax.named_scope("tail"):
-                td, tg, tc = _full_scan(
+                td, tg, tc, tp = _full_scan(
                     query_p, store, ns, ext, cr2, L=L, k=k,
                     interpret=interpret, name="bucket_search_pallas_tail")
             cand_d = jnp.concatenate([cand_d, td[:R]], axis=1)
             cand_g = jnp.concatenate([cand_g, tg[:R]], axis=1)
             cnt = cnt + tc[:R]
+            passes = passes + tp
         with jax.named_scope("merge"):
             sd, sg = jax.lax.sort((cand_d, cand_g), dimension=1,
                                   num_keys=2)
-        return sd[:, :k], sg[:, :k], cnt
+        return sd[:, :k], sg[:, :k], cnt, passes
 
     def run_full(_):
         with jax.named_scope("fallback"):
-            td, tg, tc = _full_scan(
+            td, tg, tc, tp = _full_scan(
                 query_p, store, 0, ext, cr2, L=L, k=k, interpret=interpret,
                 name="bucket_search_pallas_fallback")
-        return td[:R], tg[:R], tc[:R]
+        return td[:R], tg[:R], tc[:R], tp
 
-    topd, topg, cnt = jax.lax.cond(overflow, run_full, run_csr, None)
+    topd, topg, cnt, passes = jax.lax.cond(overflow, run_full, run_csr,
+                                           None)
     R_p = query_p.q.shape[0]
     csr_steps = _grid_steps(E, G * TILE_N) + (
         _grid_steps(R_p, ext - ns) if ext > ns else 0)
     steps = jnp.where(overflow, _grid_steps(R_p, ext), csr_steps)
-    return topd, topg, cnt, _counters(guard, cnt, topg, steps)
+    return topd, topg, cnt, _counters(guard, cnt, topg, steps, passes)
 
 
 def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
@@ -327,8 +332,9 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     with a non-empty span and all expanded rows (padding included), each
     zero on every other path; then, on every path, the hits within cr
     (the sum of ``cnt``), the top-K ranks that hold a gid (summed over
-    rows) and the (row tile, store tile) grid steps the Pallas calls of
-    the path taken ran (0 for the oracle).
+    rows), the (row tile, store tile) grid steps the Pallas calls of
+    the path taken ran and the insertion passes their top-K merge ran,
+    summed over those grid steps (both 0 for the oracle).
 
     Dispatch: ``use_kernel`` and the store's layout alone.  A
     bucket-sorted store (``store.n_sorted > 0``) uses the CSR
@@ -347,7 +353,7 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
     if not use_kernel:
         topd, topg, cnt = ref.bucket_search_ref(query=query, store=store,
                                                 cr2=cr2, L=L, K=k)
-        return topd, topg, cnt, _counters(_no_guard(), cnt, topg, 0)
+        return topd, topg, cnt, _counters(_no_guard(), cnt, topg, 0, 0)
     R = query.q.shape[0]
     interpret = _on_cpu()
     query_p = _pad_query(query)
@@ -355,11 +361,12 @@ def bucket_search(*, query: QueryBatch, store: StoreView, cr2, L: int,
         return _csr_search(query, query_p, store, cr2, L=L, k=k,
                            window_tiles=window_tiles, interpret=interpret)
     ext = _extent(store)
-    topd, topg, cnt = _full_scan(query_p, store, 0, ext, cr2, L=L, k=k,
-                                 interpret=interpret)
+    topd, topg, cnt, passes = _full_scan(query_p, store, 0, ext, cr2, L=L,
+                                         k=k, interpret=interpret)
     topd, topg, cnt = topd[:R], topg[:R], cnt[:R]
     return topd, topg, cnt, _counters(
-        _no_guard(), cnt, topg, _grid_steps(query_p.q.shape[0], ext))
+        _no_guard(), cnt, topg, _grid_steps(query_p.q.shape[0], ext),
+        passes)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,

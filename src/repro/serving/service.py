@@ -113,6 +113,12 @@ class BatchRecord:
         over shards; 0 on the jnp oracle."""
         return int(self._col("grid_steps").sum())
 
+    @property
+    def merge_passes(self) -> int:
+        """Insertion passes the kernels' top-K merge ran over those grid
+        steps, over shards; 0 on the jnp oracle."""
+        return int(self._col("merge_passes").sum())
+
 
 @dataclasses.dataclass
 class PendingQuery:
@@ -162,6 +168,7 @@ class ServiceStats:
     scan_hits: int = 0            # candidates within cr the scans found
     scan_filled: int = 0          # top-K ranks the scans filled
     scan_grid_steps: int = 0      # kernel grid steps the scans ran
+    scan_merge_passes: int = 0    # ... and their top-K merge's passes
     # admission: queries' wait from submit until their batch was enqueued
     admission_wait_s: float = 0.0
     admitted: int = 0
@@ -200,6 +207,7 @@ class ServiceStats:
         self.scan_hits += rec.hits
         self.scan_filled += rec.filled
         self.scan_grid_steps += rec.grid_steps
+        self.scan_merge_passes += rec.merge_passes
         self.admission_wait_s += rec.admission_wait_s
         self.admitted += rec.take
 
@@ -250,6 +258,7 @@ class ServiceStats:
                 f"fallbacks={self.scan_fallbacks}/{self.scan_steps} "
                 f"hits={self.scan_hits} filled={self.scan_filled} "
                 f"grid_steps={self.scan_grid_steps} "
+                f"merge_passes={self.scan_merge_passes} "
                 f"admission_wait={self.admission_wait_ms:.1f}ms "
                 f"inserts={self.inserts} ips={self.inserts_per_s:.0f} "
                 f"deletes={self.deletes} "

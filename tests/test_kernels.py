@@ -191,6 +191,93 @@ def test_bucket_search_no_matches():
     assert np.all(np.asarray(cnt) == 0)
 
 
+def _merge_case(case, K, R=256, N=640, d=8, span=200, seed=0):
+    """Integer-valued rows, so every dist^2 is exact in f32 and both
+    kernels and the oracle agree bit for bit.  The store is sorted into
+    buckets of ``span`` rows (crossing tile edges); query row r probes
+    bucket r % 4, so rows of the short last bucket see fewer hits.
+
+      none      no pair within cr: every tile merges with zero passes
+      tightens  dist^2 falls along the store, so each tile brings more
+                improving pairs than the running K can hold and its
+                K-th tightens inside the tile
+      ties      two dist^2 levels shared by every row and point, gids
+                falling along the store: tile pairs tie the accumulator's
+                and enter by gid alone
+      few       K - 1 valid rows per bucket: fewer than K hits, sentinel
+                tails
+      random    random rows, about half the pairs within cr
+    """
+    rng = np.random.default_rng(seed)
+    q = rng.integers(-2, 3, (R, d)).astype(np.float32)
+    p = rng.integers(-2, 3, (N, d)).astype(np.float32)
+    gid = rng.permutation(N).astype(np.int32) * 3 + 1
+    valid = np.ones(N, np.int32)
+    cr2 = 1e6
+    if case == "none":
+        q += 0.5                       # dist^2 >= d / 4
+        cr2 = 0.1
+    elif case == "tightens":
+        p[:, 0] = (N - np.arange(N)) // 2
+    elif case == "ties":
+        q[:] = 0.0
+        p[:] = 1.0 + (rng.random(N) < 0.5)[:, None]
+        gid = np.arange(N, dtype=np.int32)[::-1] * 3 + 1
+    elif case == "few":
+        valid[:] = 0
+        for b0 in range(0, N, span):
+            idx = np.arange(b0, min(b0 + span, N))
+            valid[rng.choice(idx, min(K - 1, len(idx)), replace=False)] = 1
+    else:
+        cr2 = 30.0
+    pb = (np.arange(N) // span).astype(np.int32)
+    qb = (np.arange(R) % 4).astype(np.int32)
+    return q, p, gid, valid, pb, qb, cr2
+
+
+@pytest.mark.parametrize("K", [1, 10, 100, 128])
+@pytest.mark.parametrize("case", ["none", "tightens", "ties", "few",
+                                  "random"])
+def test_insertion_merge_is_exact_on_both_kernels(case, K):
+    """Both kernels' top-K merge, which runs one insertion pass per
+    entering pair of a tile's busiest row, gives the oracle's answers
+    bit for bit; both visit the same tiles in the same order, so they
+    run the same passes, and a tile that improves no row runs none."""
+    from repro.kernels.bucket_search import (bucket_gather_pallas,
+                                             bucket_search_pallas)
+    span = 200
+    q, p, gid, valid, pb, qb, cr2 = _merge_case(case, K, span=span)
+    R, N = q.shape[0], p.shape[0]
+    zeros = lambda n: np.zeros((n, 1), np.int32)
+    query = QueryBatch.build(jnp.asarray(q),
+                             jnp.asarray(np.hstack([zeros(R), qb[:, None]])),
+                             jnp.ones((R, 1), jnp.int32))
+    store = StoreView.build(jnp.asarray(p),
+                            jnp.asarray(np.hstack([zeros(N), pb[:, None]])),
+                            jnp.asarray(gid), jnp.asarray(valid))
+    want = [np.asarray(a) for a in ref.bucket_search_ref(
+        query=query, store=store, cr2=cr2, L=1, K=K)]
+    *got_s, passes_s = bucket_search_pallas(
+        query=query, store=store, cr2=cr2, L=1, K=K, interpret=True)
+    start = jnp.asarray(qb * span, jnp.int32)
+    end = jnp.asarray(np.minimum((qb + 1) * span, N), jnp.int32)
+    *got_g, passes_g = bucket_gather_pallas(
+        jnp.zeros((R // 128,), jnp.int32), query.q, query.qsq, start, end,
+        store.points, store.psq, store.gid, store.valid, cr2, K=K,
+        G=N // 128, interpret=True)
+    for got in (got_s, got_g):
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), b)
+    steps = (R // 128) * (N // 128)
+    assert int(passes_s) == int(passes_g) <= K * steps
+    assert (int(passes_s) == 0) == (case == "none" or (case == "few"
+                                                      and K == 1))
+    if case == "few":
+        assert (want[2] < K).all()
+    if case in ("tightens", "ties") and K < 128:
+        assert (want[2] > K).any()        # more hits than the top K holds
+
+
 def test_bucket_search_no_rxn_buffer():
     """The streaming-reduction contract: per-grid-step VMEM residency is
     a function of (d, L, K) only, and the kernel's HBM outputs are

@@ -25,12 +25,20 @@ from repro.compat import make_mesh
 from repro.core import DistributedLSHIndex, LSHConfig, Scheme, store_layout
 from repro.kernels import ops
 from repro.kernels.types import QueryBatch, StoreView
-from repro.serving import AsyncLSHService, ShardedLSHService
+from repro.serving import (AsyncLSHService, BatchRecord, ServiceStats,
+                           ShardedLSHService)
 
 COL = {name: i for i, name in enumerate(ops.SCAN_COUNTERS)}
 
 
-def _sorted_store(n=300, d=8, n_buckets=40, seed=0, tail_only=False):
+def _rows(rng, shape, integer):
+    """Normal rows, or small integers (every dist^2 exact in f32)."""
+    x = rng.integers(-2, 3, shape) if integer else rng.normal(size=shape)
+    return jnp.asarray(x.astype(np.float32))
+
+
+def _sorted_store(n=300, d=8, n_buckets=40, seed=0, tail_only=False,
+                  integer=False):
     """A bucket-sorted store; ``tail_only`` gives the same rows as an
     unsorted tail (``n_sorted == 0``), which the full scan reads."""
     rng = np.random.default_rng(seed)
@@ -39,13 +47,13 @@ def _sorted_store(n=300, d=8, n_buckets=40, seed=0, tail_only=False):
     table = np.zeros(n, np.int32)
     bs, be = store_layout.bucket_spans(table, packed)
     return StoreView.build(
-        jnp.asarray(rng.normal(size=(n, d)).astype(np.float32)),
+        _rows(rng, (n, d), integer),
         jnp.asarray(packed), jnp.arange(n, dtype=jnp.int32),
         jnp.ones((n,), jnp.int32), bucket_start=jnp.asarray(bs),
         bucket_end=jnp.asarray(be), n_sorted=0 if tail_only else n)
 
 
-def _probes(R=40, L=4, d=8, n_buckets=50, seed=1):
+def _probes(R=40, L=4, d=8, n_buckets=50, seed=1, integer=False):
     """Probes of buckets 0..n_buckets (some absent from the store), some
     repeated within a row, some switched off."""
     rng = np.random.default_rng(seed)
@@ -54,7 +62,7 @@ def _probes(R=40, L=4, d=8, n_buckets=50, seed=1):
     qb[:, 1, 1] = qb[:, 0, 1]                    # a repeat in every row
     probe = (rng.random((R, L)) < 0.8).astype(np.int32)
     return QueryBatch.build(
-        jnp.asarray(rng.normal(size=(R, d)).astype(np.float32)),
+        _rows(rng, (R, d), integer),
         jnp.asarray(qb.reshape(R, 2 * L)), jnp.asarray(probe))
 
 
@@ -100,6 +108,86 @@ def test_counters_are_zero_off_the_csr_path(kw):
     assert c[COL["filled"]] == (np.asarray(g) != ops.IMAX).sum() > 0
     # one row tile over the store's 300 rows, padded to 3 store tiles
     assert c[COL["grid_steps"]] == (3 if kw.get("use_kernel", True) else 0)
+
+
+def _reckoned_passes(query, store, cr2, K):
+    """The full scan's merge passes counted in NumPy: over its (row
+    tile, store tile) grid steps, in order, the sum of min(K, the most
+    pairs one row of the tile brings that are lex-smaller in (dist^2,
+    gid) than its running K-th pair), each row's top K updated tile by
+    tile."""
+    q, p = (np.asarray(a, np.float64) for a in (query.q, store.points))
+    d2 = ((q[:, None, :] - p[None, :, :]) ** 2).sum(-1)   # exact: integers
+    qb, pb = np.asarray(query.buckets)[:, 1::2], np.asarray(store.buckets)
+    probe, gid = np.asarray(query.probe) > 0, np.asarray(store.gid)
+    hit = (((qb[:, :, None] == pb[None, None, :, 1]) & probe[:, :, None])
+           .any(axis=1) & (np.asarray(store.valid) > 0)[None]
+           & (d2 <= cr2))
+    R, N = hit.shape
+    total = 0
+    for r0 in range(0, R, ops.TILE_R):
+        rows = range(r0, min(r0 + ops.TILE_R, R))
+        top = {r: [] for r in rows}
+        for c0 in range(0, N, ops.TILE_N):
+            most = 0
+            for r in rows:
+                pairs = [(d2[r, c], gid[c])
+                         for c in range(c0, min(c0 + ops.TILE_N, N))
+                         if hit[r, c]]
+                kth = (top[r][K - 1] if len(top[r]) >= K
+                       else (np.inf, ops.IMAX))
+                most = max(most, sum(pr < kth for pr in pairs))
+                top[r] = sorted(top[r] + pairs)[:K]
+            total += min(K, most)
+    return total
+
+
+@pytest.mark.parametrize("K", [4, 100])
+@pytest.mark.parametrize("path", ["unsorted", "fallback", "csr", "oracle"])
+def test_merge_passes_count_the_insertions(path, K):
+    """The full scan's passes (an unsorted store, or the fallback of a
+    sorted one) are the NumPy reckoning's; on every path they are at
+    most K a grid step (0 on the oracle), and 0 when nothing lies within
+    cr."""
+    store = _sorted_store(tail_only=path == "unsorted", integer=True)
+    query = _probes(integer=True)
+    kw = dict(query=query, store=store, L=4, k=K,
+              use_kernel=path != "oracle",
+              window_tiles=1 if path == "fallback" else 3)
+    *_, c = ops.bucket_search(cr2=12.0, **kw)
+    c = np.asarray(c)
+    assert c[COL["fallback"]] == (path == "fallback")
+    passes, steps = c[COL["merge_passes"]], c[COL["grid_steps"]]
+    assert passes <= K * steps
+    if path == "oracle":
+        assert passes == steps == 0
+    else:
+        assert passes > 0
+    if path in ("unsorted", "fallback"):
+        assert passes == _reckoned_passes(query, store, 12.0, K)
+    *_, c = ops.bucket_search(cr2=0.5, **kw)   # no integer row that close
+    c = np.asarray(c)
+    assert c[COL["hits"]] == c[COL["merge_passes"]] == 0
+
+
+def test_records_without_the_column_read_no_merge_passes():
+    """A record kept from a program that counted eight columns reads 0
+    merge passes, as one of five reads 0 hits; the stats sum and print
+    them."""
+    def rec(counters):
+        return BatchRecord(
+            seq=0, reason="full", take=1, pad=0, drops=0, routed_rows=1,
+            admission_wait_s=0.0, t_enqueue=0.0, t_enqueued=0.0,
+            counters=np.array([counters], np.int32))
+    old, new = rec([0, 0, 0, 0, 128, 5, 4, 3]), rec(
+        [0, 0, 0, 0, 128, 5, 4, 3, 7])
+    assert (old.grid_steps, old.merge_passes) == (3, 0)
+    assert new.merge_passes == 7
+    stats = ServiceStats()
+    for r in (old, new):
+        stats.record_batch(r)
+    assert stats.scan_merge_passes == 7
+    assert "grid_steps=6 merge_passes=7 " in stats.summary()
 
 
 def _index():

@@ -8,7 +8,8 @@ plus a full scan of the insert tail, the full-scan fallback forced by a
 one-tile gather window, and the gather after deletes in both regions.
 Each batch's ``hits``, ``filled`` and ``grid_steps`` are held to what
 the test counts itself: every candidate of the reference, the ranks
-min(K, candidates) and the kernels' grid from the store's shapes.
+min(K, candidates) and the kernels' grid from the store's shapes; its
+``merge_passes`` to at most K a grid step.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -108,10 +109,13 @@ def _serve_and_check(idx, x, q, deleted=()):
     assert rec.hits == n_cand.sum() == sum(h.n_within_cr for h in handles)
     assert rec.filled == np.minimum(n_cand, K).sum()
     assert rec.grid_steps == _grid_steps(idx, rec)
-    assert (stats.scan_hits, stats.scan_filled, stats.scan_grid_steps) == (
-        rec.hits, rec.filled, rec.grid_steps)
+    assert 0 < rec.merge_passes <= K * rec.grid_steps
+    assert (stats.scan_hits, stats.scan_filled, stats.scan_grid_steps,
+            stats.scan_merge_passes) == (
+        rec.hits, rec.filled, rec.grid_steps, rec.merge_passes)
     assert (f"hits={rec.hits} filled={rec.filled} "
-            f"grid_steps={rec.grid_steps}") in stats.summary()
+            f"grid_steps={rec.grid_steps} "
+            f"merge_passes={rec.merge_passes}") in stats.summary()
     return rec
 
 

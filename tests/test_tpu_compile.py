@@ -51,7 +51,7 @@ def _hlo(fn, *args, **kwargs) -> str:
     return fn.lower(*args, **kwargs).compile().as_text()
 
 
-@pytest.mark.parametrize("K", [10, 100])
+@pytest.mark.parametrize("K", [10, 100, 128])
 @pytest.mark.parametrize("d", [96, 128, 768])
 def test_bucket_search_compiles_for_v5e(one_chip, d, K):
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -68,7 +68,7 @@ def test_bucket_search_compiles_for_v5e(one_chip, d, K):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("K", [10, 100])
+@pytest.mark.parametrize("K", [10, 100, 128])
 @pytest.mark.parametrize("d", [96, 128, 768])
 def test_bucket_gather_compiles_for_v5e(one_chip, d, K):
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
